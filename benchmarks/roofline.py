@@ -22,17 +22,14 @@ import json
 from pathlib import Path
 
 from repro.configs import get_config
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, chip_peaks
 from repro.models.config import SHAPES
 
 
 def peak_bytes_per_s() -> float:
-    """Modelled HBM peak bandwidth (bytes/s) — the roofline memory ceiling.
-
-    Single source of truth is ``repro.launch.mesh.HBM_BW``; exposed here so
-    eval/throughput reports can quote the ceiling they normalise against.
-    """
-    return float(HBM_BW)
+    """HBM peak bandwidth (bytes/s) of the chip the dry-run models — the
+    roofline memory ceiling, read from ``repro.launch.mesh.CHIP_PEAKS``."""
+    return float(chip_peaks(DRYRUN_DEVICE_KIND).hbm_bytes_s)
 
 
 def load_cells(d: str = "experiments/dryrun") -> list[dict]:
@@ -72,14 +69,15 @@ def ideal_step_s(arch: str, shape: str, n_chips: int) -> tuple[float, float]:
     n_active = cfg.active_param_count()
     toks = sc.global_batch * (sc.seq_len if sc.kind != "decode" else 1)
     mult = 6 if sc.kind == "train" else 2
-    compute = mult * n_active * toks / n_chips / PEAK_FLOPS_BF16
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
+    compute = mult * n_active * toks / n_chips / peaks.flops_bf16
     if sc.kind == "train":
         min_bytes = 20 * n_active / n_chips
     elif sc.kind == "prefill":
         min_bytes = (2 * n_active + _kv_bytes(cfg, sc)) / n_chips
     else:
         min_bytes = (2 * n_active + _kv_bytes(cfg, sc)) / n_chips
-    return compute, min_bytes / HBM_BW
+    return compute, min_bytes / peaks.hbm_bytes_s
 
 
 def rows(cells: list[dict]) -> list[dict]:

@@ -17,7 +17,9 @@ def main() -> None:
         bench_kvcache,
         bench_throughput,
     )
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     failures = 0
     for mod in (bench_compression, bench_kmeans, bench_throughput,
                 bench_gradcomp, bench_kvcache):

@@ -202,10 +202,9 @@ def _runtime_models() -> dict[str, tuple[str, dict[str, int], int | None, int]] 
     except Exception:                          # pragma: no cover - no-JAX envs
         return None
     cfg = FRConfig()
-    k_pad = enc.k_padded(cfg)
     tile_env = {
         "T": enc.DEFAULT_PAGES_PER_TILE, "P": cfg.page_words,
-        "cap": cfg.outlier_cap, "k_pad": k_pad,
+        "cap": cfg.outlier_cap,
         "cfg.ptr_lanes": cfg.ptr_lanes, "cfg.delta_lanes": cfg.delta_lanes,
         "cfg.outlier_cap": cfg.outlier_cap, "cfg.page_words": cfg.page_words,
     }
@@ -218,7 +217,6 @@ def _runtime_models() -> dict[str, tuple[str, dict[str, int], int | None, int]] 
     groups = 4
     attn_env = {
         "n_kv": n_kv, "hd": hd, "groups": groups,
-        "k_pad": enc.k_padded(KV_FR),
         "cfg.ptr_lanes": KV_FR.ptr_lanes, "cfg.delta_lanes": KV_FR.delta_lanes,
         "cfg.outlier_cap": KV_FR.outlier_cap, "cfg.page_words": KV_FR.page_words,
     }
@@ -231,10 +229,10 @@ def _runtime_models() -> dict[str, tuple[str, dict[str, int], int | None, int]] 
         attn_model = None                      # flagged as missing budget tie
     return {
         "src/repro/kernels/gbdi_encode.py": (
-            "FRConfig() x pages_per_tile=4", tile_env, tile_model,
+            f"FRConfig() x pages_per_tile={enc.DEFAULT_PAGES_PER_TILE}", tile_env, tile_model,
             enc.VMEM_BUDGET_BYTES),
         "src/repro/kernels/gbdi_decode.py": (
-            "FRConfig() x pages_per_tile=4", tile_env, tile_model,
+            f"FRConfig() x pages_per_tile={enc.DEFAULT_PAGES_PER_TILE}", tile_env, tile_model,
             enc.VMEM_BUDGET_BYTES),
         "src/repro/kernels/gbdi_paged_attn.py": (
             f"KV_FR x (n_kv={n_kv}, hd={hd}, groups={groups})", attn_env,

@@ -46,30 +46,16 @@ GRAD_FR = FRConfig(word_bits=16, page_words=DEFAULT_PAGE_WORDS,
 
 
 def pod_shard_map(f, mesh, in_specs, out_specs, *, manual_axes=("pod",)):
-    """shard_map manual over ``manual_axes`` only, across jax versions.
-
-    jax >= 0.7 spells this ``jax.shard_map(..., axis_names=...)``; 0.4.x
-    spells it ``jax.experimental.shard_map.shard_map(..., auto=<the other
-    axes>)``.  Replica/varying checks are disabled in both — the compressed
-    ring exchange is deliberately non-replicated across pods.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual_axes), check_vma=False,
-        )
-    # 0.4.x: partial-auto (auto=...) trips an XLA partitioner check
-    # (IsManualSubgroup), so go fully manual over every mesh axis.  The
-    # exchange body is elementwise over the non-pod axes, so the result is
-    # identical — only automatic sharding propagation inside is lost.
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
+    """shard_map manual over ``manual_axes`` only; the other mesh axes stay
+    automatic.  Replica/varying checks are off — the compressed ring
+    exchange is deliberately non-replicated across pods."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(manual_axes), check_vma=False,
     )
 
 
-def _encode_leaf(g: jax.Array, table: BaseTable):
+def encode_leaf(g: jax.Array, table: BaseTable):
     """All pages of a leaf in one batched compiled dispatch (kernels.xla)."""
     flat = g.astype(jnp.bfloat16).reshape(-1)
     words = jax.lax.bitcast_convert_type(flat, jnp.uint16).astype(jnp.int32)
@@ -93,7 +79,7 @@ def compressed_pod_mean(grads, table: BaseTable, *, axis_name: str = "pod", n_po
     """Inside shard_map(manual over ``pod``): ring-exchange compressed grads,
     return the cross-pod mean.  Exact for in-capacity pages (bf16 transport)."""
     acc = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-    blobs = jax.tree.map(lambda g: _encode_leaf(g, table), grads,
+    blobs = jax.tree.map(lambda g: encode_leaf(g, table), grads,
                          is_leaf=lambda x: hasattr(x, "shape"))
     perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
     cur = blobs

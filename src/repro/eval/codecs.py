@@ -178,10 +178,6 @@ class FRCodec:
         n = signed.size
         pad = (-n) % cfg.page_words
         pages = np.pad(signed, (0, pad)).reshape(-1, cfg.page_words)
-        if backend == "kernel":   # Pallas grid wants whole tiles
-            row_pad = (-pages.shape[0]) % ops.DEFAULT_PAGES_PER_TILE
-            if row_pad:
-                pages = np.pad(pages, ((0, row_pad), (0, 0)))
         if backend == "xla":
             from repro.kernels import pipeline
 
@@ -237,7 +233,6 @@ class FRCodec:
 
     def size_bits(self, blob: dict[str, Any]) -> int:
         cfg: FRConfig = blob["_cfg"]
-        # data pages only — kernel-tile padding pages don't count
         n_pages = -(-blob["_n_words"] // cfg.page_words)
         # base values + width-class index per base (0 bits if single-class)
         idx_bits = (len(cfg.width_set) - 1).bit_length()

@@ -365,13 +365,14 @@ THROUGHPUT_CODECS = "gbdi,bdi,fr,fr_xla,fr_kernel"
 KERNEL_N_BYTES = 256 << 10
 
 
-def roofline_peak_bytes_s() -> float:
-    """Memory-roofline ceiling the throughput rows normalise against —
-    the same modelled HBM bandwidth ``benchmarks/roofline.py``'s
-    ``peak_bytes_per_s()`` quotes (single source: ``repro.launch.mesh``)."""
-    from repro.launch.mesh import HBM_BW
+def roofline_peak_bytes_s(device_kind: str) -> float | None:
+    """HBM peak of ``device_kind`` from ``repro.launch.mesh.CHIP_PEAKS``, or
+    None for a device with no published peak (the CPU): a host rate is
+    never divided by a chip's bandwidth."""
+    from repro.launch.mesh import CHIP_PEAKS
 
-    return float(HBM_BW)
+    peaks = CHIP_PEAKS.get(device_kind)
+    return None if peaks is None else float(peaks.hbm_bytes_s)
 
 
 def measure_throughput(
@@ -382,8 +383,9 @@ def measure_throughput(
 
     Each row carries its roofline attribution: ``bytes_moved`` (stream
     read + compressed blob write, the minimal memory traffic of one
-    encode pass), the modelled peak bandwidth, and the achieved fraction
-    of it — plus the visible device count and, when the harness ran the
+    encode pass), the ``device_kind`` it ran on, that chip's peak
+    bandwidth and the achieved fraction of it (``None`` on a device with
+    no published peak) — plus the visible device count and, when the harness ran the
     codec on a smaller stream than requested, an explicit ``truncated``
     marker (no silent caps).
     """
@@ -399,7 +401,8 @@ def measure_throughput(
     gib = n_bytes / (1 << 30)
     comp_bytes = (int(codec.size_bits(blob)) + 7) // 8
     bytes_moved = n_bytes + comp_bytes            # stream in + blob out
-    peak = roofline_peak_bytes_s()
+    kind = jax.devices()[0].device_kind
+    peak = roofline_peak_bytes_s(kind)
     return {
         "workload": workload.name,
         "kind": workload.kind,
@@ -408,6 +411,7 @@ def measure_throughput(
         "n_bytes_requested": requested,
         "truncated": n_bytes < requested,
         "devices": int(jax.local_device_count()),
+        "device_kind": kind,
         "repeats": max(1, repeats),
         "enc_s": enc_s,
         "dec_s": dec_s,
@@ -416,8 +420,10 @@ def measure_throughput(
         "comp_bytes": comp_bytes,
         "bytes_moved": bytes_moved,
         "peak_bytes_s": peak,
-        "enc_roofline_frac": bytes_moved / max(enc_s, 1e-12) / peak,
-        "dec_roofline_frac": bytes_moved / max(dec_s, 1e-12) / peak,
+        "enc_roofline_frac": None if peak is None
+        else bytes_moved / max(enc_s, 1e-12) / peak,
+        "dec_roofline_frac": None if peak is None
+        else bytes_moved / max(dec_s, 1e-12) / peak,
     }
 
 
@@ -516,10 +522,11 @@ def format_throughput_table(rows: list[dict]) -> str:
                 f"{r['n_bytes'] / (1 << 20):>6.2f} FAILED: {r['error']}")
             continue
         trunc = "*" if r.get("truncated") else " "
+        rf = r["enc_roofline_frac"]
         lines.append(
             f"{r['workload']:<20} {r['kind']:<7} {r['codec']:<10} "
             f"{r['n_bytes'] / (1 << 20):>5.2f}{trunc} {r['enc_gib_s']:>10.3f} "
-            f"{r['dec_gib_s']:>10.3f} {r['enc_roofline_frac']:>9.1e} "
+            f"{r['dec_gib_s']:>10.3f} {'-' if rf is None else f'{rf:.1e}':>9} "
             f"{r['devices']:>3}"
         )
     if any(r.get("truncated") for r in rows):
@@ -547,7 +554,8 @@ def throughput_artifact(rows: list[dict], *, codecs: str, n_bytes: int,
         "seed": seed,
         "auto_backend": ops.resolve_backend("auto"),
         "devices": int(jax.local_device_count()),
-        "peak_bytes_s": roofline_peak_bytes_s(),
+        "device_kind": jax.devices()[0].device_kind,
+        "peak_bytes_s": roofline_peak_bytes_s(jax.devices()[0].device_kind),
         "complete": complete,       # False while rows stream in mid-sweep
         "rows": rows,
         "summary": throughput_summary(rows),
@@ -677,6 +685,9 @@ def main(argv: list[str] | None = None) -> list[EvalCell]:
                     help="timed repeats per measurement (median is reported; "
                          "default 3, 5 for --throughput)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.throughput:
         n_bytes = args.n_bytes if args.n_bytes is not None else 2 << 20

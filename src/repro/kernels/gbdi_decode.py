@@ -1,11 +1,18 @@
 """Pallas TPU kernel: GBDI-FR v2 page decode.
 
 Decode is the paper's "value reconstruction" engine: global-table lookup +
-delta add + outlier scatter-back.  On TPU the table lookup is a one-hot
-integer multiply-reduce (k is tiny), the per-width-class sub-stream gather
-recomputes the encoder's page-order prefix ranks and reads slots through
-chunked one-hot reduces, and the outlier scatter is the transpose of the
-encoder's compaction one-hot — no dynamic gather/scatter anywhere.
+delta add + outlier scatter-back.  On TPU it runs the encoder's lane moves
+(:mod:`repro.kernels.gbdi_encode`) in reverse on ``(pages_per_tile,
+page_words)`` tiles: pointer codes and class sub-streams unpack by
+spreading packed lanes back over their fields; a word's slot in its class
+sub-stream is its page-order rank among same-class words, so each slot
+travels right to its word by the inverse of the encoder's compaction; the
+base value is a select over the (tiny) SMEM base table.  Outliers come
+back the same way: both encoders fill the outlier table in page order, so
+the j-th outlier-coded word owns slot j (``j < n_out``; a dropped word
+keeps the code and decodes to 0).  That makes ``out_idx`` redundant for
+encoder-written blobs, and the kernel does not read it.  No dynamic
+gather or scatter anywhere.
 """
 from __future__ import annotations
 
@@ -15,101 +22,98 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.format import WORD16_MASK, TableLike, as_base_table
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels.gbdi_encode import (
     DEFAULT_PAGES_PER_TILE,
-    SLOT_CHUNK,
     _check_vmem,
-    _cumsum_lanes,
-    k_padded,
-    pad_table,
+    compact,
+    expand,
+    lanes,
+    pad_pages,
+    prefix_sum,
+    smem_table,
+    unpack_fields,
 )
 
 
-def _gather_chunks(
-    rank: jax.Array, inclass: jax.Array, sub: jax.Array, cap: int
+def _widen(ref: Any, buf: Any) -> jax.Array:
+    """Load a ``(T, n)`` block into lanes ``[0, n)`` of a zeroed ``(T, P)``
+    VMEM scratch and return the whole plane."""
+    buf[...] = jnp.zeros(buf.shape, jnp.int32)
+    buf[:, :ref.shape[1]] = ref[...]
+    plane: jax.Array = buf[...]
+    return plane
+
+
+def decode_tile(
+    ptrs: jax.Array, deltas: jax.Array, out_vals: jax.Array, n_out: jax.Array,
+    pid: jax.Array | None, table: Any, cfg: FRConfig, k: int,
 ) -> jax.Array:
-    """``sub[:, rank]`` where ``inclass`` via chunked one-hot reduce."""
-    out = jnp.zeros(rank.shape, jnp.int32)
-    for c0 in range(0, cap, SLOT_CHUNK):
-        n = min(SLOT_CHUNK, cap - c0)
-        slots = jnp.arange(n, dtype=jnp.int32) + jnp.int32(c0)  # iota, not a const
-        oh = ((rank[:, :, None] == slots[None, None, :]) & inclass[:, :, None]).astype(jnp.int32)
-        out = out + (oh * sub[:, None, c0:c0 + n]).sum(axis=2)
-    return out
+    """Decode ``(T, P)``-widened blob planes -> ``(T, P)`` int32 words.
 
+    ``table(r, j)`` reads row ``r`` (bases, class) of the SMEM table for
+    base ``j``; ``n_out`` and ``pid`` are ``(T, 1)``."""
+    P = cfg.page_words
+    lane = lanes(ptrs.shape)
+    code = unpack_fields(ptrs, cfg.ptr_bits)
+    base_val = jnp.zeros_like(code)
+    cls_w = jnp.full_like(code, cfg.num_classes)      # non-base codes: no class
+    for j in range(k):
+        hit = code == j
+        base_val = jnp.where(hit, table(0, j), base_val)
+        cls_w = jnp.where(hit, table(1, j), cls_w)
 
-def _decode_kernel(
-    ptr_ref: Any, delta_ref: Any, oval_ref: Any, oidx_ref: Any, nout_ref: Any,
-    *refs: Any,
-    cfg: FRConfig, k_pad: int,
-) -> None:
-    prof_ref = refs[0] if cfg.num_profiles > 1 else None
-    bases_ref, cls_ref, x_ref = refs[-3:]
-    T, P = x_ref.shape
-    cap_out, wb = cfg.outlier_cap, cfg.word_bits
-    bases = bases_ref[...][0]                              # (k_pad,)
-    cls = cls_ref[...][0]
-
-    def unpack(p: jax.Array, bits: int, n: int) -> jax.Array:
-        per = 32 // bits
-        sh = (jnp.arange(per, dtype=jnp.uint32) * bits)[None, None, :]
-        fields = (p.astype(jnp.uint32)[:, :, None] >> sh) & jnp.uint32((1 << bits) - 1)
-        return fields.reshape(T, -1)[:, :n]
-
-    code = unpack(ptr_ref[...], cfg.ptr_bits, P).astype(jnp.int32)
-    active = code < cfg.num_bases
-    base_code = jnp.clip(code, 0, cfg.num_bases - 1)
-
-    # base value + word's width class via one-hot integer reduce (k_pad tiny)
-    onehot_b = (base_code[:, :, None] == jnp.arange(k_pad)[None, None, :]).astype(jnp.int32)
-    base_val = (onehot_b * bases[None, None, :]).sum(axis=2)
-    cls_w = (onehot_b * cls[None, None, :]).sum(axis=2)
-
-    # per-class sub-stream gather at the recomputed page-order ranks
-    packed = delta_ref[...]
+    def to_words(sub: jax.Array, member: jax.Array, live: jax.Array) -> jax.Array:
+        """Slot r of ``sub`` -> the lane of the r-th ``member`` word."""
+        rank = prefix_sum(member.astype(jnp.int32)) - 1
+        dist = compact([lane - rank], member, rank)[0]
+        return expand(sub, dist, live)
 
     def gather_deltas(profile: int) -> jax.Array:
-        delta = jnp.zeros((T, P), jnp.int32)
+        delta = jnp.zeros_like(code)
         for i, (w, cap, off) in enumerate(
             zip(cfg.width_set, cfg.profiles[profile],
                 cfg.class_lane_offsets_for(profile))
         ):
             if cap == 0:
                 continue
-            sub = unpack(packed[:, off:off + cap * w // 32], w, cap).astype(jnp.int32)
-            half = 1 << (w - 1)
-            sub = jnp.where(sub >= half, sub - (1 << w), sub)
-            inclass = active & (cls_w == i)
-            rank = _cumsum_lanes(inclass.astype(jnp.int32)) - 1
-            delta = delta + _gather_chunks(rank, inclass, sub, cap)
+            n_lanes = cap * w // 32
+            packed = pltpu.roll(deltas, P - off, 1) if off else deltas
+            sub = unpack_fields(jnp.where(lane < n_lanes, packed, 0), w)
+            sub = jnp.where(sub >= (1 << (w - 1)), sub - (1 << w), sub)
+            inclass = cls_w == i
+            delta = jnp.where(inclass, to_words(sub, inclass, lane < cap), delta)
         return delta
 
-    if cfg.num_profiles == 1:
+    if pid is None:
         delta = gather_deltas(0)
     else:   # per-page profile id selects the sub-stream layout
-        pid = prof_ref[...]                                # (T, 1)
-        delta = jnp.zeros((T, P), jnp.int32)
+        delta = jnp.zeros_like(code)
         for p in range(cfg.num_profiles):
             delta = jnp.where(pid == p, gather_deltas(p), delta)
 
     val = base_val + delta
-    if wb == 16:
+    if cfg.word_bits == 16:
         val = val & WORD16_MASK
     val = jnp.where(code == cfg.zero_code, 0, val)
+    is_out = code == cfg.outlier_code
+    oval = to_words(out_vals, is_out, lane < n_out)
+    return jnp.where(is_out, oval, val)
 
-    live = (jnp.arange(cap_out)[None, :] < nout_ref[...])       # (T, cap_out)
-    onehot_o = (
-        (jnp.arange(P, dtype=jnp.int32)[None, :, None] == oidx_ref[...][:, None, :])
-        & live[:, None, :]
-    )
-    out_contrib = (onehot_o.astype(jnp.int32) * oval_ref[...][:, None, :]).sum(axis=2)
-    is_out_pos = onehot_o.any(axis=2)
-    x_ref[...] = jnp.where(
-        is_out_pos, out_contrib, jnp.where(code == cfg.outlier_code, 0, val)
-    )
+
+def _decode_kernel(
+    ptr_ref: Any, delta_ref: Any, oval_ref: Any, nout_ref: Any, *refs: Any,
+    cfg: FRConfig, k: int,
+) -> None:
+    prof_ref = refs[0] if cfg.num_profiles > 1 else None
+    tab_ref, x_ref, buf = refs[-3:]
+    x_ref[...] = decode_tile(
+        _widen(ptr_ref, buf), _widen(delta_ref, buf), _widen(oval_ref, buf),
+        nout_ref[...], None if prof_ref is None else prof_ref[...],
+        lambda r, j: tab_ref[r * k + j], cfg, k)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "pages_per_tile", "interpret"))
@@ -119,37 +123,34 @@ def gbdi_decode_pallas(
     cfg: FRConfig,
     *,
     pages_per_tile: int = DEFAULT_PAGES_PER_TILE,
-    interpret: bool = True,
+    interpret: bool,               # True only off-TPU (correctness oracle)
 ) -> jax.Array:
     n_pages = blob["ptrs"].shape[0]
-    assert n_pages % pages_per_tile == 0
     _check_vmem(cfg, pages_per_tile)
     T, P, cap = pages_per_tile, cfg.page_words, cfg.outlier_cap
-    k_pad = k_padded(cfg)
-    bases_p, cls_p = pad_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
-    kernel = functools.partial(_decode_kernel, cfg=cfg, k_pad=k_pad)
+    k = cfg.num_bases
+    tab = smem_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
+    kernel = functools.partial(_decode_kernel, cfg=cfg, k=k)
     in_specs = [
         pl.BlockSpec((T, cfg.ptr_lanes), lambda i: (i, 0)),
         pl.BlockSpec((T, cfg.delta_lanes), lambda i: (i, 0)),
         pl.BlockSpec((T, cap), lambda i: (i, 0)),
-        pl.BlockSpec((T, cap), lambda i: (i, 0)),
         pl.BlockSpec((T, 1), lambda i: (i, 0)),
     ]
-    args = [blob["ptrs"], blob["deltas"], blob["out_vals"], blob["out_idx"],
-            blob["n_out"][:, None]]
+    args = [blob["ptrs"], blob["deltas"], blob["out_vals"], blob["n_out"][:, None]]
     if cfg.num_profiles > 1:   # adaptive: per-page profile id input
         in_specs.append(pl.BlockSpec((T, 1), lambda i: (i, 0)))
         args.append(blob["profile"][:, None])
-    in_specs += [
-        pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-        pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-    ]
-    args += [bases_p, cls_p]
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    args = [pad_pages(a, T) for a in args] + [tab]
+    n_padded = args[0].shape[0]
     return pl.pallas_call(
         kernel,
-        grid=(n_pages // T,),
+        grid=(n_padded // T,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((T, P), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pages, P), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n_padded, P), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((T, P), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(*args)
+    )(*args)[:n_pages]

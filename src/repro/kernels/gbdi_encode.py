@@ -1,28 +1,33 @@
 """Pallas TPU kernel: GBDI-FR v2 page encode.
 
 TPU adaptation of the paper's C/C++ bit-serial encoder: the bit loop
-becomes lane-parallel VPU arithmetic —
+becomes lane-parallel VPU arithmetic on ``(pages_per_tile, page_words)``
+tiles — pages on sublanes, words on lanes, every intermediate a 2-D
+``(T, P)`` array:
 
-* wrapping deltas against the global base table (resident in VMEM; the
-  table is tiny, <= 254 bases + their width classes, so it rides along
-  every tile);
-* narrowest-fitting-base selection as vector compares over the per-base
-  width classes (v2: each base carries a class from ``cfg.width_set``);
-* bucket compaction WITHOUT dynamic scatter (which does not lower on TPU):
-  a Hillis–Steele prefix sum ranks each width class's words in page order,
-  then one-hot integer multiply-reduces materialise the fixed-capacity
-  sub-streams chunk-by-chunk (``SLOT_CHUNK`` slots at a time, bounding the
-  transient (tile, page_words, chunk) cube).  Bucket overflow re-codes to
-  the narrowest fitting wider-class base, then to the outlier table —
-  bit-identical to the jnp oracle's spill chain;
-* fixed-width field packing as shifts + adds into int32 lanes.
+* base selection is a loop over the (tiny, <= 254-entry) base table read
+  as scalars from SMEM: each base contributes one elementwise wrapped
+  delta, fit test and running minimum, so the narrowest fitting base (and,
+  per width class, the narrowest fitting base of a strictly wider class —
+  the spill target) falls out of compare/select with first-index
+  tie-break, exactly the oracle's ``argmin``;
+* page-order ranks are Hillis–Steele prefix sums over lane rotations
+  (:func:`prefix_sum`);
+* bucket and outlier compaction WITHOUT dynamic scatter (which does not
+  lower on TPU): every kept word moves left by its distance to its slot,
+  one power-of-two lane rotation per distance bit, low bit first
+  (:func:`compact`) — collision-free because kept words keep their order;
+* fixed-width field packing is a shift by lane position, an OR over each
+  group of ``32 // bits`` neighbours, and a compaction of the group heads
+  (:func:`pack_fields`).
 
-BlockSpec tiling: ``(pages_per_tile, page_words)`` input tiles in VMEM.
-The VMEM budget is asserted in code (:func:`vmem_tile_bytes`), not prose:
-with the default FRConfig (2048-word pages, k_pad=16) a 4-page tile keeps
-the (tile, P, k_pad) delta cube at 4x2048x16x4 B = 512 KiB and the largest
-transient — the 4x2048x128x4 B = 4 MiB compaction chunk — comfortably
-inside the 16 MiB/core budget next to the packed outputs.
+All of it is bit-identical to the jnp oracle's spill chain.  The decoder
+(:mod:`repro.kernels.gbdi_decode`) runs the same moves in reverse.
+
+BlockSpec tiling: ``(pages_per_tile, page_words)`` input tiles in VMEM,
+``pages_per_tile`` a multiple of the 8-row int32 sublane tile; the wrapper
+pads the page count to whole tiles.  The VMEM budget is asserted in code
+(:func:`vmem_tile_bytes`), not prose.
 """
 from __future__ import annotations
 
@@ -32,40 +37,37 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.format import (
     WORD16_HALF,
     WORD16_MASK,
     BaseTable,
     TableLike,
+    as_base_table,
     class_indices,
     half_span,
 )
 from repro.core.gbdi_fr import FRConfig
 
-DEFAULT_PAGES_PER_TILE = 4
-SLOT_CHUNK = 128          # compaction one-hot slots per step (VMEM bound)
+DEFAULT_PAGES_PER_TILE = 8      # one int32 sublane tile
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
-
-
-def k_padded(cfg: FRConfig) -> int:
-    """Base-table padding to a lane-friendly multiple of 8."""
-    return max(8, -(-cfg.num_bases // 8) * 8)
+#: (T, P) int32 planes live at once in the encode kernel, counted from its
+#: body (words, per-base and per-alt running state, masks, rank and the
+#: compaction carries); the compiler's own report is the check
+#: (``tests/test_tpu_compile.py`` prints ``memory_analysis()``)
+_LIVE_PLANES = 32
 
 
 def vmem_tile_bytes(cfg: FRConfig, pages_per_tile: int) -> int:
-    """Conservative per-tile VMEM estimate for the encode/decode kernels."""
+    """Conservative per-tile VMEM estimate for the encode/decode kernels:
+    double-buffered I/O tiles plus the live ``(T, P)`` int32 planes (one
+    extra set per adaptive profile held until the per-page select)."""
     T, P, w = pages_per_tile, cfg.page_words, 4
-    cube = T * P * k_padded(cfg) * w            # delta/magnitude/cost cubes
-    chunk = T * P * SLOT_CHUNK * w              # compaction one-hot + product
-    out_oh = T * P * cfg.outlier_cap * w        # outlier table one-hot
-    blob = T * (cfg.ptr_lanes + cfg.delta_lanes + 2 * cfg.outlier_cap + 3) * w
-    io = T * P * w + blob
-    scratch = 8 * T * P * w                     # codes/ranks/masks etc.
-    # adaptive profiles: every candidate blob (plus its code/mask planes)
-    # is retained until the per-page select; transient chunks are reused
-    held = (cfg.num_profiles - 1) * (blob + 2 * T * P * w)
-    return io + 3 * cube + 2 * chunk + out_oh + scratch + held
+    blob = T * (cfg.ptr_lanes + cfg.delta_lanes + 2 * cfg.outlier_cap + 4) * w
+    io = 2 * (T * P * w + blob)
+    planes = (_LIVE_PLANES + 4 * (cfg.num_profiles - 1)) * T * P * w
+    return io + planes
 
 
 def _check_vmem(cfg: FRConfig, pages_per_tile: int) -> None:
@@ -77,136 +79,214 @@ def _check_vmem(cfg: FRConfig, pages_per_tile: int) -> None:
         )
 
 
-def pad_table(table: BaseTable, cfg: FRConfig) -> tuple[jax.Array, jax.Array]:
-    """(1, k_pad) padded bases + width-class indices for the kernels."""
-    k_pad = k_padded(cfg)
-    pad = k_pad - cfg.num_bases
-    bases = jnp.concatenate(
-        [table.bases.astype(jnp.int32), jnp.full((pad,), table.bases[0], jnp.int32)]
-    )[None, :]
+def smem_table(table: BaseTable, cfg: FRConfig) -> jax.Array:
+    """Flat int32 SMEM table, four rows of ``cfg.num_bases`` entries:
+    bases, width-class index (sentinel ``num_classes`` = dead entry),
+    width (``word_bits + 1`` when dead) and fit half-span (0 when dead,
+    so nothing fits)."""
+    wb = cfg.word_bits
     cls = class_indices(table.widths, cfg.width_set)
-    # padded entries carry the dead-entry sentinel, like foreign widths
-    cls = jnp.concatenate([cls, jnp.full((pad,), cfg.num_classes, jnp.int32)])[None, :]
-    return bases, cls
+    width = jnp.full(cls.shape, wb + 1, jnp.int32)
+    half = jnp.zeros(cls.shape, jnp.int32)
+    for i, w in enumerate(cfg.width_set):
+        width = jnp.where(cls == i, jnp.int32(w), width)
+        half = jnp.where(cls == i, jnp.int32(half_span(w)), half)
+    return jnp.concatenate([table.bases.astype(jnp.int32), cls, width, half])
 
 
-def _cumsum_lanes(y: jax.Array) -> jax.Array:
-    """Hillis–Steele inclusive prefix sum along axis 1 (vector-ops only)."""
+def pad_pages(x: jax.Array, pages_per_tile: int) -> jax.Array:
+    """Zero rows up to a whole number of tiles (stripped by the callers)."""
+    pad = (-x.shape[0]) % pages_per_tile
+    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
+
+
+# ---------------------------------------------------------------------------
+# lane primitives on (T, P) int32 tiles (shared with the decoder)
+# ---------------------------------------------------------------------------
+
+def lanes(shape: tuple[int, ...]) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def shift_right(y: jax.Array, s: int) -> jax.Array:
+    """``out[:, p] = y[:, p - s]``, zero-filled (a lane rotation + mask)."""
+    return jnp.where(lanes(y.shape) >= s, pltpu.roll(y, s, 1), 0)
+
+
+def shift_left(y: jax.Array, s: int) -> jax.Array:
+    """``out[:, p] = y[:, p + s]``, zero-filled."""
     n = y.shape[1]
+    return jnp.where(lanes(y.shape) < n - s, pltpu.roll(y, n - s, 1), 0)
+
+
+def prefix_sum(y: jax.Array) -> jax.Array:
+    """Hillis–Steele inclusive prefix sum along the lanes."""
     s = 1
-    while s < n:
-        shifted = jnp.pad(y, ((0, 0), (s, 0)))[:, :n]
-        y = y + shifted
+    while s < y.shape[1]:
+        y = y + shift_right(y, s)
         s *= 2
     return y
 
 
-def _class_map(cls: jax.Array, values: tuple[int, ...]) -> jax.Array:
-    """Static lookup ``values[cls]`` as vector selects (k_pad is tiny)."""
-    out = jnp.zeros(cls.shape, jnp.int32)
-    for i, v in enumerate(values):
-        out = jnp.where(cls == i, jnp.int32(v), out)
-    return out
+def compact(vals: list[jax.Array], keep: jax.Array, rank: jax.Array) -> list[jax.Array]:
+    """Move ``vals[:, p]`` where ``keep`` to lane ``rank[:, p]`` (the kept
+    words' page-order rank); every other lane ends zero.
+
+    Each kept word travels left by ``p - rank``, one power-of-two step per
+    set bit, low bit first.  Kept words keep their order, so no step ever
+    lands two words on one lane."""
+    n = keep.shape[1]
+    dist = jnp.where(keep, lanes(keep.shape) - rank, -1)   # -1: empty lane
+    vals = [jnp.where(keep, v, 0) for v in vals]
+    b = 1
+    while b < n:
+        go = (dist >= 0) & ((dist & b) != 0)
+        arrive = shift_left(go.astype(jnp.int32), b) != 0
+        vals = [jnp.where(arrive, shift_left(v, b), jnp.where(go, 0, v)) for v in vals]
+        dist = jnp.where(arrive, shift_left(dist, b), jnp.where(go, -1, dist))
+        b *= 2
+    return vals
 
 
-def _compact_chunks(
-    rank: jax.Array, keep: jax.Array, payload: jax.Array, cap: int
-) -> jax.Array:
-    """Scatter ``payload[keep]`` to slots ``rank`` of a (T, cap) sub-stream
-    via chunked one-hot multiply-reduce (no dynamic scatter on TPU)."""
-    cols = []
-    for c0 in range(0, cap, SLOT_CHUNK):
-        n = min(SLOT_CHUNK, cap - c0)
-        # arange(n) + c0, not arange(c0, c0+n): the latter is a captured
-        # constant, not an iota, and Pallas rejects non-scalar constants
-        slots = jnp.arange(n, dtype=jnp.int32) + jnp.int32(c0)
-        oh = ((rank[:, :, None] == slots[None, None, :]) & keep[:, :, None]).astype(jnp.int32)
-        cols.append((oh * payload[:, :, None]).sum(axis=1))
-    return jnp.concatenate(cols, axis=1)
+def expand(val: jax.Array, dist: jax.Array, live: jax.Array) -> jax.Array:
+    """Inverse of :func:`compact`: slot ``r`` (where ``live``) moves right by
+    ``dist[:, r]`` — the compaction's moves replayed high bit first."""
+    n = val.shape[1]
+    dist = jnp.where(live, dist, -1)
+    val = jnp.where(live, val, 0)
+    b = 1
+    while b * 2 < n:
+        b *= 2
+    while b >= 1:
+        go = (dist >= 0) & ((dist & b) != 0)
+        arrive = shift_right(go.astype(jnp.int32), b) != 0
+        val = jnp.where(arrive, shift_right(val, b), jnp.where(go, 0, val))
+        dist = jnp.where(arrive, shift_right(dist, b), jnp.where(go, -1, dist))
+        b //= 2
+    return val
 
+
+def pack_fields(fields: jax.Array, bits: int) -> jax.Array:
+    """``pack_lanes`` on a tile: field ``f`` (lane ``f``, ``< 2**bits``)
+    lands in lane ``f // per`` at bit ``(f % per) * bits``, ``per = 32 //
+    bits``.  Lanes past the packed words are zero when the fields past the
+    last one are."""
+    per = 32 // bits
+    lane = lanes(fields.shape)
+    g = fields << ((lane & (per - 1)) * bits)
+    s = 1
+    while s < per:
+        g = g | shift_left(g, s)
+        s *= 2
+    return compact([g], (lane & (per - 1)) == 0, lane >> _log2(per))[0]
+
+
+def _log2(n: int) -> int:
+    return n.bit_length() - 1
+
+
+def unpack_fields(packed: jax.Array, bits: int) -> jax.Array:
+    """Inverse of :func:`pack_fields`: lane ``f`` gets field ``f`` as an
+    unsigned value in ``[0, 2**bits)``."""
+    per = 32 // bits
+    n = packed.shape[1]
+    lane = lanes(packed.shape)
+    heads = expand(packed, lane * (per - 1), lane < n // per)
+    s = 1
+    while s < per:                 # copy each group head over its group
+        heads = heads | shift_right(heads, s)
+        s *= 2
+    sh = (lane & (per - 1)) * bits
+    return jax.lax.shift_right_logical(heads, sh) & ((1 << bits) - 1)
+
+
+# ---------------------------------------------------------------------------
+# kernel
+# ---------------------------------------------------------------------------
 
 def _encode_kernel(
-    x_ref: Any, bases_ref: Any, cls_ref: Any, *out_refs: Any,
-    cfg: FRConfig, k_pad: int,
+    x_ref: Any, tab_ref: Any, *out_refs: Any, cfg: FRConfig, k: int,
 ) -> None:
     ptr_ref, delta_ref, oval_ref, oidx_ref, nout_ref, nspill_ref, ndrop_ref = out_refs[:7]
     prof_ref = out_refs[7] if cfg.num_profiles > 1 else None
-    x = x_ref[...]                                   # (T, P) int32
-    bases = bases_ref[...][0]                        # (k_pad,) int32
-    cls = cls_ref[...][0]                            # (k_pad,) width-class idx
+    x = x_ref[...]                                   # (T, P) int32 words
     T, P = x.shape
-    wb, cap_out = cfg.word_bits, cfg.outlier_cap
-    BIG = jnp.int32(wb + 1)
+    wb, nc, cap_out = cfg.word_bits, cfg.num_classes, cfg.outlier_cap
+    big = jnp.int32(wb + 1)
+    lane = lanes(x.shape)
+    zeros = jnp.zeros_like(x)
 
-    d = x[:, :, None] - bases[None, None, :]         # (T, P, k_pad), wraps
-    if wb == 16:
-        d = ((d + WORD16_HALF) & WORD16_MASK) - WORD16_HALF
-    m = jnp.maximum(d, -d - 1)
-    # dead entries: table padding and foreign-width bases (sentinel class)
-    valid = ((jnp.arange(k_pad) < cfg.num_bases) & (cls < cfg.num_classes))[None, None, :]
-    halfs = _class_map(cls, tuple(half_span(w) for w in cfg.width_set))
-    fits = (m < halfs[None, None, :]) & valid
-    widths = _class_map(cls, cfg.width_set)
-    cost = jnp.where(fits, widths[None, None, :], BIG)   # (T, P, k_pad)
+    # running minima over the base table: the narrowest fitting base, and
+    # per class i < nc-1 the narrowest fitting base of a class > i (the
+    # spill target); strict < keeps the first index, like argmin
+    best = [jnp.full_like(x, wb + 1), zeros, zeros, zeros]  # cost, idx, cls, delta
+    alts = [[jnp.full_like(x, wb + 1), zeros, zeros, zeros] for _ in range(nc - 1)]
+    for j in range(k):
+        base, cj, width, half = (tab_ref[r * k + j] for r in range(4))
+        d = x - base
+        if wb == 16:
+            d = ((d + WORD16_HALF) & WORD16_MASK) - WORD16_HALF
+        cost = jnp.where(jnp.maximum(d, -d - 1) < half, width, big)
+        for i, st in [(-1, best)] + list(enumerate(alts)):
+            # scalar select, then a vector max: cost where class > i, else big
+            c = cost if i < 0 else jnp.maximum(cost, jnp.where(cj > i, 0, big))
+            win = c < st[0]
+            st[0] = jnp.where(win, c, st[0])
+            st[1] = jnp.where(win, j, st[1])
+            st[2] = jnp.where(win, cj, st[2])
+            st[3] = jnp.where(win, d, st[3])
 
-    sel0 = jnp.argmin(cost, axis=2).astype(jnp.int32)
-    found = jnp.take_along_axis(cost, sel0[:, :, None], axis=2)[:, :, 0] <= wb
+    found = best[0] <= wb
     is_zero = x == 0
     active0 = found & ~is_zero
-    out_cand0 = (~found) & (~is_zero)
-
-    # lane packing: shifts + adds (fields are disjoint)
-    def pack(vals: jax.Array, bits: int) -> jax.Array:
-        per = 32 // bits
-        y = vals.astype(jnp.uint32).reshape(T, -1, per)
-        sh = (jnp.arange(per, dtype=jnp.uint32) * bits)[None, None, :]
-        return (y << sh).sum(axis=2, dtype=jnp.uint32).astype(jnp.int32)
+    out_cand0 = ~found & ~is_zero
 
     def run_profile(caps: tuple[int, ...]) -> dict[str, jax.Array]:
         """Bucketing + spill chain under one cap profile (oracle parity)."""
-        sel, active, out_cand = sel0, active0, out_cand0
-        subs, n_spilled = [], jnp.zeros((T,), jnp.int32)
+        sel, cls_sel, dsel = best[1], best[2], best[3]
+        active, out_cand = active0, out_cand0
+        deltas = zeros
+        n_spilled = jnp.zeros((T, 1), jnp.int32)
+        off = 0
         for i, (w, cap) in enumerate(zip(cfg.width_set, caps)):
-            oh_sel = (sel[:, :, None] == jnp.arange(k_pad)[None, None, :]).astype(jnp.int32)
-            cls_sel = (oh_sel * cls[None, None, :]).sum(axis=2)
             inclass = active & (cls_sel == i)
-            rank = _cumsum_lanes(inclass.astype(jnp.int32)) - 1
+            rank = prefix_sum(inclass.astype(jnp.int32)) - 1
             keep = inclass & (rank < cap)
             over = inclass & ~keep
-            delta = jnp.take_along_axis(d, sel[:, :, None], axis=2)[:, :, 0]
-            payload = (jnp.where(keep, delta, 0) & ((1 << w) - 1)).astype(jnp.int32)
-            sub = _compact_chunks(rank, keep, payload, cap) if cap else jnp.zeros((T, 0), jnp.int32)
-            subs.append(sub)
-            wcost = jnp.where(cls[None, None, :] > i, cost, BIG)
-            alt = jnp.argmin(wcost, axis=2).astype(jnp.int32)
-            alt_ok = jnp.take_along_axis(wcost, alt[:, :, None], axis=2)[:, :, 0] <= wb
-            sel = jnp.where(over & alt_ok, alt, sel)
-            n_spilled = n_spilled + (over & alt_ok).sum(axis=1, dtype=jnp.int32)
-            newly_out = over & ~alt_ok
+            if cap:
+                sub = compact([dsel & ((1 << w) - 1)], keep, rank)[0]
+                packed = pack_fields(sub, w)
+                deltas = deltas | (pltpu.roll(packed, off, 1) if off else packed)
+                off += cap * w // 32
+            if i < nc - 1:
+                a_cost, a_sel, a_cls, a_d = alts[i]
+                spill = over & (a_cost <= wb)
+                sel = jnp.where(spill, a_sel, sel)
+                cls_sel = jnp.where(spill, a_cls, cls_sel)
+                dsel = jnp.where(spill, a_d, dsel)
+                n_spilled = n_spilled + spill.astype(jnp.int32).sum(axis=1, keepdims=True)
+                newly_out = over & ~spill
+            else:
+                newly_out = over
             active = active & ~newly_out
             out_cand = out_cand | newly_out
 
-        # outlier compaction (one-hot, scatter-free); overflow = dropped ->
-        # code stays outlier with no slot (decodes to 0)
-        pos = _cumsum_lanes(out_cand.astype(jnp.int32)) - 1
+        # outlier compaction in page order; overflow = dropped -> the word
+        # keeps the outlier code with no slot (decodes to 0)
+        pos = prefix_sum(out_cand.astype(jnp.int32)) - 1
         in_table = out_cand & (pos < cap_out)
-        dropped = out_cand & ~in_table
-        slots = jnp.arange(cap_out, dtype=jnp.int32)
-        onehot = ((pos[:, :, None] == slots[None, None, :]) & in_table[:, :, None]).astype(jnp.int32)
-        code = jnp.where(is_zero, jnp.int32(cfg.zero_code), sel)
-        code = jnp.where(out_cand, jnp.int32(cfg.outlier_code), code)
-        deltas = jnp.concatenate(
-            [pack(s, w) for s, w in zip(subs, cfg.width_set) if s.shape[1]], axis=1
-        )
-        deltas = jnp.pad(deltas, ((0, 0), (0, cfg.delta_lanes - deltas.shape[1])))
+        out_vals, out_idx = compact([x, lane], in_table, pos)
+        code = jnp.where(is_zero, cfg.zero_code, sel)
+        code = jnp.where(out_cand, cfg.outlier_code, code)
+        n_out = out_cand.astype(jnp.int32).sum(axis=1, keepdims=True)
         return {
-            "ptrs": pack(code.astype(jnp.uint32), cfg.ptr_bits),
+            "ptrs": pack_fields(code, cfg.ptr_bits),
             "deltas": deltas,
-            "out_vals": (onehot * x[:, :, None]).sum(axis=1),
-            "out_idx": (onehot * jnp.arange(P, dtype=jnp.int32)[None, :, None]).sum(axis=1),
-            "n_out": jnp.minimum(out_cand.sum(axis=1, dtype=jnp.int32), cap_out),
+            "out_vals": out_vals,
+            "out_idx": out_idx,
+            "n_out": jnp.minimum(n_out, cap_out),
             "n_spilled": n_spilled,
-            "n_dropped": dropped.sum(axis=1, dtype=jnp.int32),
+            "n_dropped": (out_cand & ~in_table).astype(jnp.int32).sum(axis=1, keepdims=True),
         }
 
     cands = [run_profile(caps) for caps in cfg.profiles]
@@ -218,30 +298,29 @@ def _encode_kernel(
         costs = [jnp.int32(cfg.drop_penalty_bits) * b["n_dropped"]
                  + jnp.int32(8 * cfg.compressed_bytes_for_profile(p))
                  for p, b in enumerate(cands)]
-        best, pid = costs[0], jnp.zeros((T,), jnp.int32)
+        best_cost, pid = costs[0], jnp.zeros((T, 1), jnp.int32)
         for p in range(1, cfg.num_profiles):
-            better = costs[p] < best
-            best = jnp.where(better, costs[p], best)
+            better = costs[p] < best_cost
+            best_cost = jnp.where(better, costs[p], best_cost)
             pid = jnp.where(better, jnp.int32(p), pid)
 
         def select(field: str) -> jax.Array:
             acc = cands[0][field]
-            sel_pid = pid[:, None] if acc.ndim == 2 else pid
             for p in range(1, cfg.num_profiles):
-                acc = jnp.where(sel_pid == p, cands[p][field], acc)
+                acc = jnp.where(pid == p, cands[p][field], acc)
             return acc
 
-        blob = {k: select(k) for k in cands[0]}
+        blob = {name: select(name) for name in cands[0]}
 
-    oval_ref[...] = blob["out_vals"]
-    oidx_ref[...] = blob["out_idx"]
-    nout_ref[...] = blob["n_out"][:, None]
-    nspill_ref[...] = blob["n_spilled"][:, None]
-    ndrop_ref[...] = blob["n_dropped"][:, None]
-    ptr_ref[...] = blob["ptrs"]
-    delta_ref[...] = blob["deltas"]
+    ptr_ref[...] = blob["ptrs"][:, :cfg.ptr_lanes]
+    delta_ref[...] = blob["deltas"][:, :cfg.delta_lanes]
+    oval_ref[...] = blob["out_vals"][:, :cap_out]
+    oidx_ref[...] = blob["out_idx"][:, :cap_out]
+    nout_ref[...] = blob["n_out"]
+    nspill_ref[...] = blob["n_spilled"]
+    ndrop_ref[...] = blob["n_dropped"]
     if prof_ref is not None:
-        prof_ref[...] = pid[:, None]
+        prof_ref[...] = pid
 
 
 @functools.partial(
@@ -253,28 +332,28 @@ def gbdi_encode_pallas(
     cfg: FRConfig,
     *,
     pages_per_tile: int = DEFAULT_PAGES_PER_TILE,
-    interpret: bool = True,        # CPU container: interpret; TPU: False
+    interpret: bool,               # True only off-TPU (correctness oracle)
 ) -> dict[str, jax.Array]:
-    from repro.core.format import as_base_table
-
     n_pages, P = x_pages.shape
-    assert P == cfg.page_words
-    assert n_pages % pages_per_tile == 0, "ops.py pads to tile multiple"
-    assert cfg.delta_lanes > 0, "kernel path needs at least one non-empty bucket"
+    if P != cfg.page_words:
+        raise ValueError(f"pages are {P} words, cfg.page_words={cfg.page_words}")
+    if cfg.delta_lanes <= 0:
+        raise ValueError("kernel path needs at least one non-empty bucket")
     _check_vmem(cfg, pages_per_tile)
     T, cap = pages_per_tile, cfg.outlier_cap
-    k_pad = k_padded(cfg)
-    bases_p, cls_p = pad_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
+    k = cfg.num_bases
+    tab = smem_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
+    x = pad_pages(x_pages, T)
+    n_tiles = x.shape[0] // T
 
-    grid = (n_pages // T,)
     out_shapes = [
-        jax.ShapeDtypeStruct((n_pages, cfg.ptr_lanes), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, cfg.delta_lanes), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, cap), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, cap), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, 1), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, 1), jnp.int32),
-        jax.ShapeDtypeStruct((n_pages, 1), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], cfg.ptr_lanes), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], cfg.delta_lanes), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], cap), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], cap), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], 1), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], 1), jnp.int32),
+        jax.ShapeDtypeStruct((x.shape[0], 1), jnp.int32),
     ]
     out_specs = [
         pl.BlockSpec((T, cfg.ptr_lanes), lambda i: (i, 0)),
@@ -286,21 +365,22 @@ def gbdi_encode_pallas(
         pl.BlockSpec((T, 1), lambda i: (i, 0)),
     ]
     if cfg.num_profiles > 1:   # adaptive: per-page profile id rides along
-        out_shapes.append(jax.ShapeDtypeStruct((n_pages, 1), jnp.int32))
+        out_shapes.append(jax.ShapeDtypeStruct((x.shape[0], 1), jnp.int32))
         out_specs.append(pl.BlockSpec((T, 1), lambda i: (i, 0)))
-    kernel = functools.partial(_encode_kernel, cfg=cfg, k_pad=k_pad)
+    kernel = functools.partial(_encode_kernel, cfg=cfg, k=k)
     outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((T, P), lambda i: (i, 0)),
-            pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x_pages, bases_p, cls_p)
+    )(x, tab)
+    outs = [o[:n_pages] for o in outs]
     ptrs, deltas, out_vals, out_idx, n_out, n_spilled, n_dropped = outs[:7]
     # match the oracle's blob layout
     blob = {

@@ -21,16 +21,15 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.format import WORD16_MASK, TableLike, as_base_table
+from repro.core.format import TableLike, as_base_table
 from repro.core.gbdi_fr import FRConfig
-from repro.kernels.gbdi_decode import _gather_chunks
+from repro.kernels.gbdi_decode import decode_tile
 from repro.kernels.gbdi_encode import (
-    SLOT_CHUNK,
+    _LIVE_PLANES,
     VMEM_BUDGET_BYTES,
-    _cumsum_lanes,
-    k_padded,
-    pad_table,
+    smem_table,
 )
 
 
@@ -38,15 +37,13 @@ def attn_vmem_tile_bytes(cfg: FRConfig, *, n_kv: int, hd: int, groups: int) -> i
     """Conservative per-grid-step VMEM estimate for the fused kernel:
     one K page + one V page decoded in-register next to the q/acc tiles."""
     w = 4
-    P, k_pad = cfg.page_words, k_padded(cfg)
+    P = cfg.page_words
     page_blob = (cfg.ptr_lanes + cfg.delta_lanes + 2 * cfg.outlier_cap + 1) * w
     io = (2 * page_blob                      # compressed K + V page tiles
-          + 2 * k_pad * w                    # base table + width classes
           + 2 * n_kv * groups * hd * w       # q in, acc out
           + 2 * n_kv * groups * w * 2)       # m/l scratch in + out
-    # transients of one _decode_words call: base one-hot, gather chunk,
-    # outlier one-hot, and codes/ranks/masks scratch
-    decode = (P * k_pad + P * SLOT_CHUNK + P * cfg.outlier_cap + 8 * P) * w
+    # transients of one decode_tile call on a one-page (1, P) plane
+    decode = _LIVE_PLANES * P * w
     kv = 2 * P * w                           # decoded K and V words as f32
     return io + 2 * decode + kv
 
@@ -62,63 +59,31 @@ def _check_attn_vmem(cfg: FRConfig, *, n_kv: int, hd: int, groups: int) -> None:
 
 
 def _decode_words(
-    ptrs: jax.Array, deltas: jax.Array, ovals: jax.Array, oidx: jax.Array,
-    n_out: jax.Array, bases: jax.Array, cls: jax.Array,
-    cfg: FRConfig, k_pad: int,
+    ptrs: jax.Array, deltas: jax.Array, ovals: jax.Array, n_out: jax.Array,
+    tab_ref: Any, cfg: FRConfig, k: int,
 ) -> jax.Array:
     """Inline GBDI-FR v2 page decode (1 page) -> (page_words,) int32 words."""
     P = cfg.page_words
 
-    def unpack(p: jax.Array, bits: int, n: int) -> jax.Array:
-        per = 32 // bits
-        sh = (jnp.arange(per, dtype=jnp.uint32) * bits)[None, :]
-        f = (p.astype(jnp.uint32)[:, None] >> sh) & jnp.uint32((1 << bits) - 1)
-        return f.reshape(-1)[:n]
+    def plane(v: jax.Array) -> jax.Array:
+        return jnp.concatenate([v, jnp.zeros((P - v.shape[0],), v.dtype)])[None, :]
 
-    code = unpack(ptrs, cfg.ptr_bits, P).astype(jnp.int32)
-    active = code < cfg.num_bases
-    onehot_b = (jnp.clip(code, 0, cfg.num_bases - 1)[:, None] == jnp.arange(k_pad)[None, :]).astype(jnp.int32)
-    base_val = (onehot_b * bases[None, :]).sum(axis=1)
-    cls_w = (onehot_b * cls[None, :]).sum(axis=1)
-
-    # per-width-class sub-stream gather at recomputed page-order ranks
-    delta = jnp.zeros((P,), jnp.int32)
-    for i, (w, cap, off) in enumerate(
-        zip(cfg.width_set, cfg.bucket_caps, cfg.class_lane_offsets)
-    ):
-        if cap == 0:
-            continue
-        sub = unpack(deltas[off:off + cap * w // 32], w, cap).astype(jnp.int32)
-        half = 1 << (w - 1)
-        sub = jnp.where(sub >= half, sub - (1 << w), sub)
-        inclass = active & (cls_w == i)
-        rank = _cumsum_lanes(inclass.astype(jnp.int32)[None, :]) - 1
-        delta = delta + _gather_chunks(rank, inclass[None, :], sub[None, :], cap)[0]
-
-    val = base_val + delta
-    if cfg.word_bits == 16:
-        val = val & WORD16_MASK
-    val = jnp.where(code == cfg.zero_code, 0, val)
-    live = jnp.arange(cfg.outlier_cap) < n_out
-    onehot_o = (jnp.arange(P, dtype=jnp.int32)[:, None] == oidx[None, :]) & live[None, :]
-    out_contrib = (onehot_o.astype(jnp.int32) * ovals[None, :]).sum(axis=1)
-    is_out = onehot_o.any(axis=1)
-    return jnp.where(is_out, out_contrib, jnp.where(code == cfg.outlier_code, 0, val))
+    words = decode_tile(plane(ptrs), plane(deltas), plane(ovals),
+                        jnp.reshape(n_out, (1, 1)), None,
+                        lambda r, j: tab_ref[r * k + j], cfg, k)
+    return words[0]
 
 
 def _kernel(
     pos_ref: Any, q_ref: Any,
-    kp_ref: Any, kd_ref: Any, kov_ref: Any, koi_ref: Any, kno_ref: Any,
-    vp_ref: Any, vd_ref: Any, vov_ref: Any, voi_ref: Any, vno_ref: Any,
-    bases_ref: Any, cls_ref: Any,
+    kp_ref: Any, kd_ref: Any, kov_ref: Any, kno_ref: Any,
+    vp_ref: Any, vd_ref: Any, vov_ref: Any, vno_ref: Any,
+    tab_ref: Any,
     acc_ref: Any, m_ref: Any, l_ref: Any,
-    *, cfg: FRConfig, k_pad: int, pt: int, n_kv: int, hd: int, groups: int,
+    *, cfg: FRConfig, k: int, pt: int, n_kv: int, hd: int, groups: int,
 ) -> None:
     s = pl.program_id(1)
-    n_slots = pl.num_programs(1)
     pos = pos_ref[0, 0]
-    bases = bases_ref[...][0]
-    cls = cls_ref[...][0]
 
     @pl.when(s == 0)
     def _init() -> None:
@@ -127,9 +92,9 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     kw = _decode_words(kp_ref[...][0, 0], kd_ref[...][0, 0], kov_ref[...][0, 0],
-                       koi_ref[...][0, 0], kno_ref[0, 0], bases, cls, cfg, k_pad)
+                       kno_ref[0, 0], tab_ref, cfg, k)
     vw = _decode_words(vp_ref[...][0, 0], vd_ref[...][0, 0], vov_ref[...][0, 0],
-                       voi_ref[...][0, 0], vno_ref[0, 0], bases, cls, cfg, k_pad)
+                       vno_ref[0, 0], tab_ref, cfg, k)
     K = jax.lax.bitcast_convert_type(kw.astype(jnp.uint16), jnp.bfloat16).reshape(pt, n_kv, hd)
     V = jax.lax.bitcast_convert_type(vw.astype(jnp.uint16), jnp.bfloat16).reshape(pt, n_kv, hd)
 
@@ -151,7 +116,6 @@ def _kernel(
     acc_ref[...] = acc_prev * alpha[..., None] + jnp.einsum(
         "bkgt,tkh->bkgh", p, V.astype(jnp.float32)
     )
-    del n_slots
 
 
 @functools.partial(
@@ -161,25 +125,27 @@ def paged_attention_decode(
     q: jax.Array,            # (B, Kv, G, hd) f32/bf16
     pages_k: dict[str, jax.Array], pages_v: dict[str, jax.Array],
     table: TableLike, pos: jax.Array,
-    cfg: FRConfig, *, n_kv: int, hd: int, groups: int, interpret: bool = True,
+    cfg: FRConfig, *, n_kv: int, hd: int, groups: int, interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Returns un-normalised (acc (B,Kv,G,hd) f32, m (B,Kv,G), l (B,Kv,G))."""
     B, n_slots = pages_k["ptrs"].shape[:2]
+    if cfg.page_words % (n_kv * hd):
+        raise ValueError(f"a {n_kv}x{hd}-word KV row does not tile a "
+                         f"{cfg.page_words}-word page")
     pt = cfg.page_words // (n_kv * hd)
-    assert pt >= 1 and cfg.page_words % (n_kv * hd) == 0
     # the streaming kernel decodes with the static profile-0 layout; the
     # serving KV configs are single-profile (adaptive pages go through
     # kernels.xla.paged_attention_decode, which selects per page)
     assert cfg.num_profiles == 1, "Pallas paged-attn needs a single-profile cfg"
     _check_attn_vmem(cfg, n_kv=n_kv, hd=hd, groups=groups)
-    k_pad = k_padded(cfg)
-    bases_p, cls_p = pad_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
+    k = cfg.num_bases
+    tab = smem_table(as_base_table(table, default_width=cfg.widest_bits), cfg)
     pos_arr = jnp.full((1, 1), pos, jnp.int32)
 
     def page_specs(lanes: int) -> pl.BlockSpec:
         return pl.BlockSpec((1, 1, lanes), lambda b, s: (b, s, 0))
     kernel = functools.partial(
-        _kernel, cfg=cfg, k_pad=k_pad, pt=pt, n_kv=n_kv, hd=hd, groups=groups
+        _kernel, cfg=cfg, k=k, pt=pt, n_kv=n_kv, hd=hd, groups=groups
     )
     acc, m, l = pl.pallas_call(
         kernel,
@@ -188,13 +154,12 @@ def paged_attention_decode(
             pl.BlockSpec((1, 1), lambda b, s: (0, 0)),                      # pos
             pl.BlockSpec((1, n_kv, groups, hd), lambda b, s: (b, 0, 0, 0)),  # q
             page_specs(cfg.ptr_lanes), page_specs(cfg.delta_lanes),
-            page_specs(cfg.outlier_cap), page_specs(cfg.outlier_cap),
+            page_specs(cfg.outlier_cap),
             pl.BlockSpec((1, 1), lambda b, s: (b, s)),                       # k n_out
             page_specs(cfg.ptr_lanes), page_specs(cfg.delta_lanes),
-            page_specs(cfg.outlier_cap), page_specs(cfg.outlier_cap),
+            page_specs(cfg.outlier_cap),
             pl.BlockSpec((1, 1), lambda b, s: (b, s)),                       # v n_out
-            pl.BlockSpec((1, k_pad), lambda b, s: (0, 0)),                   # bases
-            pl.BlockSpec((1, k_pad), lambda b, s: (0, 0)),                   # width cls
+            pl.BlockSpec(memory_space=pltpu.SMEM),                          # base table
         ],
         out_specs=(
             pl.BlockSpec((1, n_kv, groups, hd), lambda b, s: (b, 0, 0, 0)),
@@ -209,9 +174,9 @@ def paged_attention_decode(
         interpret=interpret,
     )(
         pos_arr, q.astype(jnp.float32),
-        pages_k["ptrs"], pages_k["deltas"], pages_k["out_vals"], pages_k["out_idx"], pages_k["n_out"],
-        pages_v["ptrs"], pages_v["deltas"], pages_v["out_vals"], pages_v["out_idx"], pages_v["n_out"],
-        bases_p, cls_p,
+        pages_k["ptrs"], pages_k["deltas"], pages_k["out_vals"], pages_k["n_out"],
+        pages_v["ptrs"], pages_v["deltas"], pages_v["out_vals"], pages_v["n_out"],
+        tab,
     )
     return acc, m, l
 

@@ -25,7 +25,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.format import TableLike
 from repro.core.gbdi_fr import (
@@ -34,7 +33,7 @@ from repro.core.gbdi_fr import (
     tensor_to_pages,
 )
 from repro.kernels.gbdi_decode import gbdi_decode_pallas
-from repro.kernels.gbdi_encode import DEFAULT_PAGES_PER_TILE, gbdi_encode_pallas
+from repro.kernels.gbdi_encode import gbdi_encode_pallas
 from repro.kernels import ref as _ref
 from repro.kernels import xla as _xla
 
@@ -81,11 +80,7 @@ def decode_pages(
 def encode_tensor(
     x: jax.Array, table: TableLike, cfg: FRConfig, backend: str = "auto"
 ) -> tuple[dict[str, jax.Array], dict[str, Any]]:
-    backend = resolve_backend(backend)
     pages, meta = tensor_to_pages(x, cfg)
-    pad = (-pages.shape[0]) % DEFAULT_PAGES_PER_TILE if backend == "kernel" else 0
-    if pad:
-        pages = jnp.pad(pages, ((0, pad), (0, 0)))
     meta["n_pages"] = pages.shape[0]
     return encode_pages(pages, table, cfg, backend), meta
 

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from repro.core.format import TableLike
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels import xla as _xla
-from repro.kernels.xla import BLOB_TRAILING, PreparedTable, prepare_table
+from repro.kernels.xla import BLOB_TRAILING, PreparedTable, prepare_table, under_trace
 
 
 def device_count() -> int:
@@ -68,11 +68,6 @@ def auto_shards() -> int:
     docstring has the full table).
     """
     return max(1, min(device_count(), os.cpu_count() or 1))
-
-
-def _is_traced(*leaves: Any) -> bool:
-    clean = bool(jax.core.trace_state_clean())
-    return not clean or any(isinstance(v, jax.core.Tracer) for v in leaves)
 
 
 def _pad_rows(flat: jax.Array, shards: int) -> tuple[jax.Array, int]:
@@ -109,7 +104,7 @@ def encode_pages(
     trace this is exactly :func:`repro.kernels.xla.encode_pages`.
     """
     prep = prepare_table(table, cfg)
-    if _is_traced(x_pages, *prep):
+    if under_trace(x_pages, *prep):
         return _xla.encode_pages(x_pages, prep, cfg)
     devs = _resolve_devices(devices)
     lead = x_pages.shape[:-1]
@@ -248,7 +243,7 @@ def decode_pages(
     prep = prepare_table(table, cfg)
     udt = jnp.uint16 if cfg.word_bits == 16 else jnp.uint32
     leaves = jax.tree_util.tree_leaves(blob)
-    if _is_traced(*leaves, *prep):
+    if under_trace(*leaves, *prep):
         words = _xla.decode_pages(blob, prep, cfg)
         # under a trace the cast fuses into the caller's program anyway
         return words.astype(udt) if unsigned else words

@@ -55,6 +55,15 @@ from repro.core.format import TableLike, as_base_table
 from repro.core.gbdi_fr import FRConfig, pack_lanes, unpack_lanes
 
 
+def under_trace(*leaves: Any) -> bool:
+    """True inside any active trace (jit, vmap, shard_map, a ``lax.cond``
+    branch) or when any of ``leaves`` is a tracer.  Under a trace even ops
+    on concrete arrays yield trace-local tracers, so eager-only shortcuts
+    (memoized device constants, per-device dispatch) must step aside."""
+    return (not jax.core.trace_ctx.is_top_level()
+            or any(isinstance(leaf, jax.core.Tracer) for leaf in leaves))
+
+
 class PreparedTable(NamedTuple):
     """Device-resident table constants: bases, widths, width-class codes."""
 
@@ -127,10 +136,8 @@ def prepare_table(table: TableLike | PreparedTable, cfg: FRConfig) -> PreparedTa
     if isinstance(table, PreparedTable):
         return table
     leaves = jax.tree_util.tree_leaves(table)
-    # Under any active trace (jit/vmap/cond branch), even ops on concrete
-    # arrays yield trace-local tracers — never cache those across traces.
-    if (any(isinstance(leaf, jax.core.Tracer) for leaf in leaves)
-            or not jax.core.trace_state_clean()):
+    # never cache trace-local tracers across traces
+    if under_trace(*leaves):
         return _build_prepared(table, cfg)
     key = (_table_digest(leaves), type(table).__name__,
            cfg.width_set, cfg.word_bits, cfg.widest_bits)
@@ -587,10 +594,7 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
     outer trace the same calls inline into the caller's single program.
     Blobs are bit-identical to the oracle either way.
     """
-    eager = (jax.core.trace_state_clean()
-             and not any(isinstance(leaf, jax.core.Tracer)
-                         for leaf in (x, *prep)))
-    const = _const_stages(prep, cfg) if eager else None
+    const = None if under_trace(x, *prep) else _const_stages(prep, cfg)
     if const is not None:
         sel, cls_sel, active, out_cand, is_zero, alts = const.assign(x)
     else:
@@ -971,8 +975,12 @@ def paged_attention_decode(
     — un-normalised ``(acc, m, l)`` over *full* pages only; the caller
     attends over the raw tail and merges with ``merge_softmax``.  Unlike
     the Pallas kernel this materialises decoded K/V in HBM (no VMEM
-    streaming win), but it is fully compiled off-TPU.
+    streaming win), but it is fully compiled off-TPU.  A KV row must tile
+    a page: rows wider than a page (deepseek-7b's 32x128 words) raise.
     """
+    if cfg.page_words % (n_kv * hd):
+        raise ValueError(f"a {n_kv}x{hd}-word KV row does not tile a "
+                         f"{cfg.page_words}-word page")
     prep = prepare_table(table, cfg)
     return _paged_attn(q, pages_k, pages_v, prep, jnp.asarray(pos, jnp.int32),
                        cfg, n_kv, hd, groups)

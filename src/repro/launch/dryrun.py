@@ -1,10 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the production meshes need 512 placeholder host devices.
+``main`` forces 512 placeholder host devices through ``XLA_FLAGS`` before
+jax initialises its backend (the production meshes need them); importing
+this module changes nothing.
 
 Per cell this lowers the real step function (train_step / prefill /
 decode_step) against ShapeDtypeStruct inputs with full production
@@ -23,6 +21,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -32,7 +31,7 @@ import jax
 from repro.configs import ARCHS, get_config
 from repro.distributed import sharding as shd
 from repro.launch import hlo_stats, specs
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16, make_production_mesh
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, chip_peaks, make_production_mesh
 from repro.models.api import build_model
 from repro.models.config import SHAPES, ModelConfig, ShapeConfig
 from repro.optim import adamw
@@ -116,8 +115,6 @@ def analyse(cfg: ModelConfig, sc: ShapeConfig, mesh_name: str, lowered, compile_
     bytes_accessed = stats["hbm_bytes"] * dtype_scale
     coll = {k: v * dtype_scale for k, v in stats["collectives"].items()}
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # jax 0.4.x: one dict per program
-        xla_cost = xla_cost[0] if xla_cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {
@@ -136,9 +133,10 @@ def analyse(cfg: ModelConfig, sc: ShapeConfig, mesh_name: str, lowered, compile_
     model_flops_global = mult * n_active * toks
     model_flops_per_chip = model_flops_global / n_chips
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll.get("total", 0.0) / ICI_BW_PER_LINK
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
+    compute_s = flops / peaks.flops_bf16
+    memory_s = bytes_accessed / peaks.hbm_bytes_s
+    collective_s = coll.get("total", 0.0) / peaks.ici_bytes_s_per_link
     dominant = max(
         ("compute", compute_s), ("memory", memory_s), ("collective", collective_s),
         key=lambda kv: kv[1],
@@ -189,8 +187,10 @@ def run_cell(
         import dataclasses
 
         if mesh_shape is not None:
-            mesh = jax.make_mesh(mesh_shape, ("data", "model") if len(mesh_shape) == 2
-                                 else ("pod", "data", "model"))
+            mesh = jax.make_mesh(
+                mesh_shape, ("data", "model") if len(mesh_shape) == 2
+                else ("pod", "data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh_shape))
             n_chips = 1
             for s in mesh_shape:
                 n_chips *= s
@@ -230,6 +230,8 @@ def run_cell(
 
 
 def main() -> None:
+    # must precede the first backend initialisation (device count locks)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -20,6 +20,8 @@ from repro.training.trainer import Trainer, TrainerConfig
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--reduced", action="store_true", help="CPU-sized config")
@@ -32,6 +34,7 @@ def main():
     ap.add_argument("--n-micro", type=int, default=1)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)

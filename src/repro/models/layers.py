@@ -35,17 +35,13 @@ def constrain_batch(x: jax.Array, cfg: ModelConfig, *extra) -> jax.Array:
 def _axes_size(axes: tuple) -> int:
     import numpy as _np
 
-    # jax >= 0.5 exposes the ambient mesh as jax.sharding.get_abstract_mesh();
-    # on 0.4.x the `with mesh:` context only sets thread_resources.
-    mesh = None
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        mesh = get_am()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        # a `with mesh:` context sets only the physical mesh
         from jax._src import mesh as _mesh_lib
 
         mesh = _mesh_lib.thread_resources.env.physical_mesh
-    if mesh is None or getattr(mesh, "empty", True):
+    if mesh.empty:
         return 1
     return int(_np.prod([mesh.shape.get(a, 1) for a in axes]))
 

@@ -64,7 +64,8 @@ from repro.models.config import ShapeConfig
 
 cfg = dataclasses.replace(reduced(ARCHS["gemma3-12b"]), dtype="float32")
 sc = ShapeConfig("tiny_train", seq_len=64, global_batch=4, kind="train")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 lowered = lower_cell(cfg, sc, mesh, n_micro=1)
 compiled = lowered.compile()
 rec = analyse(cfg, sc, "tiny", lowered, 0.0, compiled, n_chips=4)

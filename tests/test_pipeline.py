@@ -315,5 +315,8 @@ def test_throughput_row_marks_truncation_and_roofline():
     assert row["truncated"] and row["n_bytes_requested"] == 2 << 20
     assert row["devices"] == jax.local_device_count()
     assert row["bytes_moved"] > row["n_bytes"]
-    assert row["peak_bytes_s"] == roofline_peak_bytes_s() == 819e9
-    assert 0 < row["enc_roofline_frac"] < 1
+    # a host rate never goes under a chip's roofline: no published peak
+    # for the CPU, so the roofline columns are null
+    assert row["device_kind"] == jax.devices()[0].device_kind
+    assert row["peak_bytes_s"] is roofline_peak_bytes_s(row["device_kind"]) is None
+    assert row["enc_roofline_frac"] is None and row["dec_roofline_frac"] is None

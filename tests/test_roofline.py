@@ -53,10 +53,11 @@ def test_peak_bytes_per_s_finite():
     assert isinstance(peak, float)
     assert math.isfinite(peak)
     assert peak > 0
-    # it must be the mesh module's HBM constant, not a re-derived number
-    from repro.launch.mesh import HBM_BW
+    # it must be the peak table's entry for the modelled chip, not a
+    # re-derived number
+    from repro.launch.mesh import DRYRUN_DEVICE_KIND, chip_peaks
 
-    assert peak == float(HBM_BW)
+    assert peak == chip_peaks(DRYRUN_DEVICE_KIND).hbm_bytes_s
 
 
 def test_ideal_step_terms_positive_and_finite():

@@ -321,3 +321,21 @@ def test_throughput_harness_smoke(tmp_path):
     assert back["bench"] == "throughput" and len(back["rows"]) == 2
     assert back["auto_backend"] == "xla"
     assert {"workload", "codec", "enc_gib_s", "dec_gib_s"} <= set(back["rows"][0])
+
+
+def test_paged_attention_rejects_rows_wider_than_a_page():
+    """A 32x128-word row (deepseek-7b) spans two 2048-word pages: the
+    paged path must refuse it, not attend over zero tokens per page."""
+    from repro.serving.kv_cache import KV_FR
+
+    n_kv, hd, B, slots = 32, 128, 1, 2
+    pages = {"ptrs": jnp.zeros((B, slots, KV_FR.ptr_lanes), jnp.int32),
+             "deltas": jnp.zeros((B, slots, KV_FR.delta_lanes), jnp.int32),
+             "out_vals": jnp.zeros((B, slots, KV_FR.outlier_cap), jnp.int32),
+             "out_idx": jnp.zeros((B, slots, KV_FR.outlier_cap), jnp.int32),
+             "n_out": jnp.zeros((B, slots), jnp.int32)}
+    table = jnp.zeros((KV_FR.num_bases,), jnp.int32)
+    q = jnp.zeros((B, n_kv, 1, hd), jnp.float32)
+    with pytest.raises(ValueError, match="does not tile"):
+        xla.paged_attention_decode(q, pages, pages, table, jnp.int32(0), KV_FR,
+                                   n_kv=n_kv, hd=hd, groups=1)
