@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.format import WORD16_MASK, TableLike, as_base_table
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels.gbdi_encode import (
@@ -58,13 +59,14 @@ def decode_tile(
     base ``j``; ``n_out`` and ``pid`` are ``(T, 1)``."""
     P = cfg.page_words
     lane = lanes(ptrs.shape)
-    code = unpack_fields(ptrs, cfg.ptr_bits)
-    base_val = jnp.zeros_like(code)
-    cls_w = jnp.full_like(code, cfg.num_classes)      # non-base codes: no class
-    for j in range(k):
-        hit = code == j
-        base_val = jnp.where(hit, table(0, j), base_val)
-        cls_w = jnp.where(hit, table(1, j), cls_w)
+    with jax.named_scope(obs.DECODE_POINTERS):
+        code = unpack_fields(ptrs, cfg.ptr_bits)
+        base_val = jnp.zeros_like(code)
+        cls_w = jnp.full_like(code, cfg.num_classes)      # non-base codes: no class
+        for j in range(k):
+            hit = code == j
+            base_val = jnp.where(hit, table(0, j), base_val)
+            cls_w = jnp.where(hit, table(1, j), cls_w)
 
     def to_words(sub: jax.Array, member: jax.Array, live: jax.Array) -> jax.Array:
         """Slot r of ``sub`` -> the lane of the r-th ``member`` word."""
@@ -88,20 +90,22 @@ def decode_tile(
             delta = jnp.where(inclass, to_words(sub, inclass, lane < cap), delta)
         return delta
 
-    if pid is None:
-        delta = gather_deltas(0)
-    else:   # per-page profile id selects the sub-stream layout
-        delta = jnp.zeros_like(code)
-        for p in range(cfg.num_profiles):
-            delta = jnp.where(pid == p, gather_deltas(p), delta)
+    with jax.named_scope(obs.DECODE_BUCKETS):
+        if pid is None:
+            delta = gather_deltas(0)
+        else:   # per-page profile id selects the sub-stream layout
+            delta = jnp.zeros_like(code)
+            for p in range(cfg.num_profiles):
+                delta = jnp.where(pid == p, gather_deltas(p), delta)
+        val = base_val + delta
+        if cfg.word_bits == 16:
+            val = val & WORD16_MASK
 
-    val = base_val + delta
-    if cfg.word_bits == 16:
-        val = val & WORD16_MASK
-    val = jnp.where(code == cfg.zero_code, 0, val)
-    is_out = code == cfg.outlier_code
-    oval = to_words(out_vals, is_out, lane < n_out)
-    return jnp.where(is_out, oval, val)
+    with jax.named_scope(obs.DECODE_OUTLIERS):
+        val = jnp.where(code == cfg.zero_code, 0, val)
+        is_out = code == cfg.outlier_code
+        oval = to_words(out_vals, is_out, lane < n_out)
+        return jnp.where(is_out, oval, val)
 
 
 def _decode_kernel(
@@ -110,9 +114,10 @@ def _decode_kernel(
 ) -> None:
     prof_ref = refs[0] if cfg.num_profiles > 1 else None
     tab_ref, x_ref, buf = refs[-3:]
+    with jax.named_scope(obs.DECODE_WIDEN):
+        planes = _widen(ptr_ref, buf), _widen(delta_ref, buf), _widen(oval_ref, buf)
     x_ref[...] = decode_tile(
-        _widen(ptr_ref, buf), _widen(delta_ref, buf), _widen(oval_ref, buf),
-        nout_ref[...], None if prof_ref is None else prof_ref[...],
+        *planes, nout_ref[...], None if prof_ref is None else prof_ref[...],
         lambda r, j: tab_ref[r * k + j], cfg, k)
 
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.format import (
     WORD16_HALF,
     WORD16_MASK,
@@ -216,101 +217,115 @@ def _encode_kernel(
     lane = lanes(x.shape)
     zeros = jnp.zeros_like(x)
 
-    # running minima over the base table: the narrowest fitting base, and
-    # per class i < nc-1 the narrowest fitting base of a class > i (the
-    # spill target); strict < keeps the first index, like argmin
-    best = [jnp.full_like(x, wb + 1), zeros, zeros, zeros]  # cost, idx, cls, delta
-    alts = [[jnp.full_like(x, wb + 1), zeros, zeros, zeros] for _ in range(nc - 1)]
-    for j in range(k):
-        base, cj, width, half = (tab_ref[r * k + j] for r in range(4))
-        d = x - base
-        if wb == 16:
-            d = ((d + WORD16_HALF) & WORD16_MASK) - WORD16_HALF
-        cost = jnp.where(jnp.maximum(d, -d - 1) < half, width, big)
-        for i, st in [(-1, best)] + list(enumerate(alts)):
-            # scalar select, then a vector max: cost where class > i, else big
-            c = cost if i < 0 else jnp.maximum(cost, jnp.where(cj > i, 0, big))
-            win = c < st[0]
-            st[0] = jnp.where(win, c, st[0])
-            st[1] = jnp.where(win, j, st[1])
-            st[2] = jnp.where(win, cj, st[2])
-            st[3] = jnp.where(win, d, st[3])
+    with jax.named_scope(obs.ENCODE_CLASSIFY):
+        # running minima over the base table: the narrowest fitting base, and
+        # per class i < nc-1 the narrowest fitting base of a class > i (the
+        # spill target); strict < keeps the first index, like argmin
+        best = [jnp.full_like(x, wb + 1), zeros, zeros, zeros]  # cost, idx, cls, delta
+        alts = [[jnp.full_like(x, wb + 1), zeros, zeros, zeros] for _ in range(nc - 1)]
+        for j in range(k):
+            base, cj, width, half = (tab_ref[r * k + j] for r in range(4))
+            d = x - base
+            if wb == 16:
+                d = ((d + WORD16_HALF) & WORD16_MASK) - WORD16_HALF
+            cost = jnp.where(jnp.maximum(d, -d - 1) < half, width, big)
+            for i, st in [(-1, best)] + list(enumerate(alts)):
+                # scalar select, then a vector max: cost where class > i, else big
+                c = cost if i < 0 else jnp.maximum(cost, jnp.where(cj > i, 0, big))
+                win = c < st[0]
+                st[0] = jnp.where(win, c, st[0])
+                st[1] = jnp.where(win, j, st[1])
+                st[2] = jnp.where(win, cj, st[2])
+                st[3] = jnp.where(win, d, st[3])
 
-    found = best[0] <= wb
-    is_zero = x == 0
-    active0 = found & ~is_zero
-    out_cand0 = ~found & ~is_zero
+        found = best[0] <= wb
+        is_zero = x == 0
+        active0 = found & ~is_zero
+        out_cand0 = ~found & ~is_zero
 
     def run_profile(caps: tuple[int, ...]) -> dict[str, jax.Array]:
         """Bucketing + spill chain under one cap profile (oracle parity)."""
-        sel, cls_sel, dsel = best[1], best[2], best[3]
-        active, out_cand = active0, out_cand0
-        deltas = zeros
-        n_spilled = jnp.zeros((T, 1), jnp.int32)
-        off = 0
-        for i, (w, cap) in enumerate(zip(cfg.width_set, caps)):
-            inclass = active & (cls_sel == i)
-            rank = prefix_sum(inclass.astype(jnp.int32)) - 1
-            keep = inclass & (rank < cap)
-            over = inclass & ~keep
-            if cap:
-                sub = compact([dsel & ((1 << w) - 1)], keep, rank)[0]
-                packed = pack_fields(sub, w)
-                deltas = deltas | (pltpu.roll(packed, off, 1) if off else packed)
-                off += cap * w // 32
-            if i < nc - 1:
-                a_cost, a_sel, a_cls, a_d = alts[i]
-                spill = over & (a_cost <= wb)
-                sel = jnp.where(spill, a_sel, sel)
-                cls_sel = jnp.where(spill, a_cls, cls_sel)
-                dsel = jnp.where(spill, a_d, dsel)
-                n_spilled = n_spilled + spill.astype(jnp.int32).sum(axis=1, keepdims=True)
-                newly_out = over & ~spill
-            else:
-                newly_out = over
-            active = active & ~newly_out
-            out_cand = out_cand | newly_out
+        with jax.named_scope(obs.ENCODE_BUCKETS):
+            sel, cls_sel, dsel = best[1], best[2], best[3]
+            active, out_cand = active0, out_cand0
+            deltas = zeros
+            n_spilled = jnp.zeros((T, 1), jnp.int32)
+            off = 0
+            for i, (w, cap) in enumerate(zip(cfg.width_set, caps)):
+                inclass = active & (cls_sel == i)
+                rank = prefix_sum(inclass.astype(jnp.int32)) - 1
+                keep = inclass & (rank < cap)
+                over = inclass & ~keep
+                if cap:
+                    sub = compact([dsel & ((1 << w) - 1)], keep, rank)[0]
+                    packed = pack_fields(sub, w)
+                    deltas = deltas | (pltpu.roll(packed, off, 1) if off else packed)
+                    off += cap * w // 32
+                if i < nc - 1:
+                    a_cost, a_sel, a_cls, a_d = alts[i]
+                    spill = over & (a_cost <= wb)
+                    sel = jnp.where(spill, a_sel, sel)
+                    cls_sel = jnp.where(spill, a_cls, cls_sel)
+                    dsel = jnp.where(spill, a_d, dsel)
+                    n_spilled = n_spilled + spill.astype(jnp.int32).sum(axis=1, keepdims=True)
+                    newly_out = over & ~spill
+                else:
+                    newly_out = over
+                active = active & ~newly_out
+                out_cand = out_cand | newly_out
 
         # outlier compaction in page order; overflow = dropped -> the word
-        # keeps the outlier code with no slot (decodes to 0)
-        pos = prefix_sum(out_cand.astype(jnp.int32)) - 1
-        in_table = out_cand & (pos < cap_out)
-        out_vals, out_idx = compact([x, lane], in_table, pos)
-        code = jnp.where(is_zero, cfg.zero_code, sel)
-        code = jnp.where(out_cand, cfg.outlier_code, code)
-        n_out = out_cand.astype(jnp.int32).sum(axis=1, keepdims=True)
+        # keeps the outlier code with no slot (decodes to 0).  The outlier
+        # and pointer scopes alternate to keep the statements' order: the
+        # TPU compiler's schedule follows it, and reordered, this kernel
+        # compiles to more bundles per grid step.
+        with jax.named_scope(obs.ENCODE_OUTLIERS):
+            pos = prefix_sum(out_cand.astype(jnp.int32)) - 1
+            in_table = out_cand & (pos < cap_out)
+            out_vals, out_idx = compact([x, lane], in_table, pos)
+        with jax.named_scope(obs.ENCODE_POINTERS):
+            code = jnp.where(is_zero, cfg.zero_code, sel)
+            code = jnp.where(out_cand, cfg.outlier_code, code)
+        with jax.named_scope(obs.ENCODE_OUTLIERS):
+            n_out = out_cand.astype(jnp.int32).sum(axis=1, keepdims=True)
+        with jax.named_scope(obs.ENCODE_POINTERS):
+            ptrs = pack_fields(code, cfg.ptr_bits)
+        with jax.named_scope(obs.ENCODE_OUTLIERS):
+            n_out = jnp.minimum(n_out, cap_out)
+            n_dropped = (out_cand & ~in_table).astype(jnp.int32).sum(axis=1, keepdims=True)
         return {
-            "ptrs": pack_fields(code, cfg.ptr_bits),
+            "ptrs": ptrs,
             "deltas": deltas,
             "out_vals": out_vals,
             "out_idx": out_idx,
-            "n_out": jnp.minimum(n_out, cap_out),
+            "n_out": n_out,
             "n_spilled": n_spilled,
-            "n_dropped": (out_cand & ~in_table).astype(jnp.int32).sum(axis=1, keepdims=True),
+            "n_dropped": n_dropped,
         }
 
     cands = [run_profile(caps) for caps in cfg.profiles]
     if cfg.num_profiles == 1:
         blob, pid = cands[0], None
     else:
-        # per-page argmin of the effective encoded size, first-wins ties —
-        # identical cost + tie-break to cfg.profile_cost_bits (oracle/xla)
-        costs = [jnp.int32(cfg.drop_penalty_bits) * b["n_dropped"]
-                 + jnp.int32(8 * cfg.compressed_bytes_for_profile(p))
-                 for p, b in enumerate(cands)]
-        best_cost, pid = costs[0], jnp.zeros((T, 1), jnp.int32)
-        for p in range(1, cfg.num_profiles):
-            better = costs[p] < best_cost
-            best_cost = jnp.where(better, costs[p], best_cost)
-            pid = jnp.where(better, jnp.int32(p), pid)
-
-        def select(field: str) -> jax.Array:
-            acc = cands[0][field]
+        with jax.named_scope(obs.ENCODE_POINTERS):   # the profile select
+            # per-page argmin of the effective encoded size, first-wins ties —
+            # identical cost + tie-break to cfg.profile_cost_bits (oracle/xla)
+            costs = [jnp.int32(cfg.drop_penalty_bits) * b["n_dropped"]
+                     + jnp.int32(8 * cfg.compressed_bytes_for_profile(p))
+                     for p, b in enumerate(cands)]
+            best_cost, pid = costs[0], jnp.zeros((T, 1), jnp.int32)
             for p in range(1, cfg.num_profiles):
-                acc = jnp.where(pid == p, cands[p][field], acc)
-            return acc
+                better = costs[p] < best_cost
+                best_cost = jnp.where(better, costs[p], best_cost)
+                pid = jnp.where(better, jnp.int32(p), pid)
 
-        blob = {name: select(name) for name in cands[0]}
+            def select(field: str) -> jax.Array:
+                acc = cands[0][field]
+                for p in range(1, cfg.num_profiles):
+                    acc = jnp.where(pid == p, cands[p][field], acc)
+                return acc
+
+            blob = {name: select(name) for name in cands[0]}
 
     ptr_ref[...] = blob["ptrs"][:, :cfg.ptr_lanes]
     delta_ref[...] = blob["deltas"][:, :cfg.delta_lanes]

@@ -26,6 +26,7 @@ from typing import Any
 
 import jax
 
+from repro import obs
 from repro.core.format import TableLike
 from repro.core.gbdi_fr import (
     FRConfig,
@@ -57,24 +58,26 @@ def encode_pages(
     x_pages: jax.Array, table: TableLike, cfg: FRConfig, backend: str = "auto"
 ) -> dict[str, jax.Array]:
     backend = resolve_backend(backend)
-    if backend == "kernel":
-        return gbdi_encode_pallas(x_pages, table, cfg, interpret=not _on_tpu())
-    if backend == "xla":
-        from repro.kernels import pipeline as _pipeline
+    with obs.span("codec.encode"):
+        if backend == "kernel":
+            return gbdi_encode_pallas(x_pages, table, cfg, interpret=not _on_tpu())
+        if backend == "xla":
+            from repro.kernels import pipeline as _pipeline
 
-        return _pipeline.encode_pages(x_pages, table, cfg)
-    return _ref.encode_ref(x_pages, table, cfg)
+            return _pipeline.encode_pages(x_pages, table, cfg)
+        return _ref.encode_ref(x_pages, table, cfg)
 
 
 def decode_pages(
     blob: dict[str, jax.Array], table: TableLike, cfg: FRConfig, backend: str = "auto"
 ) -> jax.Array:
     backend = resolve_backend(backend)
-    if backend == "kernel":
-        return gbdi_decode_pallas(blob, table, cfg, interpret=not _on_tpu())
-    if backend == "xla":
-        return _xla.decode_pages(blob, table, cfg)
-    return _ref.decode_ref(blob, table, cfg)
+    with obs.span("codec.decode"):
+        if backend == "kernel":
+            return gbdi_decode_pallas(blob, table, cfg, interpret=not _on_tpu())
+        if backend == "xla":
+            return _xla.decode_pages(blob, table, cfg)
+        return _ref.decode_ref(blob, table, cfg)
 
 
 def encode_tensor(

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from repro.core.format import TableLike
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels import xla as _xla
-from repro.kernels.xla import BLOB_TRAILING, PreparedTable, prepare_table, under_trace
+from repro.kernels.xla import BLOB_TRAILING, PreparedTable, prepare_table
+from repro.obs import under_trace
 
 
 def device_count() -> int:

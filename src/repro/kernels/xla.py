@@ -50,18 +50,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import format as fmt
 from repro.core.format import TableLike, as_base_table
 from repro.core.gbdi_fr import FRConfig, pack_lanes, unpack_lanes
-
-
-def under_trace(*leaves: Any) -> bool:
-    """True inside any active trace (jit, vmap, shard_map, a ``lax.cond``
-    branch) or when any of ``leaves`` is a tracer.  Under a trace even ops
-    on concrete arrays yield trace-local tracers, so eager-only shortcuts
-    (memoized device constants, per-device dispatch) must step aside."""
-    return (not jax.core.trace_ctx.is_top_level()
-            or any(isinstance(leaf, jax.core.Tracer) for leaf in leaves))
+from repro.obs import under_trace
 
 
 class PreparedTable(NamedTuple):
@@ -411,29 +404,35 @@ def _finalize_batch(
     sel_p, _, _, out_p, n_spilled = state
     n, p = x.shape
     dt = sel_p.dtype.type
-    wm_o, bcsum_o = _mask_blocks(out_p)
-    n_total_out = bcsum_o[:, -1].astype(jnp.int32)
     ocap = cfg.outlier_cap
-    opos = _positions(wm_o, bcsum_o, min(ocap, p))
-    if ocap > p:
-        opos = jnp.pad(opos, ((0, 0), (0, ocap - p)), constant_values=p)
-    olive = opos < p
-    out_vals = jnp.where(
-        olive, jnp.take_along_axis(x, jnp.minimum(opos, p - 1), axis=1), 0)
-    out_idx = jnp.where(olive, opos, 0)
-    code = jnp.where(is_zero, dt(cfg.zero_code), sel_p)
-    code = jnp.where(out_p, dt(cfg.outlier_code), code)
-    deltas = (jnp.concatenate(subs, axis=1) if subs
-              else jnp.zeros((n, 0), jnp.int32))
-    deltas = jnp.pad(deltas, ((0, 0), (0, cfg.delta_lanes - deltas.shape[1])))
+    with jax.named_scope(obs.ENCODE_OUTLIERS):
+        wm_o, bcsum_o = _mask_blocks(out_p)
+        n_total_out = bcsum_o[:, -1].astype(jnp.int32)
+        opos = _positions(wm_o, bcsum_o, min(ocap, p))
+        if ocap > p:
+            opos = jnp.pad(opos, ((0, 0), (0, ocap - p)), constant_values=p)
+        olive = opos < p
+        out_vals = jnp.where(
+            olive, jnp.take_along_axis(x, jnp.minimum(opos, p - 1), axis=1), 0)
+        out_idx = jnp.where(olive, opos, 0)
+        n_out = jnp.minimum(n_total_out, ocap)
+        n_dropped = jnp.maximum(n_total_out - ocap, 0)
+    with jax.named_scope(obs.ENCODE_POINTERS):
+        code = jnp.where(is_zero, dt(cfg.zero_code), sel_p)
+        code = jnp.where(out_p, dt(cfg.outlier_code), code)
+        ptrs = pack_lanes(code.astype(jnp.uint32), cfg.ptr_bits)
+    with jax.named_scope(obs.ENCODE_BUCKETS):
+        deltas = (jnp.concatenate(subs, axis=1) if subs
+                  else jnp.zeros((n, 0), jnp.int32))
+        deltas = jnp.pad(deltas, ((0, 0), (0, cfg.delta_lanes - deltas.shape[1])))
     return {
-        "ptrs": pack_lanes(code.astype(jnp.uint32), cfg.ptr_bits),
+        "ptrs": ptrs,
         "deltas": deltas,
         "out_vals": out_vals,
         "out_idx": out_idx,
-        "n_out": jnp.minimum(n_total_out, ocap),
+        "n_out": n_out,
         "n_spilled": n_spilled,
-        "n_dropped": jnp.maximum(n_total_out - ocap, 0),
+        "n_dropped": n_dropped,
     }
 
 
@@ -595,10 +594,11 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
     Blobs are bit-identical to the oracle either way.
     """
     const = None if under_trace(x, *prep) else _const_stages(prep, cfg)
-    if const is not None:
-        sel, cls_sel, active, out_cand, is_zero, alts = const.assign(x)
-    else:
-        sel, cls_sel, active, out_cand, is_zero, alts = _assign_batch(x, prep, cfg)
+    with jax.named_scope(obs.ENCODE_CLASSIFY):
+        if const is not None:
+            sel, cls_sel, active, out_cand, is_zero, alts = const.assign(x)
+        else:
+            sel, cls_sel, active, out_cand, is_zero, alts = _assign_batch(x, prep, cfg)
     solo = cfg.num_profiles == 1
     zero_sp = jnp.zeros(x.shape[:1], jnp.int32)
     cands = []
@@ -609,25 +609,27 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
         for caps in cfg.profiles:
             state: _EncState = (sel, cls_sel, active, out_cand, zero_sp)
             subs = []
-            for i, cap in enumerate(caps):
-                pos, inclass = _class_positions(state[1], state[2],
-                                                cfg=cfg, i=i, cap=cap)
-                alt: tuple[jax.Array, ...] = alts[i] if i + 1 < cfg.num_classes else ()
-                # the first class of a multi-profile probe re-buckets the
-                # shared assignment state, so only later stages may donate it
-                donate = solo or i > 0
-                if const is not None:
-                    fn = const.update if donate else const.update_shared
-                    sub, state = fn(x, state, alt, pos, inclass, i=i, cap=cap)
-                else:
-                    fn2 = _class_update if donate else _class_update_shared
-                    sub, state = fn2(x, prep, state, alt, pos, inclass,
-                                     cfg=cfg, i=i, cap=cap)
-                subs.append(sub)
+            with jax.named_scope(obs.ENCODE_BUCKETS):
+                for i, cap in enumerate(caps):
+                    pos, inclass = _class_positions(state[1], state[2],
+                                                    cfg=cfg, i=i, cap=cap)
+                    alt: tuple[jax.Array, ...] = alts[i] if i + 1 < cfg.num_classes else ()
+                    # the first class of a multi-profile probe re-buckets the
+                    # shared assignment state, so only later stages may donate it
+                    donate = solo or i > 0
+                    if const is not None:
+                        fn = const.update if donate else const.update_shared
+                        sub, state = fn(x, state, alt, pos, inclass, i=i, cap=cap)
+                    else:
+                        fn2 = _class_update if donate else _class_update_shared
+                        sub, state = fn2(x, prep, state, alt, pos, inclass,
+                                         cfg=cfg, i=i, cap=cap)
+                    subs.append(sub)
             cands.append(_finalize_batch(x, is_zero, state, tuple(subs), cfg=cfg))
     if solo:
         return cands[0]
-    return _pick_profile(tuple(cands), cfg=cfg)
+    with jax.named_scope(obs.ENCODE_POINTERS):
+        return _pick_profile(tuple(cands), cfg=cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -740,43 +742,46 @@ def _build_dec_stages(
         n_out: jax.Array, profile: jax.Array | None, unsigned: bool,
     ) -> jax.Array:
         n = ptrs.shape[0]
-        code = unpack_lanes(ptrs, cfg.ptr_bits, P).astype(jnp.int32)
-        # three separate small-table gathers — measured faster than one
-        # 3-wide row gather on XLA:CPU (the (N, P, 3) intermediate defeats
-        # elementwise fusion and costs ~35%)
-        cfm = jnp.asarray(cfm_t)[code]
-        if profile is not None:
-            idx = profile[:, None] * NC + code
-            t1v = jnp.asarray(t1_t.reshape(-1))[idx]
-            t2v = jnp.asarray(t2_t.reshape(-1))[idx]
-        else:
-            t1v = jnp.asarray(t1_t[0])[code]
-            t2v = jnp.asarray(t2_t[0])[code]
-        # packed rank scan: every class rank + the outlier rank advance in
-        # parallel as bit fields of one int32 accumulator
-        csh = (cfm & 31).astype(jnp.uint32)
-        fmask = cfm >> 5
-        inc = jnp.minimum(fmask, 1) << csh
-        f = inc.reshape(-1, 16).astype(jnp.float32)
-        s = (f @ jnp.asarray(tri16)).astype(jnp.int32).reshape(n, P // 16, 16)
-        tot = s[:, :, -1]
-        boff = (jnp.cumsum(tot, axis=1) - tot)[:, :, None]
-        cnt = (s + boff).reshape(n, P)
-        rank = ((cnt >> csh) & fmask) - 1
-        # payload: variable-width delta gather + rank-select outlier gather
-        w_pos = (t1v & 31).astype(jnp.uint32)
-        capv = t2v >> (wb + 1)
-        live = -((t2v >> wb) & 1)
-        rc = jnp.clip(rank, 0, capv - 1)
-        bitpos = (t1v & ~31) + rc * (t1v & 31)
-        dv = jnp.take_along_axis(deltas, bitpos >> 5, axis=1).astype(jnp.uint32)
-        sign = jnp.uint32(1) << (w_pos - 1)
-        dvv = (dv >> (bitpos & 31).astype(jnp.uint32)) & ((jnp.uint32(1) << w_pos) - 1)
-        delta = (dvv ^ sign).astype(jnp.int32) - sign.astype(jnp.int32)
-        val = ((t2v & wmask) + (delta & live)) & wmask
-        oval = jnp.take_along_axis(out_vals, jnp.clip(rank, 0, ocap - 1), axis=1)
-        oval = jnp.where(rank < n_out[:, None], oval, 0)
-        out = jnp.where(code == cfg.outlier_code, oval, val)
+        with jax.named_scope(obs.DECODE_POINTERS):
+            code = unpack_lanes(ptrs, cfg.ptr_bits, P).astype(jnp.int32)
+            # three separate small-table gathers — measured faster than one
+            # 3-wide row gather on XLA:CPU (the (N, P, 3) intermediate defeats
+            # elementwise fusion and costs ~35%)
+            cfm = jnp.asarray(cfm_t)[code]
+            if profile is not None:
+                idx = profile[:, None] * NC + code
+                t1v = jnp.asarray(t1_t.reshape(-1))[idx]
+                t2v = jnp.asarray(t2_t.reshape(-1))[idx]
+            else:
+                t1v = jnp.asarray(t1_t[0])[code]
+                t2v = jnp.asarray(t2_t[0])[code]
+        with jax.named_scope(obs.DECODE_BUCKETS):
+            # packed rank scan: every class rank + the outlier rank advance in
+            # parallel as bit fields of one int32 accumulator
+            csh = (cfm & 31).astype(jnp.uint32)
+            fmask = cfm >> 5
+            inc = jnp.minimum(fmask, 1) << csh
+            f = inc.reshape(-1, 16).astype(jnp.float32)
+            s = (f @ jnp.asarray(tri16)).astype(jnp.int32).reshape(n, P // 16, 16)
+            tot = s[:, :, -1]
+            boff = (jnp.cumsum(tot, axis=1) - tot)[:, :, None]
+            cnt = (s + boff).reshape(n, P)
+            rank = ((cnt >> csh) & fmask) - 1
+            # payload: variable-width delta gather
+            w_pos = (t1v & 31).astype(jnp.uint32)
+            capv = t2v >> (wb + 1)
+            live = -((t2v >> wb) & 1)
+            rc = jnp.clip(rank, 0, capv - 1)
+            bitpos = (t1v & ~31) + rc * (t1v & 31)
+            dv = jnp.take_along_axis(deltas, bitpos >> 5, axis=1).astype(jnp.uint32)
+            sign = jnp.uint32(1) << (w_pos - 1)
+            dvv = (dv >> (bitpos & 31).astype(jnp.uint32)) & ((jnp.uint32(1) << w_pos) - 1)
+            delta = (dvv ^ sign).astype(jnp.int32) - sign.astype(jnp.int32)
+            val = ((t2v & wmask) + (delta & live)) & wmask
+        with jax.named_scope(obs.DECODE_OUTLIERS):   # rank-select outlier gather
+            oval = jnp.take_along_axis(out_vals, jnp.clip(rank, 0, ocap - 1), axis=1)
+            oval = jnp.where(rank < n_out[:, None], oval, 0)
+            out = jnp.where(code == cfg.outlier_code, oval, val)
         if not unsigned:
             return out
         # unsigned output fuses the consumer-side word cast into the final
@@ -842,10 +847,11 @@ def _decode_batch_ref(blob: dict[str, jax.Array], prep: PreparedTable, cfg: FRCo
     bases, _, cls = prep
     rows = jnp.arange(N, dtype=jnp.int32)[:, None]
 
-    code = unpack_lanes(blob["ptrs"], cfg.ptr_bits, P).astype(jnp.int32)  # (N, P)
-    active = code < cfg.num_bases
-    base_code = jnp.clip(code, 0, cfg.num_bases - 1)
-    cls_w = cls[base_code]
+    with jax.named_scope(obs.DECODE_POINTERS):
+        code = unpack_lanes(blob["ptrs"], cfg.ptr_bits, P).astype(jnp.int32)  # (N, P)
+        active = code < cfg.num_bases
+        base_code = jnp.clip(code, 0, cfg.num_bases - 1)
+        cls_w = cls[base_code]
 
     def gather_deltas(profile: int) -> jax.Array:
         delta = jnp.zeros((N, P), jnp.int32)
@@ -864,29 +870,32 @@ def _decode_batch_ref(blob: dict[str, jax.Array], prep: PreparedTable, cfg: FRCo
             delta = jnp.where(inclass, gathered, delta)
         return delta
 
-    if cfg.num_profiles == 1:
-        delta = gather_deltas(0)
-    else:   # per-page profile id selects the sub-stream layout
-        pid = blob["profile"][:, None]
-        delta = jnp.zeros((N, P), jnp.int32)
-        for p in range(cfg.num_profiles):
-            delta = jnp.where(pid == p, gather_deltas(p), delta)
+    with jax.named_scope(obs.DECODE_BUCKETS):
+        if cfg.num_profiles == 1:
+            delta = gather_deltas(0)
+        else:   # per-page profile id selects the sub-stream layout
+            pid = blob["profile"][:, None]
+            delta = jnp.zeros((N, P), jnp.int32)
+            for p in range(cfg.num_profiles):
+                delta = jnp.where(pid == p, gather_deltas(p), delta)
 
-    val = bases[base_code] + delta
-    if wb == 16:
-        val = val & fmt.WORD16_MASK
-    val = jnp.where(code == cfg.zero_code, 0, val)
+        val = bases[base_code] + delta
+        if wb == 16:
+            val = val & fmt.WORD16_MASK
 
-    # outlier scatter-back: live slots hold distinct page positions, so a
-    # scatter is value-equal to the oracle's one-hot matmul (dead slots are
-    # parked at column P of a scratch buffer)
-    live = jnp.arange(cap_out)[None, :] < blob["n_out"][:, None]
-    idx = jnp.where(live, blob["out_idx"], P)
-    out_contrib = jnp.zeros((N, P + 1), jnp.int32).at[rows, idx].set(
-        jnp.where(live, blob["out_vals"], 0))[:, :P]
-    is_out_pos = jnp.zeros((N, P + 1), jnp.bool_).at[rows, idx].set(live)[:, :P]
-    return jnp.where(is_out_pos, out_contrib,
-                     jnp.where(code == cfg.outlier_code, 0, val))
+    with jax.named_scope(obs.DECODE_OUTLIERS):
+        val = jnp.where(code == cfg.zero_code, 0, val)
+
+        # outlier scatter-back: live slots hold distinct page positions, so a
+        # scatter is value-equal to the oracle's one-hot matmul (dead slots are
+        # parked at column P of a scratch buffer)
+        live = jnp.arange(cap_out)[None, :] < blob["n_out"][:, None]
+        idx = jnp.where(live, blob["out_idx"], P)
+        out_contrib = jnp.zeros((N, P + 1), jnp.int32).at[rows, idx].set(
+            jnp.where(live, blob["out_vals"], 0))[:, :P]
+        is_out_pos = jnp.zeros((N, P + 1), jnp.bool_).at[rows, idx].set(live)[:, :P]
+        return jnp.where(is_out_pos, out_contrib,
+                         jnp.where(code == cfg.outlier_code, 0, val))
 
 
 # ---------------------------------------------------------------------------

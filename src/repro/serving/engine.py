@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import numpy.typing as npt
 
+from repro import obs
 from repro.models.api import Model
 from repro.serving import kv_cache
 from repro.serving.kv_cache import KVSpec
@@ -185,17 +186,20 @@ class KVSession:
 
     def prefill(self, ks: jax.Array, vs: jax.Array) -> None:
         """Append a whole (B, T, Kv, hd) context in one fori_loop dispatch."""
-        self.cache = self._prefill(ks, vs, self.cache, jnp.int32(self.pos))
+        with obs.span("kv.prefill"):
+            self.cache = self._prefill(ks, vs, self.cache, jnp.int32(self.pos))
         self.pos += int(ks.shape[1])
 
     def append(self, k: jax.Array, v: jax.Array) -> None:
         """Append one token's (B, 1, Kv, hd) K/V at the current position."""
-        self.cache = self._append(self.cache, k, v, jnp.int32(self.pos))
+        with obs.span("kv.append"):
+            self.cache = self._append(self.cache, k, v, jnp.int32(self.pos))
         self.pos += 1
 
     def step(self, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
         """One decode step: append this token's K/V, attend with ``q`` over
         everything appended so far.  Returns (B, 1, H*hd)."""
         self.append(k, v)
-        out: jax.Array = self._attend(q, self.cache, jnp.int32(self.pos - 1))
+        with obs.span("kv.attend"):
+            out: jax.Array = self._attend(q, self.cache, jnp.int32(self.pos - 1))
         return out

@@ -46,6 +46,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.format import (
     DEFAULT_NUM_BASES,
     DEFAULT_OUTLIER_CAP,
@@ -213,8 +214,9 @@ def append(spec: KVSpec, cache: Cache, k: jax.Array, v: jax.Array, pos: jax.Arra
     pages_per_row = max(1, spec.row_words * pt // spec.fr.page_words)
 
     def flush(c: Cache) -> Cache:
-        kb = _compress_rows(spec, k_tail, cache["table"])
-        vb = _compress_rows(spec, v_tail, cache["table"])
+        with jax.named_scope(obs.KV_FLUSH_ENCODE):
+            kb = _compress_rows(spec, k_tail, cache["table"])
+            vb = _compress_rows(spec, v_tail, cache["table"])
         def put(dst: dict[str, jax.Array], src: dict[str, jax.Array]) -> dict[str, jax.Array]:
             merged: dict[str, jax.Array] = jax.tree_util.tree_map(
                 lambda d, s: jax.lax.dynamic_update_slice(
@@ -235,10 +237,11 @@ def append(spec: KVSpec, cache: Cache, k: jax.Array, v: jax.Array, pos: jax.Arra
                 w = fr_pipeline.decode_pages(blob, cache["table"], spec.fr)
                 B = w.shape[0]
                 return _from_words(w.reshape(B, pt, spec.n_kv, spec.head_dim))
-            out["k_dec"] = jax.lax.dynamic_update_slice(
-                c["k_dec"], dec(kb), (0, page_id * pt, 0, 0))
-            out["v_dec"] = jax.lax.dynamic_update_slice(
-                c["v_dec"], dec(vb), (0, page_id * pt, 0, 0))
+            with jax.named_scope(obs.KV_FLUSH_DECODE):
+                out["k_dec"] = jax.lax.dynamic_update_slice(
+                    c["k_dec"], dec(kb), (0, page_id * pt, 0, 0))
+                out["v_dec"] = jax.lax.dynamic_update_slice(
+                    c["v_dec"], dec(vb), (0, page_id * pt, 0, 0))
         return out
 
     def nop(c: Cache) -> Cache:
@@ -295,39 +298,40 @@ def attention_decode(
     if backend == "resident" and "k_dec" not in cache:
         raise ValueError("backend='resident' requires a cache built with "
                          "spec.resident_decode=True")
-    if backend in ("oracle", "resident") or (backend == "auto" and "k_dec" in cache):
-        K, V, valid = read_full(spec, cache, pos)
-        B, S, Kv, hd = K.shape
-        H = q.shape[2]
-        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-        qg = q.reshape(B, 1, Kv, H // Kv, hd)
-        logits = jnp.einsum("bskgh,btkh->bkgst", qg, K).astype(jnp.float32) * scale
-        logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(V.dtype)
-        out = jnp.einsum("bkgst,btkh->bskgh", probs, V)
-        return out.reshape(B, 1, H * hd)
+    with jax.named_scope(obs.KV_ATTEND):
+        if backend in ("oracle", "resident") or (backend == "auto" and "k_dec" in cache):
+            K, V, valid = read_full(spec, cache, pos)
+            B, S, Kv, hd = K.shape
+            H = q.shape[2]
+            scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+            qg = q.reshape(B, 1, Kv, H // Kv, hd)
+            logits = jnp.einsum("bskgh,btkh->bkgst", qg, K).astype(jnp.float32) * scale
+            logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(V.dtype)
+            out = jnp.einsum("bkgst,btkh->bskgh", probs, V)
+            return out.reshape(B, 1, H * hd)
 
-    from repro.kernels.gbdi_paged_attn import merge_softmax
+        from repro.kernels.gbdi_paged_attn import merge_softmax
 
-    B, _, H, hd = q.shape
-    Kv = spec.n_kv
-    G = H // Kv
-    qg = q.reshape(B, Kv, G, hd).astype(jnp.float32)
-    acc, m, l = fr_xla.paged_attention_decode(
-        qg, cache["k_pages"], cache["v_pages"], cache["table"], pos, spec.fr,
-        n_kv=Kv, hd=hd, groups=G,
-    )
-    # raw-tail stream (the current partial page), then softmax-merge
-    pt = spec.page_tokens
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    Kt = cache["k_tail"].astype(jnp.float32)
-    Vt = cache["v_tail"].astype(jnp.float32)
-    tail_valid = (pos // pt) * pt + jnp.arange(pt) <= pos
-    lg = jnp.einsum("bkgh,btkh->bkgt", qg, Kt) * scale
-    lg = jnp.where(tail_valid[None, None, None, :], lg, -1e30)
-    m2 = lg.max(-1)
-    p2 = jnp.where(lg <= -1e29, 0.0, jnp.exp(lg - m2[..., None]))
-    acc2 = jnp.einsum("bkgt,btkh->bkgh", p2, Vt)
-    accm, _, lm = merge_softmax(acc, m, l, acc2, m2, p2.sum(-1))
-    out = accm / lm[..., None]
-    return out.reshape(B, 1, H * hd).astype(cache["k_tail"].dtype)
+        B, _, H, hd = q.shape
+        Kv = spec.n_kv
+        G = H // Kv
+        qg = q.reshape(B, Kv, G, hd).astype(jnp.float32)
+        acc, m, l = fr_xla.paged_attention_decode(
+            qg, cache["k_pages"], cache["v_pages"], cache["table"], pos, spec.fr,
+            n_kv=Kv, hd=hd, groups=G,
+        )
+        # raw-tail stream (the current partial page), then softmax-merge
+        pt = spec.page_tokens
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+        Kt = cache["k_tail"].astype(jnp.float32)
+        Vt = cache["v_tail"].astype(jnp.float32)
+        tail_valid = (pos // pt) * pt + jnp.arange(pt) <= pos
+        lg = jnp.einsum("bkgh,btkh->bkgt", qg, Kt) * scale
+        lg = jnp.where(tail_valid[None, None, None, :], lg, -1e30)
+        m2 = lg.max(-1)
+        p2 = jnp.where(lg <= -1e29, 0.0, jnp.exp(lg - m2[..., None]))
+        acc2 = jnp.einsum("bkgt,btkh->bkgh", p2, Vt)
+        accm, _, lm = merge_softmax(acc, m, l, acc2, m2, p2.sum(-1))
+        out = accm / lm[..., None]
+        return out.reshape(B, 1, H * hd).astype(cache["k_tail"].dtype)

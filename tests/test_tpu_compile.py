@@ -105,3 +105,17 @@ def test_xla_chain_compiles_under_jit(topo, one_chip):
 
 def test_described_chip_has_published_peaks(topo):
     assert topo.devices[0].device_kind in CHIP_PEAKS
+
+
+@pytest.mark.parametrize("kernel", ["encode", "decode"])
+def test_kernels_compile_keeping_their_regions(one_chip, kernel):
+    """The kernels under the flag that keeps their phase regions for a
+    profile (``LIBTPU_INIT_ARGS=--xla_enable_custom_call_region_trace=true``
+    on the chip), given here per compile: the TPU compiler knows the flag
+    and compiles both kernels with it."""
+    cfg = KV_FR
+    x, table, blob = _shapes(one_chip, cfg)
+    fn, arg = (gbdi_encode_pallas, x) if kernel == "encode" else (gbdi_decode_pallas, blob)
+    compiled = jax.jit(lambda a, t: fn(a, t, cfg, interpret=False)).lower(arg, table).compile(
+        compiler_options={"xla_enable_custom_call_region_trace": True})
+    assert "tpu_custom_call" in compiled.as_text()
