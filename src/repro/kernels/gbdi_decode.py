@@ -6,7 +6,8 @@ delta add + outlier scatter-back.  On TPU it runs the encoder's lane moves
 page_words)`` tiles: pointer codes and class sub-streams unpack by
 spreading packed lanes back over their fields; a word's slot in its class
 sub-stream is its page-order rank among same-class words, so each slot
-travels right to its word by the inverse of the encoder's compaction; the
+travels right to its word by the inverse of the encoder's compaction, its
+unsigned field and distance in one int32 (sign-extended once it lands); the
 base value is a select over the (tiny) SMEM base table.  Outliers come
 back the same way: both encoders fill the outlier table in page order, so
 the j-th outlier-coded word owns slot j (``j < n_out``; a dropped word
@@ -68,11 +69,12 @@ def decode_tile(
             base_val = jnp.where(hit, table(0, j), base_val)
             cls_w = jnp.where(hit, table(1, j), cls_w)
 
-    def to_words(sub: jax.Array, member: jax.Array, live: jax.Array) -> jax.Array:
-        """Slot r of ``sub`` -> the lane of the r-th ``member`` word."""
+    def to_words(sub: jax.Array, member: jax.Array, live: jax.Array, bits: int) -> jax.Array:
+        """Slot r of ``sub`` (unsigned, ``bits`` wide) -> the lane of the
+        r-th ``member`` word.  The compaction moves the distance alone."""
         rank = prefix_sum(member.astype(jnp.int32)) - 1
-        dist = compact([lane - rank], member, rank)[0]
-        return expand(sub, dist, live)
+        dist = compact(None, member, rank)[1]
+        return expand(sub, dist, live, bits)
 
     def gather_deltas(profile: int) -> jax.Array:
         delta = jnp.zeros_like(code)
@@ -82,12 +84,12 @@ def decode_tile(
         ):
             if cap == 0:
                 continue
-            n_lanes = cap * w // 32
             packed = pltpu.roll(deltas, P - off, 1) if off else deltas
-            sub = unpack_fields(jnp.where(lane < n_lanes, packed, 0), w)
-            sub = jnp.where(sub >= (1 << (w - 1)), sub - (1 << w), sub)
+            sub = unpack_fields(packed, w)      # fields past cap are not live
             inclass = cls_w == i
-            delta = jnp.where(inclass, to_words(sub, inclass, lane < cap), delta)
+            moved = to_words(sub, inclass, lane < cap, w)
+            moved = (moved << (32 - w)) >> (32 - w)   # sign-extend the w-bit delta
+            delta = jnp.where(inclass, moved, delta)
         return delta
 
     with jax.named_scope(obs.DECODE_BUCKETS):
@@ -104,7 +106,7 @@ def decode_tile(
     with jax.named_scope(obs.DECODE_OUTLIERS):
         val = jnp.where(code == cfg.zero_code, 0, val)
         is_out = code == cfg.outlier_code
-        oval = to_words(out_vals, is_out, lane < n_out)
+        oval = to_words(out_vals, is_out, lane < n_out, cfg.word_bits)
         return jnp.where(is_out, oval, val)
 
 

@@ -15,11 +15,16 @@ tiles — pages on sublanes, words on lanes, every intermediate a 2-D
   (:func:`prefix_sum`);
 * bucket and outlier compaction WITHOUT dynamic scatter (which does not
   lower on TPU): every kept word moves left by its distance to its slot,
-  one power-of-two lane rotation per distance bit, low bit first
-  (:func:`compact`) — collision-free because kept words keep their order;
+  one power-of-two step per distance bit, low bit first (:func:`compact`)
+  — collision-free because kept words keep their order.  A step rotates
+  one plane: the distance rides in the payload's spare bits (a delta
+  field, a 16-bit word: ``bits + log2(page_words) <= 31``), and a lane
+  receives a word when the rotated distance there has the step's bit set.
+  Only a 32-bit word needs a second plane;
 * fixed-width field packing is a shift by lane position, an OR over each
-  group of ``32 // bits`` neighbours, and a compaction of the group heads
-  (:func:`pack_fields`).
+  group of ``32 // bits`` neighbours, and a move of the group heads whose
+  distances are fixed by the lane: each step rotates the payload alone,
+  under masks computed from the lane index (:func:`pack_fields`).
 
 All of it is bit-identical to the jnp oracle's spill chain.  The decoder
 (:mod:`repro.kernels.gbdi_decode`) runs the same moves in reverse.
@@ -114,12 +119,6 @@ def shift_right(y: jax.Array, s: int) -> jax.Array:
     return jnp.where(lanes(y.shape) >= s, pltpu.roll(y, s, 1), 0)
 
 
-def shift_left(y: jax.Array, s: int) -> jax.Array:
-    """``out[:, p] = y[:, p + s]``, zero-filled."""
-    n = y.shape[1]
-    return jnp.where(lanes(y.shape) < n - s, pltpu.roll(y, n - s, 1), 0)
-
-
 def prefix_sum(y: jax.Array) -> jax.Array:
     """Hillis–Steele inclusive prefix sum along the lanes."""
     s = 1
@@ -129,73 +128,123 @@ def prefix_sum(y: jax.Array) -> jax.Array:
     return y
 
 
-def compact(vals: list[jax.Array], keep: jax.Array, rank: jax.Array) -> list[jax.Array]:
-    """Move ``vals[:, p]`` where ``keep`` to lane ``rank[:, p]`` (the kept
-    words' page-order rank); every other lane ends zero.
+def carries(bits: int, n: int) -> bool:
+    """Whether a ``bits``-wide payload and a lane distance below ``n`` share
+    one int32 lane word, so that one rotation per step moves both."""
+    return bits + (n - 1).bit_length() <= 31
+
+
+def _move(
+    val: jax.Array | None, dist: jax.Array, bits: int, left: bool,
+) -> tuple[jax.Array, jax.Array]:
+    """Move each word ``dist`` lanes, one power-of-two step per distance bit:
+    left low bit first (compaction), right high bit first (expansion).
+
+    Empty lanes hold distance 0 and payload 0.  At step ``b`` a lane
+    receives a word exactly when the rotated distance there has bit ``b``
+    set, so the distance alone says where words go.  When :func:`carries`
+    holds, the word travels as ``dist << bits | val`` and each step rotates
+    one plane; otherwise the payload rides in a second plane.  No word with
+    bit ``b`` set sits within ``b`` lanes of the edge it moves towards, so
+    what a rotation wraps round never arrives and needs no zero fill."""
+    n = dist.shape[1]
+    steps = [1 << i for i in range((n - 1).bit_length())]
+    if val is None:
+        planes, tag = [dist], 0
+    elif carries(bits, n):
+        planes, tag = [(dist << bits) | val], bits
+    else:
+        planes, tag = [dist, val], 0
+    for b in steps if left else steps[::-1]:
+        moved = [pltpu.roll(p, n - b if left else b, 1) for p in planes]
+        arrive = (moved[0] & (b << tag)) != 0
+        go = (planes[0] & (b << tag)) != 0
+        planes = [jnp.where(arrive, m, jnp.where(go, 0, p)) for m, p in zip(moved, planes)]
+    if val is None:
+        return planes[0], planes[0]
+    if tag:
+        return planes[0] & ((1 << bits) - 1), planes[0] >> bits
+    return planes[1], planes[0]
+
+
+def compact(
+    val: jax.Array | None, keep: jax.Array, rank: jax.Array, bits: int = 32,
+) -> tuple[jax.Array, jax.Array]:
+    """Move ``val[:, p]`` where ``keep`` to lane ``rank[:, p]`` (the kept
+    words' page-order rank); every other lane ends zero.  Returns the moved
+    values and, per lane, how far its word travelled (0 on empty lanes).
 
     Each kept word travels left by ``p - rank``, one power-of-two step per
-    set bit, low bit first.  Kept words keep their order, so no step ever
-    lands two words on one lane."""
-    n = keep.shape[1]
-    dist = jnp.where(keep, lanes(keep.shape) - rank, -1)   # -1: empty lane
-    vals = [jnp.where(keep, v, 0) for v in vals]
-    b = 1
-    while b < n:
-        go = (dist >= 0) & ((dist & b) != 0)
-        arrive = shift_left(go.astype(jnp.int32), b) != 0
-        vals = [jnp.where(arrive, shift_left(v, b), jnp.where(go, 0, v)) for v in vals]
-        dist = jnp.where(arrive, shift_left(dist, b), jnp.where(go, -1, dist))
-        b *= 2
-    return vals
+    set bit, low bit first (:func:`_move`).  Kept words keep their order,
+    so no step ever lands two words on one lane.  A ``bits``-wide unsigned
+    payload shares the distance's int32 when :func:`carries` holds: then a
+    step rotates one plane, else two.  ``val=None`` moves the distance
+    alone, which is then both results."""
+    dist = jnp.where(keep, lanes(keep.shape) - rank, 0)
+    return _move(None if val is None else jnp.where(keep, val, 0), dist, bits, left=True)
 
 
-def expand(val: jax.Array, dist: jax.Array, live: jax.Array) -> jax.Array:
+def expand(val: jax.Array, dist: jax.Array, live: jax.Array, bits: int = 32) -> jax.Array:
     """Inverse of :func:`compact`: slot ``r`` (where ``live``) moves right by
-    ``dist[:, r]`` — the compaction's moves replayed high bit first."""
-    n = val.shape[1]
-    dist = jnp.where(live, dist, -1)
-    val = jnp.where(live, val, 0)
-    b = 1
-    while b * 2 < n:
-        b *= 2
-    while b >= 1:
-        go = (dist >= 0) & ((dist & b) != 0)
-        arrive = shift_right(go.astype(jnp.int32), b) != 0
-        val = jnp.where(arrive, shift_right(val, b), jnp.where(go, 0, val))
-        dist = jnp.where(arrive, shift_right(dist, b), jnp.where(go, -1, dist))
-        b //= 2
-    return val
-
-
-def pack_fields(fields: jax.Array, bits: int) -> jax.Array:
-    """``pack_lanes`` on a tile: field ``f`` (lane ``f``, ``< 2**bits``)
-    lands in lane ``f // per`` at bit ``(f % per) * bits``, ``per = 32 //
-    bits``.  Lanes past the packed words are zero when the fields past the
-    last one are."""
-    per = 32 // bits
-    lane = lanes(fields.shape)
-    g = fields << ((lane & (per - 1)) * bits)
-    s = 1
-    while s < per:
-        g = g | shift_left(g, s)
-        s *= 2
-    return compact([g], (lane & (per - 1)) == 0, lane >> _log2(per))[0]
+    ``dist[:, r]`` to ``r + dist[:, r] < page_words`` — the compaction's
+    moves replayed high bit first, one plane per step for a ``bits``-wide
+    unsigned payload when :func:`carries` holds; every lane no slot reaches
+    ends zero."""
+    return _move(jnp.where(live, val, 0), jnp.where(live, dist, 0), bits, left=False)[0]
 
 
 def _log2(n: int) -> int:
     return n.bit_length() - 1
 
 
+def _head_steps(shape: tuple[int, ...], per: int) -> list[tuple[int, int, jax.Array]]:
+    """The static moves between lane ``per * j`` and lane ``j``: one per bit
+    ``k`` of ``j``, which travels between bit ``k + log2(per)`` and bit
+    ``k`` of the lane index.  Each is ``(k, shift, field)``: the rotation
+    and the index bits ``k .. k + log2(per)`` the masks read."""
+    if per == 1:
+        return []
+    span = (2 << _log2(per)) - 1
+    lane = lanes(shape)
+    return [(k, (per - 1) << k, lane & (span << k))
+            for k in range((shape[1] // per - 1).bit_length())]
+
+
+def pack_fields(fields: jax.Array, bits: int) -> jax.Array:
+    """``pack_lanes`` on a tile: field ``f`` (lane ``f``, ``< 2**bits``)
+    lands in lane ``f // per`` at bit ``(f % per) * bits``, ``per = 32 //
+    bits``.  Lanes past the packed words are zero.
+
+    The group heads gather their neighbours by an OR tree, then move to
+    their packed lanes; the distances are fixed by the lane, so each step
+    rotates the payload alone under a mask computed from the lane index."""
+    per = 32 // bits
+    n = fields.shape[1]
+    lane = lanes(fields.shape)
+    g = fields << ((lane & (per - 1)) * bits)
+    s = 1
+    while s < per:                 # a head reads its own group only: no wrap
+        g = g | pltpu.roll(g, n - s, 1)
+        s *= 2
+    for k, shift, field in _head_steps(g.shape, per):   # low bit first
+        g = jnp.where(field == (1 << k), pltpu.roll(g, n - shift, 1), g)
+    return jnp.where(lane < n // per, g, 0)
+
+
 def unpack_fields(packed: jax.Array, bits: int) -> jax.Array:
     """Inverse of :func:`pack_fields`: lane ``f`` gets field ``f`` as an
-    unsigned value in ``[0, 2**bits)``."""
+    unsigned value in ``[0, 2**bits)``.  Only the first ``page_words // per``
+    lanes of ``packed`` are read."""
     per = 32 // bits
-    n = packed.shape[1]
+    s = _log2(per)
     lane = lanes(packed.shape)
-    heads = expand(packed, lane * (per - 1), lane < n // per)
+    heads = packed
+    for k, shift, field in _head_steps(packed.shape, per)[::-1]:   # high bit first
+        heads = jnp.where(field == (1 << (k + s)), pltpu.roll(heads, shift, 1), heads)
+    heads = jnp.where((lane & (per - 1)) == 0, heads, 0)
     s = 1
     while s < per:                 # copy each group head over its group
-        heads = heads | shift_right(heads, s)
+        heads = heads | pltpu.roll(heads, s, 1)
         s *= 2
     sh = (lane & (per - 1)) * bits
     return jax.lax.shift_right_logical(heads, sh) & ((1 << bits) - 1)
@@ -257,7 +306,7 @@ def _encode_kernel(
                 keep = inclass & (rank < cap)
                 over = inclass & ~keep
                 if cap:
-                    sub = compact([dsel & ((1 << w) - 1)], keep, rank)[0]
+                    sub = compact(dsel & ((1 << w) - 1), keep, rank, w)[0]
                     packed = pack_fields(sub, w)
                     deltas = deltas | (pltpu.roll(packed, off, 1) if off else packed)
                     off += cap * w // 32
@@ -282,7 +331,7 @@ def _encode_kernel(
         with jax.named_scope(obs.ENCODE_OUTLIERS):
             pos = prefix_sum(out_cand.astype(jnp.int32)) - 1
             in_table = out_cand & (pos < cap_out)
-            out_vals, out_idx = compact([x, lane], in_table, pos)
+            out_vals, out_dist = compact(x, in_table, pos, wb)
         with jax.named_scope(obs.ENCODE_POINTERS):
             code = jnp.where(is_zero, cfg.zero_code, sel)
             code = jnp.where(out_cand, cfg.outlier_code, code)
@@ -292,6 +341,7 @@ def _encode_kernel(
             ptrs = pack_fields(code, cfg.ptr_bits)
         with jax.named_scope(obs.ENCODE_OUTLIERS):
             n_out = jnp.minimum(n_out, cap_out)
+            out_idx = jnp.where(lane < n_out, lane + out_dist, 0)
             n_dropped = (out_cand & ~in_table).astype(jnp.int32).sum(axis=1, keepdims=True)
         return {
             "ptrs": ptrs,

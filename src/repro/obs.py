@@ -22,6 +22,7 @@ window idle where the device ran throughout).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 from typing import Any, ContextManager
 
@@ -59,3 +60,27 @@ def span(name: str) -> ContextManager[object]:
     if under_trace():
         return contextlib.nullcontext()
     return jax.profiler.TraceAnnotation(f"repro.{name}")
+
+
+def kernel_primitive_counts(closed: Any) -> collections.Counter[str]:
+    """How often each primitive occurs in the bodies of the ``pallas_call``s
+    in a closed jaxpr, nested jaxprs included: a compile-time count, fixed
+    per format (the codec kernels' lane moves are their ``roll``s)."""
+    counts: collections.Counter[str] = collections.Counter()
+
+    def tally(jaxpr: Any) -> None:
+        for eqn in jaxpr.eqns:
+            counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                tally(sub)
+
+    def find(jaxpr: Any) -> None:
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                tally(eqn.params["jaxpr"])
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    find(sub)
+
+    find(closed.jaxpr)
+    return counts

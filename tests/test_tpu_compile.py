@@ -28,6 +28,9 @@ CONFIGS = {
     # the eval codec's bf16 default
     "bf16_eval": FRConfig(word_bits=16, page_words=2048, num_bases=14,
                           width_set=(4, 8), bucket_caps=(192, 1856), outlier_cap=64),
+    # 32-bit words: the outlier moves carry payload and distance in two planes
+    "w32": FRConfig(word_bits=32, page_words=2048, num_bases=14,
+                    width_set=(8, 16), bucket_caps=(512, 1536), outlier_cap=64),
 }
 
 
