@@ -4,10 +4,11 @@ a traffic kind, found by name in ``drivers/<kind>.py``.
 
 A driver is built from a configuration, a mix and a seed, and has
 ``setup()``; ``window(seconds)``, which returns what the end-to-end
-metrics need; ``outputs()``, which copies what the check compares to the
-host and drops the program's state; ``check(out, limits)``, which runs
-the reference; and ``control(out)``, the reference one precision below
-in the program's place.
+metrics need and the seconds each timed call took (``times``);
+``outputs()``, which copies what the check compares to the host and
+drops the program's state; ``check(out, limits)``, which runs the
+reference; and ``control(out)``, the reference one precision below in
+the program's place.
 """
 from __future__ import annotations
 

@@ -21,6 +21,14 @@ from fmt import Format
 span = jax.profiler.TraceAnnotation
 
 
+def attn_flops(batch: int, heads: int, head_dim: int, positions: int) -> int:
+    """FLOPs one decode step's attention needs over ``positions`` valid
+    positions: q.K and p.V, a multiply and an add each, for every head of
+    every sequence.  Masked positions past the last written one are not
+    counted."""
+    return 4 * batch * heads * head_dim * positions
+
+
 class Driver:
     def __init__(self, config: dict, mix: dict, seed: int) -> None:
         self.config, self.mix, self.seed = config, mix, seed
@@ -59,8 +67,8 @@ class Driver:
         self.sess.pos = self.mix["context"]
 
     def window(self, seconds: float) -> dict:
-        S, steps = self.mix["answer"], 0
-        t0 = time.perf_counter()
+        S, T0, steps, positions, times = self.mix["answer"], self.mix["context"], 0, 0, []
+        t = t0 = time.perf_counter()
         with span("bench.window"):
             done = False
             while not done:
@@ -68,18 +76,24 @@ class Driver:
                     with span("bench.step"):
                         out = jax.block_until_ready(self.sess.step(*self.steps[i]))
                     steps += 1
+                    positions += T0 + i + 1           # step i attends [0, T0 + i]
                     if i in self.kept:
                         self.kept[i].append(out)
-                    if time.perf_counter() - t0 >= seconds:
+                    now = time.perf_counter()
+                    times.append(now - t)
+                    t = now
+                    if now - t0 >= seconds:
                         done = True
                         break
                 else:
                     self.restart()
         elapsed = time.perf_counter() - t0
-        B = self.mix["batch"]
-        return {"elapsed": elapsed, "attempted": steps * B,
+        B, kv = self.mix["batch"], self.config["kv"]
+        return {"elapsed": elapsed, "attempted": steps * B, "times": times,
                 "metrics": {"decode_tokens_per_s": steps * B / elapsed},
-                "work": {"steps": steps}}
+                "work": {"steps": steps,
+                         "attn_flops": attn_flops(B, self.config["num_attention_heads"],
+                                                  kv["head_dim"], positions)}}
 
     def raw_row(self, b: int, t: int, name: str) -> jax.Array:
         T0 = self.mix["context"]

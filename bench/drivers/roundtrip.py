@@ -42,19 +42,22 @@ class Driver:
                 self.ops.decode_pages(self.blob, self.table, self.cfg))
 
     def window(self, seconds: float) -> dict:
-        rounds = 0
-        t0 = time.perf_counter()
+        rounds, times = 0, []
+        t = t0 = time.perf_counter()
         with span("bench.window"):
             while True:
                 with span("bench.round"):
                     self.round()
                 rounds += 1
-                if time.perf_counter() - t0 >= seconds:
+                now = time.perf_counter()
+                times.append(now - t)
+                t = now
+                if now - t0 >= seconds:
                     break
         elapsed = time.perf_counter() - t0
         n = self.pages.shape[0]
         raw = n * self.f.raw_bytes_per_page
-        return {"elapsed": elapsed, "attempted": rounds,
+        return {"elapsed": elapsed, "attempted": rounds, "times": times,
                 "metrics": {"roundtrip_GiBps": rounds * raw / elapsed / 2**30},
                 "work": {"encode_bytes": n * self.f.page_bytes_moved,
                          "decode_bytes": n * self.f.page_bytes_moved}}

@@ -1,0 +1,13 @@
+"""The whole decode step's share of the chip's bf16 peak: the attention
+FLOPs the traced steps need (``drivers/decode.py::attn_flops``, over the
+valid positions only) over the device time inside the ``bench.step``
+spans.  Whatever implements the step, the work counted is the same, so
+this bounds any gain claimed on the cell's token rate."""
+
+
+def read(t):
+    steps = t.named("bench.step")
+    busy = t.busy_s(steps) if steps else 0.0
+    if busy <= 0 or "attn_flops" not in t.work:
+        return None
+    return 100.0 * t.work["attn_flops"] / t.peaks["flops_bf16"] / busy

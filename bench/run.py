@@ -25,6 +25,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -88,6 +89,19 @@ def memory_peak(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices)
 
 
+def describe_times(times: list[float]) -> str:
+    """The timed calls' seconds for the run's log: every one where they are
+    few, else their quantiles and the slowest, so that a slow run shows
+    whether all its calls were slow or a few."""
+    if len(times) <= 64:
+        return f"{len(times)} calls, ms each: " + " ".join(f"{1000.0 * t:.3f}" for t in times)
+    ms = sorted(1000.0 * t for t in times)
+    q = statistics.quantiles(ms, n=20)
+    return (f"{len(ms)} calls, ms: min {ms[0]:.3f}, p5 {q[0]:.3f}, p25 {q[4]:.3f}, "
+            f"median {q[9]:.3f}, p75 {q[14]:.3f}, p95 {q[18]:.3f}; slowest "
+            + " ".join(f"{t:.3f}" for t in ms[-5:]))
+
+
 def run_cell(doc: dict, name: str, seed: int, seconds: float, trace: bool, *,
              devices, peaks: dict, t_start: float, is_ops=None,
              parts: tuple[dict, dict, dict, dict] | None = None) -> dict:
@@ -107,8 +121,8 @@ def run_cell(doc: dict, name: str, seed: int, seconds: float, trace: bool, *,
     log(f"[{name}] seed {seed}: set-up")
     drv.setup()
     setup_s = time.perf_counter() - t_start
-    log(f"[{name}] set-up {setup_s:.3f} s, {compiles.count(COMPILE_EVENTS[0])} compiles, "
-        f"{compiles.count(COMPILE_EVENTS[1])} compile-cache hits; "
+    log(f"[{name}] set-up {setup_s:.3f} s, {compiles.count(COMPILE_EVENTS[0])} programs built, "
+        f"{compiles.count(COMPILE_EVENTS[1])} of them from the compile cache; "
         f"window {seconds} s, trace {int(trace)}")
     compiles.clear()
     try:
@@ -121,7 +135,8 @@ def run_cell(doc: dict, name: str, seed: int, seconds: float, trace: bool, *,
         jax.monitoring.unregister_event_duration_listener(listen)
     mem = memory_peak(devices)
     log(f"[{name}] window {w['elapsed']:.3f} s, {w['attempted']} attempted; "
-        f"compiles in window: {len(compiles)}")
+        f"programs built in window: {compiles.count(COMPILE_EVENTS[0])}")
+    log(f"[{name}] {describe_times(w['times'])}")
 
     out = drv.outputs()
     t0 = time.perf_counter()

@@ -17,7 +17,7 @@ import run
 from conftest import BENCH, ROOT, SEED, cpu_ops
 
 CELLS = ["deepseek-7b-kv.roundtrip", "deepseek-7b-kv.decode"]
-PEAKS = {"hbm_bytes_s": 819e9}
+PEAKS = {"hbm_bytes_s": 819e9, "flops_bf16": 197e12}
 
 
 def small_run(doc, parts, name, trace=False, seconds=0.3):
@@ -35,6 +35,8 @@ def test_cell_runs_correct(doc, small_parts, name, trace):
     if trace:
         want = {m["name"] for m in run.per_layer_for(doc, name)}
         assert set(res["metrics"]) == want
+        if name == "deepseek-7b-kv.decode":
+            assert "decode_mfu" in want and 0 < res["metrics"]["decode_mfu"]["value"] < 100
         assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
         assert res["breakdown"]["device_ops"]
     else:

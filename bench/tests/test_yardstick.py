@@ -166,6 +166,32 @@ def test_readers_on_a_synthetic_trace():
     assert dict(t.device_ops()) == pytest.approx({"enc": 1.0, "dec": 1.0, "late": 1.0})
 
 
+def test_decode_mfu_on_a_synthetic_trace():
+    """Three steps of 20 ms device time each inside their spans, a stray op
+    outside them, and the FLOPs `attn_flops` counts for three steps at
+    positions 100..102 of a 2-sequence, 4-head, 8-wide attention."""
+    import byname
+    import run
+
+    flops = byname.load("drivers", "decode").attn_flops
+    # q.K and p.V: 2 FLOPs per multiply-add each, over (pos + 1) positions
+    assert flops(2, 4, 8, 101) == 2 * 2 * (2 * 4 * 8 * 101)
+    work = sum(flops(2, 4, 8, p + 1) for p in (100, 101, 102))
+    spans = [("bench.window", 0.0, 1.0)] + [("bench.step", 0.1 * i, 0.1 * i + 0.05)
+                                             for i in range(1, 4)]
+    ops = {"/device:TPU:0": [(f"step{i}", 0.1 * i + 0.01, 0.1 * i + 0.03) for i in range(1, 4)]
+           + [("stray", 0.6, 0.9)]}
+    t = traces.Trace(spans=spans, host=[], ops=ops, work={"steps": 3, "attn_flops": work},
+                     peaks={"flops_bf16": 1e9})
+    assert run.reader("decode_mfu")(t) == pytest.approx(100.0 * work / 1e9 / 0.06)
+    assert run.reader("kv_step_device_ms")(t) == pytest.approx(20.0)
+    assert run.reader("device_idle_pct.decode")(t) == pytest.approx(100.0 * (1 - 0.36))
+    # without step spans, or without `attn_flops` in the work, nothing to read
+    assert run.reader("decode_mfu")(synthetic()) is None
+    t.work = {"steps": 3}
+    assert run.reader("decode_mfu")(t) is None
+
+
 def test_reduction_of_a_cpu_trace():
     f = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((256, 256))
