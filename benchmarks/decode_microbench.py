@@ -122,11 +122,11 @@ def main(argv: list[str] | None = None) -> None:
             us_med = statistics.median(times) * 1e6
             us_best = min(times) * 1e6
             rows.append({"context": context, "mode": mode,
-                         "n_pages": spec.n_pages,
+                         "n_pages": spec.n_slots,
                          "us_per_token": us_med, "us_best": us_best,
                          "repeats": args.repeats})
             print(f"decode_microbench/ctx{context}_{mode},{us_med:.1f},"
-                  f"best={us_best:.1f};n_pages={spec.n_pages}")
+                  f"best={us_best:.1f};n_pages={spec.n_slots}")
 
     summary = {}
     for mode in MODES:

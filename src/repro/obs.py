@@ -7,8 +7,9 @@ Under a JAX trace it is a null context, so a jitted caller records no
 span at trace time.
 
 The phase names label ``jax.named_scope`` regions: inside the Pallas
-kernels they lower to Mosaic trace regions; in the XLA chain and the KV
-cache they become HLO op metadata, which costs nothing on the device.
+kernels they lower to Mosaic trace regions; in the XLA chain, the KV
+cache and the MLA layer they become HLO op metadata, which costs nothing
+on the device.
 
 The TPU compiler drops the kernels' regions unless libtpu runs with
 ``--xla_enable_custom_call_region_trace=true``; an operator appends it to
@@ -43,6 +44,11 @@ DECODE_PHASES = (DECODE_WIDEN, DECODE_POINTERS, DECODE_BUCKETS, DECODE_OUTLIERS)
 KV_FLUSH_ENCODE = "kv.flush_encode"   # the flush's encode of the full tail page
 KV_FLUSH_DECODE = "kv.flush_decode"   # the flush's decode into the resident region
 KV_ATTEND = "kv.attend"               # attention over the cache
+
+MLA_Q_PROJ = "mla.q_proj"             # q_a, q_norm, q_b, rope on q_pe
+MLA_KV_PROJ = "mla.kv_proj"           # kv_a, kv_norm, rope on k_pe: the latent row
+MLA_ABSORB = "mla.absorb"             # q_nope through W_UK into the latent
+MLA_OUT_PROJ = "mla.out_proj"         # W_UV, then o_proj
 
 
 def under_trace(*leaves: Any) -> bool:

@@ -23,9 +23,10 @@ import numpy as np
 import numpy.typing as npt
 
 from repro import obs
+from repro.models import mla
 from repro.models.api import Model
 from repro.serving import kv_cache
-from repro.serving.kv_cache import KVSpec
+from repro.serving.kv_cache import KVSpec, LatentSpec, Spec
 
 
 @dataclasses.dataclass
@@ -152,23 +153,71 @@ class Engine:
         return any(r is not None and not r.done for r in self.slot_req)
 
 
-class KVSession:
-    """Serving-shaped driver over one compressed KV cache (single layer).
+def _mla_step(spec: LatentSpec, cfg: mla.MLAConfig, params: list[mla.Params],
+              caches: list[kv_cache.Cache], xs: jax.Array,
+              pos: jax.Array) -> tuple[jax.Array, jax.Array, list[kv_cache.Cache]]:
+    """Every layer's absorbed decode step at ``pos``: its token's latent
+    row appended to its cache, then attention over that cache.  Returns
+    the layers' outputs, their latent attention outputs (before ``W_UV``)
+    and the caches."""
+    outs, attns, new = [], [], []
+    scale = mla.softmax_scale(cfg)
+    for i, (p, cache) in enumerate(zip(params, caches)):
+        row, q = mla.decode_in(p, cfg, xs[i], pos)
+        cache = kv_cache.append_rows(spec, cache, {"c": row}, pos)
+        o = kv_cache.attention_decode_latent(spec, q, cache, pos, scale)
+        outs.append(mla.decode_out(p, cfg, o))
+        attns.append(o)
+        new.append(cache)
+    return jnp.stack(outs), jnp.stack(attns), new
 
-    Owns the cache tree and the decode position; every entry point is one
-    jitted dispatch.  This is the surface the decode-steady-state
-    microbench (``benchmarks/decode_microbench.py``) and the incremental
-    property tests drive: ``step`` is the per-token serving cost under
+
+def _mla_prefill(spec: LatentSpec, caches: list[kv_cache.Cache], tables: list[Any],
+                 rows: jax.Array, start: jax.Array) -> list[kv_cache.Cache]:
+    """Each layer's latent rows ``rows[i]`` written as whole flush groups;
+    the caches come and go without their base tables (which the caller
+    keeps), so that they can be donated."""
+    out = []
+    for i, (c, t) in enumerate(zip(caches, tables)):
+        new = kv_cache.prefill_groups(spec, {**c, "table": t}, {"c": rows[i]}, start)
+        out.append({k: v for k, v in new.items() if k != "table"})
+    return out
+
+
+class KVSession:
+    """Serving-shaped driver over compressed caches.
+
+    Owns the cache trees and the decode position; every entry point is one
+    jitted dispatch.
+
+    Over one K/V layer (``spec`` a :class:`KVSpec`, ``table`` its base
+    table) this is the surface the decode-steady-state microbench
+    (``benchmarks/decode_microbench.py``) and the incremental property
+    tests drive: ``step(q, k, v)`` is the per-token serving cost under
     measurement — with ``spec.resident_decode`` it overlays the raw tail
     over the flush-maintained decoded region (flat in context length);
     without it every step re-decodes all pages (linear).
+
+    Over a stack of MLA layers (``spec`` a :class:`LatentSpec`,
+    ``layers=(cfg, params)`` with one parameter tree per layer, ``table``
+    one base table per layer) each layer owns a latent cache:
+    ``prefill(rows)`` writes every layer's context as whole flush groups,
+    and ``step(x)`` runs every layer's absorbed decode step in one
+    dispatch.
     """
 
-    def __init__(self, spec: KVSpec, batch: int, table: Any, *,
-                 backend: str = "auto") -> None:
-        self.spec, self.backend = spec, backend
-        self.cache = kv_cache.init_compressed(spec, batch, table)
+    def __init__(self, spec: Spec, batch: int, table: Any, *, backend: str = "auto",
+                 layers: tuple[mla.MLAConfig, list[mla.Params]] | None = None) -> None:
+        self.spec, self.backend, self.layers = spec, backend, layers
         self.pos = 0
+        if layers is not None:
+            assert isinstance(spec, LatentSpec)
+            self.cache: Any = [kv_cache.init_compressed(spec, batch, t) for t in table]
+            self._step = jax.jit(functools.partial(_mla_step, spec, layers[0]))
+            self._prefill = jax.jit(functools.partial(_mla_prefill, spec), donate_argnums=(0,))
+            return
+        assert isinstance(spec, KVSpec)
+        self.cache = kv_cache.init_compressed(spec, batch, table)
         self._append = jax.jit(functools.partial(kv_cache.append, spec))
         self._attend = jax.jit(functools.partial(
             kv_cache.attention_decode, spec, backend=backend))
@@ -184,8 +233,23 @@ class KVSession:
 
         self._prefill = jax.jit(functools.partial(prefill_body, spec))
 
-    def prefill(self, ks: jax.Array, vs: jax.Array) -> None:
-        """Append a whole (B, T, Kv, hd) context in one fori_loop dispatch."""
+    def prefill(self, *rows: jax.Array) -> None:
+        """K/V: ``prefill(ks, vs)`` appends a whole (B, T, Kv, hd) context
+        in one fori_loop dispatch.  MLA layers: ``prefill(rows)`` writes
+        each layer's latent rows (L, B, T, R) at the current position, a
+        multiple of the flush group as T is, in one dispatch that encodes
+        (and decodes into the resident region) every page through
+        :mod:`repro.kernels.ops`."""
+        if self.layers is not None:
+            (c,) = rows
+            tables = [cache.pop("table") for cache in self.cache]
+            with obs.span("kv.prefill_groups"):
+                self.cache = self._prefill(self.cache, tables, c, jnp.int32(self.pos))
+            for cache, t in zip(self.cache, tables):
+                cache["table"] = t
+            self.pos += int(c.shape[2])
+            return
+        ks, vs = rows
         with obs.span("kv.prefill"):
             self.cache = self._prefill(ks, vs, self.cache, jnp.int32(self.pos))
         self.pos += int(ks.shape[1])
@@ -196,10 +260,23 @@ class KVSession:
             self.cache = self._append(self.cache, k, v, jnp.int32(self.pos))
         self.pos += 1
 
-    def step(self, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-        """One decode step: append this token's K/V, attend with ``q`` over
-        everything appended so far.  Returns (B, 1, H*hd)."""
+    def step(self, *inputs: jax.Array) -> Any:
+        """One decode step.  K/V: ``step(q, k, v)`` appends this token's
+        K/V and attends with ``q`` over everything appended so far; returns
+        (B, 1, H*hd).  MLA layers: ``step(x)`` takes each layer's hidden
+        state (L, B, 1, hidden) and returns each layer's attention sublayer
+        output (L, B, 1, hidden) and its latent attention output
+        (L, B, 1, H, kv_lora_rank), the heads' softmax-weighted latent rows
+        before ``W_UV``."""
+        if self.layers is not None:
+            (x,) = inputs
+            with obs.span("kv.step"):
+                out, attn, self.cache = self._step(self.layers[1], self.cache, x,
+                                                   jnp.int32(self.pos))
+            self.pos += 1
+            return out, attn
+        q, k, v = inputs
         self.append(k, v)
         with obs.span("kv.attend"):
-            out: jax.Array = self._attend(q, self.cache, jnp.int32(self.pos - 1))
+            out = self._attend(q, self.cache, jnp.int32(self.pos - 1))
         return out

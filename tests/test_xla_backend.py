@@ -270,7 +270,7 @@ def test_paged_attention_xla_matches_oracle():
         qg, cache["k_pages"], cache["v_pages"], cache["table"], pos, spec.fr,
         n_kv=KV, hd=HD, groups=G,
     )
-    pt = spec.page_tokens
+    pt = spec.group_tokens
     lim = (int(pos) // pt) * pt
     Kt = cache["k_tail"].astype(jnp.float32)
     Vt = cache["v_tail"].astype(jnp.float32)
