@@ -172,16 +172,17 @@ def _mla_step(spec: LatentSpec, cfg: mla.MLAConfig, params: list[mla.Params],
     return jnp.stack(outs), jnp.stack(attns), new
 
 
-def _mla_prefill(spec: LatentSpec, caches: list[kv_cache.Cache], tables: list[Any],
-                 rows: jax.Array, start: jax.Array) -> list[kv_cache.Cache]:
-    """Each layer's latent rows ``rows[i]`` written as whole flush groups;
-    the caches come and go without their base tables (which the caller
-    keeps), so that they can be donated."""
-    out = []
-    for i, (c, t) in enumerate(zip(caches, tables)):
-        new = kv_cache.prefill_groups(spec, {**c, "table": t}, {"c": rows[i]}, start)
-        out.append({k: v for k, v in new.items() if k != "table"})
-    return out
+def _mla_prefill(spec: LatentSpec, caches: list[kv_cache.Cache], rows: jax.Array,
+                 start: jax.Array) -> list[kv_cache.Cache]:
+    """Each layer's latent rows ``rows[i]`` written as whole flush groups."""
+    return [kv_cache.prefill_groups(spec, c, {"c": rows[i]}, start)
+            for i, c in enumerate(caches)]
+
+
+@jax.jit
+def _adopt(tree: Any) -> Any:
+    """A copy of a cache tree in new buffers."""
+    return jax.tree.map(jnp.copy, tree)
 
 
 class KVSession:
@@ -204,21 +205,33 @@ class KVSession:
     ``prefill(rows)`` writes every layer's context as whole flush groups,
     and ``step(x)`` runs every layer's absorbed decode step in one
     dispatch.
+
+    Every entry point that updates the cache donates the tree it consumes,
+    so XLA updates its leaves in place.  Only a tree the session alone
+    holds is donated: reading ``cache`` lends the tree (the caller may
+    keep it), and so does assigning one.  The next update first copies a
+    lent tree into buffers of the session's own, once, under a
+    ``kv.adopt`` span; ``cache_copies`` counts these copies.  The session
+    keeps its own copy of the base tables it is given.
     """
 
     def __init__(self, spec: Spec, batch: int, table: Any, *, backend: str = "auto",
                  layers: tuple[mla.MLAConfig, list[mla.Params]] | None = None) -> None:
         self.spec, self.backend, self.layers = spec, backend, layers
         self.pos = 0
+        self.cache_copies = 0
+        self._lent = False
+        table = jax.tree.map(jnp.copy, table)     # the updates donate it with the cache
         if layers is not None:
             assert isinstance(spec, LatentSpec)
-            self.cache: Any = [kv_cache.init_compressed(spec, batch, t) for t in table]
-            self._step = jax.jit(functools.partial(_mla_step, spec, layers[0]))
+            self._cache: Any = [kv_cache.init_compressed(spec, batch, t) for t in table]
+            self._step = jax.jit(functools.partial(_mla_step, spec, layers[0]),
+                                 donate_argnums=(1,))
             self._prefill = jax.jit(functools.partial(_mla_prefill, spec), donate_argnums=(0,))
             return
         assert isinstance(spec, KVSpec)
-        self.cache = kv_cache.init_compressed(spec, batch, table)
-        self._append = jax.jit(functools.partial(kv_cache.append, spec))
+        self._cache = kv_cache.init_compressed(spec, batch, table)
+        self._append = jax.jit(functools.partial(kv_cache.append, spec), donate_argnums=(0,))
         self._attend = jax.jit(functools.partial(
             kv_cache.attention_decode, spec, backend=backend))
 
@@ -231,7 +244,27 @@ class KVSession:
             out: kv_cache.Cache = jax.lax.fori_loop(0, ks.shape[1], body, cache)
             return out
 
-        self._prefill = jax.jit(functools.partial(prefill_body, spec))
+        self._prefill = jax.jit(functools.partial(prefill_body, spec), donate_argnums=(2,))
+
+    @property
+    def cache(self) -> Any:
+        """The cache tree: a dict over a K/V layer, a list of them over MLA
+        layers.  Reading it lends it to the caller."""
+        self._lent = True
+        return self._cache
+
+    @cache.setter
+    def cache(self, tree: Any) -> None:
+        self._cache, self._lent = tree, True
+
+    def _own(self) -> Any:
+        """The cache tree, for a call to donate: a lent tree is copied."""
+        if self._lent:
+            with obs.span("kv.adopt"):
+                self._cache = _adopt(self._cache)
+            self._lent = False
+            self.cache_copies += 1
+        return self._cache
 
     def prefill(self, *rows: jax.Array) -> None:
         """K/V: ``prefill(ks, vs)`` appends a whole (B, T, Kv, hd) context
@@ -242,22 +275,19 @@ class KVSession:
         :mod:`repro.kernels.ops`."""
         if self.layers is not None:
             (c,) = rows
-            tables = [cache.pop("table") for cache in self.cache]
             with obs.span("kv.prefill_groups"):
-                self.cache = self._prefill(self.cache, tables, c, jnp.int32(self.pos))
-            for cache, t in zip(self.cache, tables):
-                cache["table"] = t
+                self._cache = self._prefill(self._own(), c, jnp.int32(self.pos))
             self.pos += int(c.shape[2])
             return
         ks, vs = rows
         with obs.span("kv.prefill"):
-            self.cache = self._prefill(ks, vs, self.cache, jnp.int32(self.pos))
+            self._cache = self._prefill(ks, vs, self._own(), jnp.int32(self.pos))
         self.pos += int(ks.shape[1])
 
     def append(self, k: jax.Array, v: jax.Array) -> None:
         """Append one token's (B, 1, Kv, hd) K/V at the current position."""
         with obs.span("kv.append"):
-            self.cache = self._append(self.cache, k, v, jnp.int32(self.pos))
+            self._cache = self._append(self._own(), k, v, jnp.int32(self.pos))
         self.pos += 1
 
     def step(self, *inputs: jax.Array) -> Any:
@@ -271,12 +301,12 @@ class KVSession:
         if self.layers is not None:
             (x,) = inputs
             with obs.span("kv.step"):
-                out, attn, self.cache = self._step(self.layers[1], self.cache, x,
-                                                   jnp.int32(self.pos))
+                out, attn, self._cache = self._step(self.layers[1], self._own(), x,
+                                                    jnp.int32(self.pos))
             self.pos += 1
             return out, attn
         q, k, v = inputs
         self.append(k, v)
         with obs.span("kv.attend"):
-            out = self._attend(q, self.cache, jnp.int32(self.pos - 1))
+            out = self._attend(q, self._cache, jnp.int32(self.pos - 1))
         return out

@@ -277,9 +277,79 @@ def test_session_programs_carry_the_scopes(entry):
         want = {obs.MLA_Q_PROJ, obs.MLA_KV_PROJ, obs.MLA_ABSORB, obs.MLA_OUT_PROJ,
                 obs.KV_ATTEND, obs.KV_FLUSH_ENCODE, obs.KV_FLUSH_DECODE}
     else:
-        caches = [{k: v for k, v in c.items() if k != "table"} for c in sess.cache]
-        fn, args = sess._prefill, (caches, [c["table"] for c in sess.cache],
-                                   jnp.zeros((1, 1, 8, 80), jnp.bfloat16), jnp.int32(0))
+        fn, args = sess._prefill, (sess.cache, jnp.zeros((1, 1, 8, 80), jnp.bfloat16),
+                                   jnp.int32(0))
         want = {obs.KV_FLUSH_ENCODE, obs.KV_FLUSH_DECODE}
     text = fn.lower(*args).as_text(debug_info=True)
     assert {name for name in want if f"{name}/" in text} == want
+
+
+# ---------------------------------------------------------------------------
+# the session's ownership of its cache: donated when its own, copied when lent
+# ---------------------------------------------------------------------------
+
+def _kv_session():
+    """One K/V layer (32-word rows: a flush group of 4 tokens in 1 page),
+    8 tokens prefilled, its base table and its steps' inputs."""
+    spec = kvc.KVSpec(n_kv=2, head_dim=16, max_len=32, fr=FR, resident_decode=True)
+    ks, vs = (jax.random.normal(jax.random.PRNGKey(i), (B, 20, 2, 16)).astype(jnp.bfloat16)
+              for i in (3, 4))
+    qs = jax.random.normal(jax.random.PRNGKey(5), (12, B, 1, 4, 16)).astype(jnp.bfloat16)
+    table = _table(jnp.concatenate([ks, vs], axis=1))
+    sess = KVSession(spec, B, table)
+    sess.prefill(ks[:, :8], vs[:, :8])
+    return sess, table, 8, [(qs[i], ks[:, 8 + i:9 + i], vs[:, 8 + i:9 + i]) for i in range(12)]
+
+
+def _mla_session():
+    """Two MLA layers (a flush group of 8 tokens), 16 tokens prefilled,
+    their base tables and the steps' inputs."""
+    L, T0 = 2, 16
+    params = [mla.init(jax.random.PRNGKey(20 + i), SMALL) for i in range(L)]
+    x_ctx = jax.random.normal(jax.random.PRNGKey(6), (L, B, T0, 256)).astype(jnp.bfloat16)
+    xs = jax.random.normal(jax.random.PRNGKey(7), (12, L, B, 1, 256)).astype(jnp.bfloat16)
+    rows = jnp.stack([mla.latent_rows(params[i], SMALL, x_ctx[i], jnp.arange(T0))
+                      for i in range(L)])
+    tables = [_table(rows[i]) for i in range(L)]
+    sess = KVSession(_latent_spec(32), B, tables, layers=(SMALL, params))
+    sess.prefill(rows)
+    return sess, tables, T0, [(xs[i],) for i in range(12)]
+
+
+@pytest.mark.parametrize("kind", ["kv", "mla"])
+def test_session_donates_only_the_cache_it_owns(kind):
+    """A step on a cache the session made deletes the tree it consumed; a
+    tree read through ``cache`` or assigned to it, and the base tables the
+    session was given, are never deleted; restarts from the saved
+    post-prefill tree give the first pass's outputs bit for bit, at one
+    copy of the saved tree each, and build no program once the first pass
+    has run.  Each pass of 12 steps crosses a flush."""
+    sess, tables, T0, steps = _kv_session() if kind == "kv" else _mla_session()
+    assert sess.cache_copies == 0                    # prefill updated the session's own
+    post = sess.cache
+    passes, held, built = [], [], []
+    listen = lambda event, duration, **kw: built.append(event) \
+        if event == "/jax/core/compile/backend_compile_duration" else None  # noqa: E731
+    for n in range(3):
+        if n == 1:                                   # warm: no restart or read compiles
+            jax.monitoring.register_event_duration_secs_listener(listen)
+        sess.cache, sess.pos = post, T0
+        outs = []
+        for i, inp in enumerate(steps):
+            read = n == 2 and i == 5
+            if read:
+                held.append(sess.cache)              # a tree read mid-pass
+            before = jax.tree.leaves(sess._cache)
+            outs.append(jax.tree.leaves(sess.step(*inp)))
+            if i > 0 and not read:                   # the session's own tree was donated
+                assert all(x.is_deleted() for x in before), (n, i)
+        passes.append(outs)
+        assert sess.cache_copies == n + 1 + len(held)
+    jax.monitoring.unregister_event_duration_listener(listen)
+    assert not built
+    for tree in (post, *held, tables):
+        assert not any(x.is_deleted() for x in jax.tree.leaves(tree))
+    for n in (1, 2):
+        for i, (a, b) in enumerate(zip(passes[0], passes[n])):
+            for x, y in zip(a, b):
+                _bit_equal(x, y, f"pass {n}, step {i}")
