@@ -7,8 +7,8 @@ warmed, median-of-K encode/decode GiB/s for every codec x workload
 family, written to ``experiments/BENCH_throughput.json``.
 
 Codec roles on CPU: ``gbdi``/``bdi`` are the numpy host codecs, ``fr`` is
-the vmapped jnp oracle, ``fr_xla`` is the compiled batched fast path (the
-CPU datapoint, fronted by :mod:`repro.kernels.pipeline`), and
+the vmapped jnp oracle, ``fr_xla`` is the XLA chain (the CPU datapoint,
+through :mod:`repro.kernels.ops`), and
 ``fr_kernel`` interprets the Pallas kernels on a small stream — a
 correctness reference whose timing is NOT TPU-representative; its rows
 are marked ``truncated`` with the requested size recorded.  Rows carry a

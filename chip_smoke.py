@@ -10,11 +10,11 @@ against the repo's own reference:
 * codec — 256 MiB per family (``ml_kvcache_bf16`` under the serving
   cache's ``KV_FR``, ``605.mcf_s`` under the eval's 32-bit default) through
   ``kernels.ops`` with ``backend="auto"`` (the Pallas kernels on TPU) and
-  through ``kernels.pipeline`` (the XLA chain serving and collectives
-  call).  Blobs must be bit-identical to the jnp oracle on a 1024-page
-  sample and to each other on the whole stream; every page without a
-  dropped word must round-trip exactly, and no page may get more words
-  wrong than it dropped.
+  with ``backend="xla"`` (the XLA chain serving and collectives call).
+  Blobs must be bit-identical to the jnp oracle on a 1024-page sample and
+  to each other on the whole stream; every page without a dropped word
+  must round-trip exactly, and no page may get more words wrong than it
+  dropped.
 * serving — deepseek-7b at its published widths, depth cut to 4 layers,
   through the same ``launch.serve.serve`` as ``python -m
   repro.launch.serve``: 12 requests on 8 slots, prompts of 256 and 1024
@@ -98,7 +98,7 @@ def codec_phase(seed: int) -> None:
     from repro.core.gbdi_fr import fit_fr_bases, fr_decode
     from repro.eval.codecs import FRCodec
     from repro.eval.workloads import default_workloads
-    from repro.kernels import ops, pipeline
+    from repro.kernels import ops
     from repro.kernels.gbdi_encode import DEFAULT_PAGES_PER_TILE
     from repro.kernels.ref import encode_ref
     from repro.serving.kv_cache import KV_FR
@@ -134,8 +134,8 @@ def codec_phase(seed: int) -> None:
             f"Pallas grid tile {DEFAULT_PAGES_PER_TILE} pages")
 
         kblob = first_and_steady("encode, ops auto (Pallas)", ops.encode_pages, pages, table, cfg)
-        xblob = first_and_steady("encode, pipeline (XLA chain)", pipeline.encode_pages,
-                                 pages, table, cfg)
+        xblob = first_and_steady("encode, ops xla (XLA chain)", ops.encode_pages,
+                                 pages, table, cfg, backend="xla")
         rows = np.sort(np.random.default_rng(seed).choice(n_pages, ORACLE_PAGES, replace=False))
         sample = pages[rows]
         rblob = first_and_steady(f"encode, jnp oracle ({ORACLE_PAGES} pages)",
@@ -153,8 +153,8 @@ def codec_phase(seed: int) -> None:
         log(f"  n_dropped={n_dropped} words on {lossy} pages, n_spilled={n_spilled} words")
 
         kdec = first_and_steady("decode, ops auto (Pallas)", ops.decode_pages, kblob, table, cfg)
-        xdec = first_and_steady("decode, pipeline (XLA chain)", pipeline.decode_pages,
-                                xblob, table, cfg)
+        xdec = first_and_steady("decode, ops xla (XLA chain)", ops.decode_pages,
+                                xblob, table, cfg, backend="xla")
         check(bool(jnp.array_equal(kdec[rows], fr_decode(rblob, table, cfg))),
               f"{fam}: Pallas decode bit-identical to the oracle decode on the sample")
         exact = int((kblob["n_dropped"] == 0).sum())
@@ -232,7 +232,7 @@ def compressed_kv_phase(eng, seed: int) -> None:
     import jax.numpy as jnp
 
     from repro.core.gbdi_fr import fit_fr_bases
-    from repro.kernels import pipeline
+    from repro.kernels import ops
     from repro.serving import kv_cache
     from repro.serving.engine import KVSession
 
@@ -257,8 +257,9 @@ def compressed_kv_phase(eng, seed: int) -> None:
     spread = np.linspace(0, n_tok - 1, 8).astype(int)
     table = fit_fr_bases(jnp.concatenate([words(K[:, spread]).reshape(-1),
                                           words(V[:, spread]).reshape(-1)]), spec.fr)
-    drops = {name: np.asarray(pipeline.encode_pages(
-        words(x).reshape(n_tok, -1, spec.fr.page_words), table, spec.fr)["n_dropped"].sum(axis=1))
+    drops = {name: np.asarray(ops.encode_pages(
+        words(x).reshape(n_tok, -1, spec.fr.page_words), table, spec.fr,
+        backend="xla")["n_dropped"].sum(axis=1))
         for name, x in (("K", K), ("V", V))}
     drops_upto = np.cumsum(drops["K"] + drops["V"])
     log(f"  n_dropped over the context: K {int(drops['K'].sum())}, "

@@ -6,7 +6,7 @@ it carries a jit decorator, looks like a Pallas kernel, or is lexically
 nested in one.  Real kernel code factors helpers out to module level
 (``_decode_words`` in ``gbdi_paged_attn.py``, the ``_class_update_impl``
 stage bodies in ``kernels/xla.py``), and a ``.item()`` inside such a
-helper serialises the pipeline exactly as hard as one written inline.
+helper serialises the program exactly as hard as one written inline.
 
 This module closes that gap without whole-program analysis: it builds the
 module-local call graph (who calls whom, among functions *defined in the
@@ -22,9 +22,9 @@ analysis pass never guesses a hazard it cannot see the trace context of.
 
 A second escape hatch keeps the pass quiet on deliberate host/device
 dispatchers: a function that is *not* lexically device but tests
-``isinstance(..., Tracer)`` in its body (``_decode_batch`` in
-``kernels/xla.py`` routes tracer tables to a ref graph and concrete
-tables to a host-built compiled chain) is a *trace boundary* — it is
+``isinstance(..., Tracer)`` in its body (``fit_fr_bases`` in
+``core/gbdi_fr.py`` filters a concrete sample on the host with numpy and
+takes a traced one as it is) is a *trace boundary* — it is
 still checked itself, but it does not transmit device context to its
 callees, because the calls on its concrete path run at trace time only
 when the guard has already proven the inputs are host values.

@@ -8,10 +8,10 @@ runtime, and only on paths that actually execute.  ``use-after-donate``
 finds those reads statically by tracking names through each function
 body in execution order.
 
-PR 8 retro-fitted eviction bounds onto the XLA stage memo caches after
-they grew without limit in long sweeps (``_const_stages``/``_dec_stages``
-keyed by table digest x config — every new table leaked a compiled
-closure).  ``unbounded-module-cache`` makes that class of leak a gate:
+A module-level memo keyed by content grows without limit in a long sweep
+unless it evicts (``_PREP_CACHE`` in ``kernels/xla.py`` is keyed by table
+digest x config, and every new table would pin its device arrays).
+``unbounded-module-cache`` makes that class of leak a gate:
 a module-level dict that function bodies insert into must also have an
 eviction path (``popitem``/``pop``/``del``/``clear``) or an explicit
 baseline entry saying why it is bounded.

@@ -30,7 +30,7 @@ from repro.core.format import (
     BaseTable,
 )
 from repro.core.gbdi_fr import FRConfig
-from repro.kernels import pipeline as fr_pipeline
+from repro.kernels import ops
 
 # Gradients are quality-critical: one 8-bit class with a full-page bucket
 # (the v2 single-width special case) — bucket overflow cannot occur, so
@@ -44,6 +44,10 @@ GRAD_FR = FRConfig(word_bits=16, page_words=DEFAULT_PAGE_WORDS,
                    bucket_caps=(DEFAULT_PAGE_WORDS,),
                    outlier_cap=DEFAULT_OUTLIER_CAP)
 
+# The ring exchange encodes and decodes on the XLA chain, inlined into the
+# pod shard_map program; no four-chip cell has measured the Pallas kernels here.
+_CODEC_BACKEND = "xla"
+
 
 def pod_shard_map(f, mesh, in_specs, out_specs, *, manual_axes=("pod",)):
     """shard_map manual over ``manual_axes`` only; the other mesh axes stay
@@ -56,21 +60,17 @@ def pod_shard_map(f, mesh, in_specs, out_specs, *, manual_axes=("pod",)):
 
 
 def encode_leaf(g: jax.Array, table: BaseTable):
-    """All pages of a leaf in one batched compiled dispatch (kernels.xla)."""
+    """All pages of a leaf in one batched encode on the XLA chain."""
     flat = g.astype(jnp.bfloat16).reshape(-1)
     words = jax.lax.bitcast_convert_type(flat, jnp.uint16).astype(jnp.int32)
     pad = (-words.shape[0]) % GRAD_FR.page_words
     words = jnp.pad(words, (0, pad))
-    # pipeline front-end is a no-op under the pod shard_map trace (the mesh
-    # already owns placement); eager unit tests get the sharding-aware path
-    return fr_pipeline.encode_pages(
-        words.reshape(-1, GRAD_FR.page_words), table, GRAD_FR)
+    return ops.encode_pages(
+        words.reshape(-1, GRAD_FR.page_words), table, GRAD_FR, backend=_CODEC_BACKEND)
 
 
 def _decode_leaf(blob, table: BaseTable, n, shape, dtype):
-    # same front-end as encode: no-op under the pod shard_map trace, the
-    # sharding-aware split for eager gradient decode
-    words = fr_pipeline.decode_pages(blob, table, GRAD_FR).reshape(-1)[:n]
+    words = ops.decode_pages(blob, table, GRAD_FR, backend=_CODEC_BACKEND).reshape(-1)[:n]
     flat = jax.lax.bitcast_convert_type(words.astype(jnp.uint16), jnp.bfloat16)
     return flat.astype(dtype).reshape(shape)
 

@@ -2,9 +2,8 @@
 
 The concrete codecs are the paper's GBDI host codec
 (:mod:`repro.core.gbdi`), the B∆I baseline (:mod:`repro.core.bdi`), and
-the fixed-rate device format GBDI-FR in its pure-jnp oracle, compiled
-batched XLA, and Pallas-kernel backends (:mod:`repro.core.gbdi_fr`,
-:mod:`repro.kernels.xla`, :mod:`repro.kernels`).
+the fixed-rate device format GBDI-FR through each backend of
+:mod:`repro.kernels.ops` (the oracle, the XLA chain, the Pallas kernels).
 
 The adapter contract (duck-typed, see :class:`repro.eval.registry.CodecRegistry`):
 
@@ -24,7 +23,6 @@ frame identically.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import numpy as np
@@ -32,26 +30,6 @@ import numpy as np
 from repro.core import bdi, gbdi
 from repro.core.gbdi_fr import FRConfig, fit_fr_bases, fr_decode, fr_encode
 from repro.eval.registry import CodecRegistry
-
-
-@functools.lru_cache(maxsize=4)
-def _word_cast(word_bits: int):
-    """Jitted signed-page-words -> unsigned-words cast (value-identical to
-    :func:`repro.core.gbdi.signed_to_words`, but on device: decoded pages
-    are already masked to word range, so for 16-bit words this also halves
-    the device->host transfer)."""
-    import jax
-    import jax.numpy as jnp
-
-    if word_bits == 32:
-        def cast32(pages):
-            return jax.lax.bitcast_convert_type(
-                pages.astype(jnp.int32), jnp.uint32)
-        return jax.jit(cast32)
-
-    def cast16(pages):
-        return (pages & 0xFFFF).astype(jnp.uint16)
-    return jax.jit(cast16)
 
 
 def word_bits_for_dtype(dtype) -> int:
@@ -118,7 +96,7 @@ class BDICodec:
 
 @dataclasses.dataclass
 class FRCodec:
-    """GBDI-FR v2 fixed-rate pages via the jnp oracle or the Pallas kernels.
+    """GBDI-FR v2 fixed-rate pages through :mod:`repro.kernels.ops`.
 
     v2: per-base width classes with bucketed delta sub-streams — zeros and
     outliers consume no payload, which puts the bf16 defaults strictly
@@ -129,13 +107,6 @@ class FRCodec:
 
     ``cfg`` overrides the per-word-size default — the ``--sweep`` harness
     uses it to walk num_bases / width_set / bucket_caps grids.
-
-    The ``xla`` backend routes through :mod:`repro.kernels.pipeline`:
-    ``devices`` forces an explicit shard count (default: the pipeline's
-    core-capped auto heuristic) and ``stream_batches > 1`` splits the
-    page batch into that many chunks fed through the double-buffered
-    ``encode_stream`` (host->device copy of chunk i+1 overlaps chunk
-    i's encode).  Both paths are bit-identical to the plain call.
     """
 
     word_bits: int = 16
@@ -143,8 +114,6 @@ class FRCodec:
     name: str = "fr"
     lossless: bool = False
     cfg: FRConfig | None = None
-    devices: int | None = None    # xla backend: explicit shard count
-    stream_batches: int = 0       # xla backend: >1 enables encode_stream
 
     def _config(self) -> FRConfig:
         if self.cfg is not None:
@@ -172,26 +141,13 @@ class FRCodec:
         from repro.kernels import ops
 
         cfg = self._config()
-        backend = ops.resolve_backend(self.backend)
         words = gbdi.to_words(data, cfg.word_bits)
         signed = gbdi.words_to_signed(words, cfg.word_bits)
         n = signed.size
         pad = (-n) % cfg.page_words
         pages = np.pad(signed, (0, pad)).reshape(-1, cfg.page_words)
-        if backend == "xla":
-            from repro.kernels import pipeline
-
-            if self.stream_batches > 1 and pages.shape[0] >= self.stream_batches:
-                parts = np.array_split(pages, self.stream_batches)
-                blobs = list(pipeline.encode_stream(parts, table, cfg))
-                blob = {k: jnp.concatenate([b[k] for b in blobs])
-                        for k in blobs[0]}
-            else:
-                blob = dict(pipeline.encode_pages(
-                    jnp.asarray(pages), table, cfg, devices=self.devices))
-        else:
-            blob = dict(ops.encode_pages(jnp.asarray(pages), table, cfg,
-                                         backend=backend))
+        blob = dict(ops.encode_pages(jnp.asarray(pages), table, cfg,
+                                     backend=self.backend))
         blob.update(_table=table, _cfg=cfg, _n_words=n)
         return blob
 
@@ -200,34 +156,7 @@ class FRCodec:
 
         cfg: FRConfig = blob["_cfg"]
         inner = {k: v for k, v in blob.items() if not k.startswith("_")}
-        backend = ops.resolve_backend(self.backend)
-        if backend == "xla":
-            import jax.numpy as jnp
-
-            from repro.kernels import pipeline
-
-            # page count is static metadata — read it off the shape, no
-            # device->host sync
-            n_pages = int(np.prod(inner["n_out"].shape))
-            if self.stream_batches > 1 and n_pages >= self.stream_batches:
-                bounds = np.array_split(np.arange(n_pages),
-                                        self.stream_batches)
-                parts = ({k: v[idx[0]:idx[-1] + 1] for k, v in inner.items()}
-                         for idx in bounds)
-                pages = jnp.concatenate(
-                    list(pipeline.decode_stream(parts, blob["_table"], cfg)))
-                pages = _word_cast(cfg.word_bits)(pages)
-            else:
-                # unsigned decode fuses the word cast into the compiled
-                # chain (and halves the 16-bit device->host transfer)
-                pages = pipeline.decode_pages(inner, blob["_table"], cfg,
-                                              devices=self.devices,
-                                              unsigned=True)
-            # flatten on the host view — an eager device reshape would
-            # copy the buffer
-            words = np.asarray(pages).reshape(-1)
-            return words[: blob["_n_words"]]   # host view, no device slice
-        pages = ops.decode_pages(inner, blob["_table"], cfg, backend=backend)
+        pages = ops.decode_pages(inner, blob["_table"], cfg, backend=self.backend)
         signed = np.asarray(pages).reshape(-1)[: blob["_n_words"]]
         return gbdi.signed_to_words(signed, cfg.word_bits)
 

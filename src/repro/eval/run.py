@@ -37,8 +37,8 @@ to the repo root (trajectory tracking reads root ``BENCH_*.json``).
 ``--throughput`` is the perf baseline: warmed, median-of-K encode/decode
 GiB/s per codec x workload family (no CR columns, no verification), with
 a ``BENCH_throughput.json`` artifact.  The compiled ``fr_xla`` backend is
-the CPU datapoint (via the :mod:`repro.kernels.pipeline` front-end, so
-rows record the visible ``devices`` count); interpret-mode ``fr_kernel``
+the CPU datapoint (the XLA chain through :mod:`repro.kernels.ops`; rows
+record the visible ``devices`` count); interpret-mode ``fr_kernel``
 runs on a small stream as a correctness reference, not a throughput
 claim — those rows carry ``truncated: true`` plus ``n_bytes_requested``
 and are flagged in the table (no silent caps).  Every row is roofline-

@@ -1,24 +1,22 @@
-"""Public wrappers around the GBDI-FR codec with backend selection.
+"""The codec's one entry point: every encode and decode outside
+``repro.kernels`` goes through :func:`encode_pages`/:func:`decode_pages`
+(or their tensor-level wrappers).
 
-Backends (all produce bit-identical blobs):
+Backends, all bit-identical on every blob and word:
 
-* ``'ref'``    — the pure-jnp oracle (:mod:`repro.kernels.ref`), vmapped
-  per-page; the semantic ground truth.
 * ``'kernel'`` — the Pallas kernels: compiled on TPU, interpret mode
-  elsewhere.  Interpret mode is a correctness oracle, orders of magnitude
-  slower than compiled code — it runs only when a caller explicitly asks
-  for ``'kernel'`` off-TPU.
-* ``'xla'``    — the natively batched jit-compiled path
-  (:mod:`repro.kernels.xla`), fronted by the device-sharding pipeline
-  (:mod:`repro.kernels.pipeline`) for eager callers; memoized device
-  table constants.  The compiled fast path off TPU.
-* ``'auto'``   — resolves to ``'kernel'`` on TPU and ``'xla'`` everywhere
-  else; never resolves to interpret mode.  This is the default.
+  elsewhere (a correctness check, far slower than compiled code; it runs
+  only when a caller asks for ``'kernel'`` off TPU).
+* ``'xla'``    — the XLA chain (:mod:`repro.kernels.xla`), compiled on any
+  platform, inlined into the caller's program under a trace.
+* ``'ref'``    — the pure-jnp oracle (:mod:`repro.kernels.ref`), the
+  semantic reference.
+* ``'auto'``   — the default: ``'kernel'`` on TPU, ``'xla'`` elsewhere;
+  never interpret mode.
 
-Tensor-level helpers handle dtype bitcasting and page padding so callers
-hand in plain fp32/bf16/int32 tensors plus the fitted
-:class:`repro.core.format.BaseTable` (a bare bases array is accepted for
-v1 compatibility and treated as all-widest-class).
+The tensor-level helpers bitcast and pad plain fp32/bf16/int32 tensors
+into pages.  Tables are a fitted :class:`repro.core.format.BaseTable` (a
+bare bases array is taken as all-widest-class).
 """
 from __future__ import annotations
 
@@ -62,9 +60,7 @@ def encode_pages(
         if backend == "kernel":
             return gbdi_encode_pallas(x_pages, table, cfg, interpret=not _on_tpu())
         if backend == "xla":
-            from repro.kernels import pipeline as _pipeline
-
-            return _pipeline.encode_pages(x_pages, table, cfg)
+            return _xla.encode_pages(x_pages, table, cfg)
         return _ref.encode_ref(x_pages, table, cfg)
 
 

@@ -1,43 +1,37 @@
-"""Compiled batched GBDI-FR fast path: one XLA dispatch over many pages.
+"""The XLA chain: GBDI-FR v2 encode and decode as batched XLA programs.
 
-The Pallas kernels only compile on TPU — off-TPU they run in interpret
-mode, which is a correctness oracle, not an engine.  This module is the
-compiled CPU/GPU backend: GBDI-FR v2 encode/decode written *natively
-batched* — every op carries a leading page-batch axis (``(N, page_words)``
-in, ``(N, lanes)`` out) so the whole page batch lowers to a handful of
-fused XLA executables instead of a Python loop (or an interpret-mode
-grid) over single pages.  The encode is a short chain of fused stages
-(assign -> per-class compaction -> finalize); eagerly each stage is its
-own dispatch (XLA:CPU compiles the chain ~2.3x faster than the same
-graph as one mega-jit — see the note above ``_assign_batch``), while
-traced callers get everything inlined into their single program.  The
-decode mirrors it as a two-stage chain (rank-select expansion via one
-packed per-class prefix scan, then a payload gather with constant-baked
-per-code tables — see the layout notes above ``_dec_layout``); configs
-whose class caps don't fit the packed layout fall back to
-``_decode_batch_ref``, bit-identically.
+This is the compiled codec wherever the Pallas kernels do not compile
+(off TPU), and the chain a caller gets from ``repro.kernels.ops`` with
+``backend="xla"`` on any platform.  Every op carries a leading page-batch
+axis (``(N, page_words)`` in, ``(N, lanes)`` out), so a whole batch is a
+few fused programs, not a loop over pages.
 
-Bit-compatibility contract: blobs are **bit-identical** to the pure-jnp
-oracle (:mod:`repro.core.gbdi_fr`) and hence to the Pallas kernels, across
-width-set/bucket-cap configs including the narrow -> wide -> outlier spill
-chain.  The staged rewrite preserves the oracle's exact semantics: the
-lexicographic running minimum equals the oracle's width-cost argmin with
-first-index tie-break (``width_set`` is validated ascending), compaction
-ranks match the oracle's page-order prefix sums, dead entries for
-foreign-width bases never win.  The only representational change is
-replacing the oracle's outlier one-hot matmul with an equivalent integer
-scatter (distinct live positions, same values — still bit-exact),
-asserted in ``tests/test_xla_backend.py``.
+* Encode is a chain of jitted stages: base assignment, then per width
+  class the compaction positions and the packed sub-stream with its spill
+  step, then outliers/pointers/deltas, then the per-page profile pick.
+  Eagerly each stage is one dispatch.
+* Decode is one jitted program (:func:`_decode_batch`).
+* Under an outer trace (the KV cache's jitted flush, the gradient ring
+  exchange under ``shard_map``) every stage inlines into the caller's
+  program, and a traced table stays a runtime operand.
 
-Device-constant hygiene: :func:`prepare_table` memoizes the BaseTable ->
-device-array conversion (bases/widths upload + width-class codes), so
-repeated ``encode_pages`` calls with the same fitted table reuse the same
-device buffers — no per-call host->device round trips.  Traced tables
-(inside jit/shard_map) bypass the cache.
+Contract: blobs and decoded words are **bit-identical** to the pure-jnp
+oracle (:mod:`repro.core.gbdi_fr`) and hence to the Pallas kernels, for
+every width-set/bucket-cap/profile config, including the narrow -> wide
+-> outlier spill chain.  The lexicographic running minimum of
+:func:`_assign_batch` equals the oracle's width-cost argmin with
+first-index tie-break (``width_set`` is validated ascending); compaction
+ranks match the oracle's page-order prefix sums; bases of a foreign width
+never win.  The one representational change is the outlier scatter in
+place of the oracle's one-hot matmul (distinct live positions, the same
+values).  ``tests/test_xla_backend.py`` asserts all of it.
 
-Shape convention: public entry points accept any number of leading batch
-axes — ``(N, P)``, ``(B, n_pages, P)``, ... — flatten them into one page
-axis for the single jitted dispatch, and restore them on the outputs.
+:func:`prepare_table` memoizes the BaseTable -> device-array conversion
+by content, so eager calls with one fitted table reuse its device
+buffers; traced tables bypass the memo.
+
+Public entry points accept any number of leading batch axes and restore
+them on the outputs.
 """
 from __future__ import annotations
 
@@ -140,7 +134,9 @@ def prepare_table(table: TableLike | PreparedTable, cfg: FRConfig) -> PreparedTa
         _PREP_CACHE.move_to_end(key)
         return hit
     _PREP_STATS["misses"] += 1
-    prep = _build_prepared(table, cfg)
+    # the memo outlives the call, so it holds buffers of its own: a caller
+    # may donate its table later (KVSession donates tables with the cache)
+    prep = jax.tree.map(jnp.copy, _build_prepared(table, cfg))
     _PREP_CACHE[key] = prep
     while len(_PREP_CACHE) > _PREP_CAP:
         _PREP_CACHE.popitem(last=False)
@@ -161,21 +157,14 @@ def table_cache_clear() -> None:
 # ---------------------------------------------------------------------------
 # batched encode: a short chain of fused stage dispatches
 # ---------------------------------------------------------------------------
-# Why a chain and not one mega-jit: XLA:CPU's fusion heuristics inflate
-# gather costs inside very large graphs (concatenate-of-gather fusions
-# materialise fat (N, T, 2) index tensors), and the identical computation
-# chained as ~6 dispatches measures ~2.3x faster than the mono graph on a
-# 512-page x 2048-word bf16 stream (``lax.optimization_barrier`` does not
-# recover it).  Under an outer trace — collectives and kv_cache call
-# encode inside jit / shard_map — the stages inline into the caller's
-# single program, so traced callers still get one fused dispatch.
+# Each stage is its own jit: eagerly one dispatch a stage, under an outer
+# trace inlined into the caller's single program.
 #
 # Buffer donation: the per-class state is threaded linearly through the
 # chain, so each stage donates its ``state`` argument (the old buffers
 # are dead the moment the stage returns).  XLA:CPU declines donation for
 # some leaves and warns about it at lowering time; ``_encode_batch``
-# silences that advisory warning around its stage calls (on GPU/TPU the
-# donation halves the peak footprint of the chain state).
+# silences that advisory warning around its stage calls.
 
 #: per-page encoder state threaded through the class chain:
 #: (sel, cls, active, out_cand, n_spilled)
@@ -225,8 +214,7 @@ def _positions(wm: jax.Array, bcsum: jax.Array, t: int) -> jax.Array:
     the scatter-histogram of the (clamped) block cumsums — no gather over
     the page axis at all.  Two small (N, t) gathers (block word + rank
     before the block) and a 5-step popcount descend finish inside the
-    32-bit block.  Replaces the vmapped per-target binary search of the
-    previous fast path, whose page-axis gathers dominated the profile.
+    32-bit block.
     """
     n, nb = wm.shape
     tgt = jnp.arange(1, t + 1, dtype=bcsum.dtype)[None]
@@ -264,10 +252,10 @@ def _assign_batch(
     argmin with first-index tie-break because ``width_set`` is validated
     ascending — plus, per spill threshold i, the same minimum restricted
     to classes > i (the narrowest fitting wider base, precomputed here so
-    bucket overflow needs no second pass over the table).  The (N, P, k)
-    cost tensor of the previous fast path is never materialised; the fit
-    test is two arithmetic shifts (``d`` fits in w bits iff its top
-    ``word_bits - w + 1`` bits are all copies of the sign bit).
+    bucket overflow needs no second pass over the table).  No (N, P, k)
+    cost tensor is materialised; the fit test is two arithmetic shifts
+    (``d`` fits in w bits iff its top ``word_bits - w + 1`` bits are all
+    copies of the sign bit).
     """
     bases, widths, cls = prep
     k = bases.shape[0]          # static under trace: shapes are Python ints
@@ -436,135 +424,6 @@ def _finalize_batch(
     }
 
 
-# ---------------------------------------------------------------------------
-# constant-baked stage twins for the eager path
-# ---------------------------------------------------------------------------
-# The traced-arg stages above keep tables as runtime operands, which is
-# what an outer trace needs — but eagerly it costs ~2x in the assign
-# pass: XLA:CPU lowers shift-by-tensor and per-base dynamic slices far
-# worse than shift-by-immediate.  For concrete tables we instead bake
-# bases/widths/codes into the executable as constants (per-base immediate
-# shifts, dead bases statically skipped, spill minima only updated where
-# the class threshold statically allows) and memoize the compiled
-# closures by table content digest + config.
-
-
-class _ConstStages(NamedTuple):
-    """Compiled encode stages specialised to one table's constants."""
-
-    assign: Any
-    update: Any         # donating ``st`` (single-profile / later classes)
-    update_shared: Any  # keeps ``st`` alive (first class of a probe)
-
-
-_STAGE_CACHE: "OrderedDict[tuple[Any, ...], _ConstStages]" = OrderedDict()
-_STAGE_CAP = 16
-
-
-def _build_const_stages(prep: PreparedTable, cfg: FRConfig) -> _ConstStages:
-    bases = np.asarray(prep.bases)
-    cls_np = np.asarray(prep.cls)
-    k = int(bases.shape[0])
-    nc = cfg.num_classes
-    wt = _word_dt(cfg)
-    dt = _code_dt(cfg, k)
-    big = dt(nc * k)
-    sign_sh = cfg.word_bits - 1
-    bw_const = bases.astype(np.int16 if cfg.word_bits == 16 else np.int32)
-    base_vals = [int(v) for v in bw_const]
-    cls_vals = [int(c) for c in cls_np]
-
-    @jax.jit
-    def assign(
-        x: jax.Array,
-    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array,
-               tuple[_AltTriple, ...]]:
-        n, p = x.shape
-        xw = x.astype(wt)
-        m0 = jnp.full((n, p), big)
-        malt = [jnp.full((n, p), big) for _ in range(nc - 1)]
-        for j in range(k):
-            c = cls_vals[j]
-            if c >= nc:        # foreign-width base: can never win
-                continue
-            d = xw - wt(base_vals[j])
-            fits = (d >> wt(cfg.width_set[c] - 1)) == (d >> wt(sign_sh))
-            ej = jnp.where(fits, dt(c * k + j), big)
-            m0 = jnp.minimum(m0, ej)
-            for i in range(nc - 1):
-                if c > i:      # spill-threshold test is static here
-                    malt[i] = jnp.minimum(malt[i], ej)
-        found = m0 < big
-        sel = jnp.where(found, m0 % dt(k), dt(0))
-        cls_sel = jnp.where(found, m0 // dt(k), dt(0))
-        is_zero = x == 0
-        active = found & ~is_zero
-        out_cand = (~found) & (~is_zero)
-        alts = tuple(
-            (jnp.where(mi < big, mi % dt(k), dt(0)), mi // dt(k), mi < big)
-            for mi in malt)
-        return sel, cls_sel, active, out_cand, is_zero, alts
-
-    def update_impl(
-        x: jax.Array, st: _EncState, alt: tuple[jax.Array, ...],
-        pos: jax.Array, inclass: jax.Array, i: int, cap: int,
-    ) -> tuple[jax.Array, _EncState]:
-        sel_p, cls_p, active_p, out_p, n_spilled = st
-        n, p = x.shape
-        w = cfg.width_set[i]
-        overflow = cap < p
-        if overflow:
-            bound = pos[:, cap:cap + 1]
-            pos = pos[:, :cap]
-        if cap > p:
-            pos = jnp.pad(pos, ((0, 0), (0, cap - p)), constant_values=p)
-        if cap == 0:
-            sub = jnp.zeros((n, 0), jnp.int32)
-        else:
-            live = pos < p
-            xs = jnp.take_along_axis(x.astype(wt), pos, axis=1)
-            bs = jnp.asarray(bw_const)[
-                jnp.take_along_axis(sel_p, pos, axis=1).astype(jnp.int32)]
-            payload = (xs - bs).astype(jnp.uint32) & jnp.uint32((1 << w) - 1)
-            sub = pack_lanes(jnp.where(live, payload, 0), w)
-        if not overflow:
-            return sub, (sel_p, cls_p, active_p, out_p, n_spilled)
-        iota_p = jnp.arange(p, dtype=jnp.int32)[None]
-        over = inclass & (iota_p >= bound)
-        if i + 1 == nc:
-            newly_out = over
-        else:
-            ai, ac, ok = alt
-            spill = over & ok
-            sel_p = jnp.where(spill, ai, sel_p)
-            cls_p = jnp.where(spill, ac, cls_p)
-            n_spilled = n_spilled + spill.sum(axis=1, dtype=jnp.int32)
-            newly_out = over & ~ok
-        active_p = active_p & ~newly_out
-        out_p = out_p | newly_out
-        return sub, (sel_p, cls_p, active_p, out_p, n_spilled)
-
-    return _ConstStages(
-        assign,
-        jax.jit(update_impl, static_argnames=("i", "cap"), donate_argnums=(1,)),
-        jax.jit(update_impl, static_argnames=("i", "cap")),
-    )
-
-
-def _const_stages(prep: PreparedTable, cfg: FRConfig) -> _ConstStages:
-    """Memoized constant-baked stages (key: table content digest + cfg)."""
-    key = (_table_digest(list(prep)), cfg)
-    hit = _STAGE_CACHE.get(key)
-    if hit is not None:
-        _STAGE_CACHE.move_to_end(key)
-        return hit
-    stages = _build_const_stages(prep, cfg)
-    _STAGE_CACHE[key] = stages
-    while len(_STAGE_CACHE) > _STAGE_CAP:
-        _STAGE_CACHE.popitem(last=False)
-    return stages
-
-
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _pick_profile(
     cands: tuple[dict[str, jax.Array], ...], cfg: FRConfig
@@ -593,12 +452,8 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
     outer trace the same calls inline into the caller's single program.
     Blobs are bit-identical to the oracle either way.
     """
-    const = None if under_trace(x, *prep) else _const_stages(prep, cfg)
     with jax.named_scope(obs.ENCODE_CLASSIFY):
-        if const is not None:
-            sel, cls_sel, active, out_cand, is_zero, alts = const.assign(x)
-        else:
-            sel, cls_sel, active, out_cand, is_zero, alts = _assign_batch(x, prep, cfg)
+        sel, cls_sel, active, out_cand, is_zero, alts = _assign_batch(x, prep, cfg)
     solo = cfg.num_profiles == 1
     zero_sp = jnp.zeros(x.shape[:1], jnp.int32)
     cands = []
@@ -616,14 +471,9 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
                     alt: tuple[jax.Array, ...] = alts[i] if i + 1 < cfg.num_classes else ()
                     # the first class of a multi-profile probe re-buckets the
                     # shared assignment state, so only later stages may donate it
-                    donate = solo or i > 0
-                    if const is not None:
-                        fn = const.update if donate else const.update_shared
-                        sub, state = fn(x, state, alt, pos, inclass, i=i, cap=cap)
-                    else:
-                        fn2 = _class_update if donate else _class_update_shared
-                        sub, state = fn2(x, prep, state, alt, pos, inclass,
-                                         cfg=cfg, i=i, cap=cap)
+                    update = _class_update if solo or i > 0 else _class_update_shared
+                    sub, state = update(x, prep, state, alt, pos, inclass,
+                                        cfg=cfg, i=i, cap=cap)
                     subs.append(sub)
             cands.append(_finalize_batch(x, is_zero, state, tuple(subs), cfg=cfg))
     if solo:
@@ -633,215 +483,16 @@ def _encode_batch(x: jax.Array, prep: PreparedTable, cfg: FRConfig) -> dict[str,
 
 
 # ---------------------------------------------------------------------------
-# batched decode: rank-select expansion (the inverse of encode compaction)
+# batched decode: per-class rank expansion, the inverse of encode compaction
 # ---------------------------------------------------------------------------
-# The fast path turns decode into four data-parallel sweeps over the page:
-# unpack pointer codes, ONE packed prefix scan that carries every class
-# rank *and* the outlier rank simultaneously, one variable-width gather
-# into the delta lanes, and a rank-select gather into the outlier table.
-#
-# Two structural facts make the packing sound for encoder-produced blobs:
-# (1) the encoder re-codes bucket overflow (spill or outlier), so the
-# final count of class-i codes in a page is <= max-over-profiles cap_i —
-# each class rank therefore fits a cap-bounded bit field of one int32
-# accumulator; (2) both encoders compact outliers in page order, so the
-# j-th outlier-coded position (rank j) owns table slot j, turning the
-# oracle's scatter-back into a gather (``rank < n_out`` masks dropped
-# outliers, which keep the code but decode to 0).  The in-block inclusive
-# scan runs as an f32 triangular matmul ((N*P/16, 16) @ (16, 16)) — ~4x
-# faster than log-shift adds on XLA:CPU, and exact because block sums are
-# bounded by 16 << out_shift <= 2^24.  Per-code constants (field shift,
-# field mask, lane offset | width, cap | live-mask | base word) are baked
-# into the compiled closures as 2^ptr_bits-entry tables indexed by the
-# raw pointer code, replacing per-class unpack/cumsum/where passes; the
-# closures are memoized by table digest + config like the encode stages.
-# Unlike encode (where a ~6-dispatch chain beats the mono graph), decode
-# compiles as ONE fused jit — scan + gathers fuse cleanly, and the mono
-# dispatch measures ~15% faster than a 2-dispatch split on XLA:CPU.
-#
-# Configs the packing cannot express (word_bits != 16, page_words not a
-# multiple of 16, field overflow past 31 bits) and traced tables fall
-# back to :func:`_decode_batch_ref` — bit-identical, just slower.
-
-
-class _DecLayout(NamedTuple):
-    """Static packed-scan field layout for one config (see note above)."""
-
-    shifts: tuple[int, ...]  # field shift per width class
-    widths: tuple[int, ...]  # field width per width class
-    out_shift: int           # outlier counter field (topmost)
-    out_bits: int
-
-
-@functools.lru_cache(maxsize=64)
-def _dec_layout(cfg: FRConfig) -> _DecLayout | None:
-    """Field layout for the packed decode scan, or None when the config
-    cannot be packed (callers then use :func:`_decode_batch_ref`)."""
-    if cfg.word_bits != 16 or cfg.page_words % 16 != 0:
-        return None
-    if cfg.page_words > 32767:     # keep rank/count fields far from int32 edge
-        return None
-    nc = cfg.num_classes
-    maxcap = [max(p[i] for p in cfg.profiles) for i in range(nc)]
-    widths = tuple(max(1, c.bit_length()) for c in maxcap)
-    shifts, acc = [], 0
-    for b in widths:
-        shifts.append(acc)
-        acc += b
-    out_bits = cfg.page_words.bit_length()
-    # cap field must also hold caps above the base word in the t2 table
-    if acc > 20 or acc + out_bits > 31:
-        return None
-    if max(maxcap, default=0) >= 1 << (31 - cfg.word_bits - 1):
-        return None
-    return _DecLayout(tuple(shifts), widths, acc, out_bits)
-
-
-class _DecStages(NamedTuple):
-    """Compiled decode chain specialised to one table's constants."""
-
-    fused: Any  # (ptrs, deltas, out_vals, n_out, profile) -> decoded words
-
-
-_DEC_CACHE: "OrderedDict[tuple[Any, ...], _DecStages]" = OrderedDict()
-_DEC_CAP = 16
-
-
-def _build_dec_stages(
-    prep: PreparedTable, cfg: FRConfig, lay: _DecLayout
-) -> _DecStages:
-    bases = np.asarray(prep.bases)
-    cls_np = np.asarray(prep.cls)
-    k = int(bases.shape[0])
-    nc = cfg.num_classes
-    P, wb, ocap = cfg.page_words, cfg.word_bits, cfg.outlier_cap
-    nP, NC = cfg.num_profiles, 1 << cfg.ptr_bits
-    wmask = (1 << wb) - 1
-
-    # per-pointer-code constants (zero/dead codes get inert rows: no scan
-    # increment, cap 1 / width 1 / offset 0, live-mask 0, base word 0)
-    cfm_t = np.zeros(NC, np.int32)        # field mask << 5 | field shift
-    t1_t = np.ones((nP, NC), np.int32)    # lane offset * 32 | delta width
-    t2_t = np.full((nP, NC), 1 << (wb + 1), np.int32)  # cap<<17 | live<<16 | base
-    for j in range(k):
-        c = int(cls_np[j])
-        base_w = int(bases[j]) & wmask
-        t2_t[:, j] = 1 << (wb + 1) | base_w
-        if c < nc:
-            cfm_t[j] = ((1 << lay.widths[c]) - 1) << 5 | lay.shifts[c]
-            for p in range(nP):
-                off = cfg.class_lane_offsets_for(p)[c]
-                cap = max(cfg.profiles[p][c], 1)
-                t1_t[p, j] = off * 32 | cfg.width_set[c]
-                t2_t[p, j] = cap << (wb + 1) | 1 << wb | base_w
-    cfm_t[cfg.outlier_code] = ((1 << lay.out_bits) - 1) << 5 | lay.out_shift
-    tri16 = np.triu(np.ones((16, 16), np.float32))
-
-    def chain_impl(
-        ptrs: jax.Array, deltas: jax.Array, out_vals: jax.Array,
-        n_out: jax.Array, profile: jax.Array | None, unsigned: bool,
-    ) -> jax.Array:
-        n = ptrs.shape[0]
-        with jax.named_scope(obs.DECODE_POINTERS):
-            code = unpack_lanes(ptrs, cfg.ptr_bits, P).astype(jnp.int32)
-            # three separate small-table gathers — measured faster than one
-            # 3-wide row gather on XLA:CPU (the (N, P, 3) intermediate defeats
-            # elementwise fusion and costs ~35%)
-            cfm = jnp.asarray(cfm_t)[code]
-            if profile is not None:
-                idx = profile[:, None] * NC + code
-                t1v = jnp.asarray(t1_t.reshape(-1))[idx]
-                t2v = jnp.asarray(t2_t.reshape(-1))[idx]
-            else:
-                t1v = jnp.asarray(t1_t[0])[code]
-                t2v = jnp.asarray(t2_t[0])[code]
-        with jax.named_scope(obs.DECODE_BUCKETS):
-            # packed rank scan: every class rank + the outlier rank advance in
-            # parallel as bit fields of one int32 accumulator
-            csh = (cfm & 31).astype(jnp.uint32)
-            fmask = cfm >> 5
-            inc = jnp.minimum(fmask, 1) << csh
-            f = inc.reshape(-1, 16).astype(jnp.float32)
-            s = (f @ jnp.asarray(tri16)).astype(jnp.int32).reshape(n, P // 16, 16)
-            tot = s[:, :, -1]
-            boff = (jnp.cumsum(tot, axis=1) - tot)[:, :, None]
-            cnt = (s + boff).reshape(n, P)
-            rank = ((cnt >> csh) & fmask) - 1
-            # payload: variable-width delta gather
-            w_pos = (t1v & 31).astype(jnp.uint32)
-            capv = t2v >> (wb + 1)
-            live = -((t2v >> wb) & 1)
-            rc = jnp.clip(rank, 0, capv - 1)
-            bitpos = (t1v & ~31) + rc * (t1v & 31)
-            dv = jnp.take_along_axis(deltas, bitpos >> 5, axis=1).astype(jnp.uint32)
-            sign = jnp.uint32(1) << (w_pos - 1)
-            dvv = (dv >> (bitpos & 31).astype(jnp.uint32)) & ((jnp.uint32(1) << w_pos) - 1)
-            delta = (dvv ^ sign).astype(jnp.int32) - sign.astype(jnp.int32)
-            val = ((t2v & wmask) + (delta & live)) & wmask
-        with jax.named_scope(obs.DECODE_OUTLIERS):   # rank-select outlier gather
-            oval = jnp.take_along_axis(out_vals, jnp.clip(rank, 0, ocap - 1), axis=1)
-            oval = jnp.where(rank < n_out[:, None], oval, 0)
-            out = jnp.where(code == cfg.outlier_code, oval, val)
-        if not unsigned:
-            return out
-        # unsigned output fuses the consumer-side word cast into the final
-        # loop: the convert truncates mod 2^wb (== the unsigned-word view
-        # of a signed word) and halves the 16-bit result buffer
-        return out.astype(jnp.uint16 if wb == 16 else jnp.uint32)
-
-    # one jit over the whole chain: scan and gathers fuse with no
-    # inter-dispatch materialisation (a ``None`` profile is an empty
-    # pytree, so both profile cases share this one callable as separate
-    # specialisations)
-    return _DecStages(jax.jit(chain_impl, static_argnames=("unsigned",)))
-
-
-def _dec_stages(prep: PreparedTable, cfg: FRConfig, lay: _DecLayout) -> _DecStages:
-    """Memoized constant-baked decode stages (key: table digest + cfg)."""
-    key = (_table_digest(list(prep)), cfg)
-    hit = _DEC_CACHE.get(key)
-    if hit is not None:
-        _DEC_CACHE.move_to_end(key)
-        return hit
-    stages = _build_dec_stages(prep, cfg, lay)
-    _DEC_CACHE[key] = stages
-    while len(_DEC_CACHE) > _DEC_CAP:
-        _DEC_CACHE.popitem(last=False)
-    return stages
-
-
-def _decode_batch(
-    blob: dict[str, jax.Array], prep: PreparedTable, cfg: FRConfig,
-    *, unsigned: bool = False,
-) -> jax.Array:
-    """Fused decode over flat (N, lanes) blobs -> (N, page_words) words.
-
-    Eagerly this is one dispatch — the packed rank scan and the payload
-    gather compile as a single jitted program; traced callers with
-    concrete tables get the same closures inlined into their program.
-    Tracer tables and unpackable configs take the reference graph —
-    every path decodes bit-identically to the oracle.
-
-    ``unsigned=True`` returns the uint16/uint32 unsigned-word view
-    instead of signed int32 words, with the cast fused into the final
-    loop of the compiled chain (consumers that want unsigned words — the
-    eval codec, bf16 bitcasts — would otherwise pay a separate full-size
-    convert pass)."""
-    lay = _dec_layout(cfg)
-    if lay is None or any(isinstance(leaf, jax.core.Tracer) for leaf in prep):
-        words = _decode_batch_ref(blob, prep, cfg)
-        if not unsigned:
-            return words
-        return words.astype(
-            jnp.uint16 if cfg.word_bits == 16 else jnp.uint32)
-    stages = _dec_stages(prep, cfg, lay)
-    profile = blob.get("profile") if cfg.num_profiles > 1 else None
-    return stages.fused(blob["ptrs"], blob["deltas"], blob["out_vals"],
-                        blob["n_out"], profile, unsigned=unsigned)
-
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def _decode_batch_ref(blob: dict[str, jax.Array], prep: PreparedTable, cfg: FRConfig) -> jax.Array:
+def _decode_batch(blob: dict[str, jax.Array], prep: PreparedTable, cfg: FRConfig) -> jax.Array:
+    """Decode flat (N, lanes) blobs -> (N, page_words) signed int32 words.
+
+    Each class's delta sub-stream is unpacked and gathered back by the
+    word's in-class page-order rank, the base added, zeros restored, and
+    live outlier slots scattered back to their page positions."""
     N = blob["ptrs"].shape[0]
     P, wb, cap_out = cfg.page_words, cfg.word_bits, cfg.outlier_cap
     bases, _, cls = prep

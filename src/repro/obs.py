@@ -55,8 +55,7 @@ def under_trace(*leaves: Any) -> bool:
     """True inside any active trace (jit, vmap, shard_map, a ``lax.cond``
     branch) or when any of ``leaves`` is a tracer.  Under a trace even ops
     on concrete arrays yield trace-local tracers, so eager-only shortcuts
-    (memoized device constants, per-device dispatch, host spans) must step
-    aside."""
+    (memoized device constants, host spans) must step aside."""
     return (not jax.core.trace_ctx.is_top_level()
             or any(isinstance(leaf, jax.core.Tracer) for leaf in leaves))
 

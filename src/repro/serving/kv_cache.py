@@ -24,18 +24,17 @@ outlier table on realistic KV distributions (words then decode to 0).
 Multi-width configs remain available per-spec for workloads whose
 measured demand fits (see ``repro.eval.run --sweep``), and adaptive
 ``cap_profiles`` configs carry their per-page profile id in the cache
-tree (the compiled xla attention path selects per page; the fused Pallas
-kernel requires a single-profile cfg).  The per-page ``n_spilled`` is
-discarded at flush; ``n_dropped`` is summed into the ``dropped`` counter.
+tree (the compiled xla attention path selects per page).  The per-page
+``n_spilled`` is discarded at flush; ``n_dropped`` is summed into the
+``dropped`` counter.
 
 Rows are cut into pages across token boundaries: a flush group of
 ``group_tokens`` rows fills ``group_pages`` whole pages (see
 :class:`_Geometry`).  Appends go to the raw tail; when the tail fills, its
 group is compressed into the next page slots (branchless ``lax.cond``).
-Reads decompress pages on the fly; K/V decode attention defaults to the
-compiled batched paged-attention path (:mod:`repro.kernels.xla`) with the
-raw tail softmax-merged in — or never leaves VMEM at all in the fused
-Pallas kernel (:mod:`repro.kernels.gbdi_paged_attn`) on TPU.
+Reads decompress pages on the fly; K/V decode attention without a resident
+region takes the compiled batched paged-attention path
+(:mod:`repro.kernels.xla`) with the raw tail softmax-merged in.
 
 Keys/values cache *with RoPE already applied* (like the raw cache), so
 page contents are position-final and compress-once.
@@ -58,8 +57,11 @@ from repro.core.format import (
 )
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels import ops
-from repro.kernels import pipeline as fr_pipeline
 from repro.kernels import xla as fr_xla
+
+# The per-token flush and the cache's own decodes run the XLA chain on every
+# platform; moving them to the Pallas kernels is ROADMAP speed item 7.
+_CODEC_BACKEND = "xla"
 
 KV_FR = FRConfig(word_bits=16, page_words=DEFAULT_PAGE_WORDS,
                  num_bases=DEFAULT_NUM_BASES, width_set=(8,),
@@ -267,25 +269,18 @@ def _compress_rows(spec: Spec, rows: jax.Array,
     """rows: (B, group_tokens, *row_shape) -> per-batch page blobs
     (B, group_pages, ...) and the words each sequence's pages dropped.
 
-    All B * group_pages pages go through ONE batched compiled dispatch
-    (:mod:`repro.kernels.xla`), not a vmap-of-vmap over single pages.
+    All B * group_pages pages go through one batched encode, not a
+    vmap-of-vmap over single pages.
     """
     B = rows.shape[0]
     words = _to_words(rows).reshape(B, -1, spec.fr.page_words)
-    # pipeline front-end: identical XLA chain under the flush trace, device
-    # sharding for eager callers (e.g. offline cache warm-up)
-    return _split_blob(fr_pipeline.encode_pages(words, table, spec.fr))
+    return _split_blob(ops.encode_pages(words, table, spec.fr, backend=_CODEC_BACKEND))
 
 
 def _decompress_all(spec: Spec, pages: dict[str, jax.Array], table: BaseTable) -> jax.Array:
-    """-> (B, n_groups*group_tokens, *row_shape) bf16; one batched dispatch.
-
-    Routed through the pipeline front-end: the fused XLA chain under a
-    trace (the jitted serving step), the sharding-aware split for eager
-    offline decompression of a big cache.
-    """
+    """-> (B, n_groups*group_tokens, *row_shape) bf16; one batched decode."""
     B = pages["ptrs"].shape[0]
-    words = fr_pipeline.decode_pages(pages, table, spec.fr)
+    words = ops.decode_pages(pages, table, spec.fr, backend=_CODEC_BACKEND)
     return _from_words(words.reshape(B, -1, *spec.row_shape))
 
 
@@ -330,7 +325,7 @@ def append_rows(spec: Spec, cache: Cache, rows: dict[str, jax.Array], pos: jax.A
             # a from-scratch decode of the page slots) and land it at this
             # group's token offset.  O(one group) per flush; reads reuse it.
             def dec(blob: dict[str, jax.Array]) -> jax.Array:
-                w = fr_pipeline.decode_pages(blob, cache["table"], spec.fr)
+                w = ops.decode_pages(blob, cache["table"], spec.fr, backend=_CODEC_BACKEND)
                 return _from_words(w.reshape(w.shape[0], G, *spec.row_shape))
             with jax.named_scope(obs.KV_FLUSH_DECODE):
                 for s in rows:

@@ -1,15 +1,18 @@
 """Eval subsystem: registries, per-cell roundtrip verification, CLI plumbing,
-and the cross-process workload-determinism regression (the old generator
-seeded with salted ``hash(name)``, so every process saw different data)."""
+the cross-process workload-determinism regression (the old generator
+seeded with salted ``hash(name)``, so every process saw different data),
+and the throughput harness's loud-failure and truncation-marking contract."""
 import json
 import os
 import subprocess
 import sys
 import zlib
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core.gbdi_fr import FRConfig
 from repro.data import workloads
 from repro.eval.codecs import FRCodec, default_codecs
 from repro.eval.registry import Workload, WorkloadRegistry
@@ -17,6 +20,8 @@ from repro.eval.run import csv_lines, evaluate, evaluate_cell, format_table, to_
 from repro.eval.workloads import default_workloads
 
 SMALL = 1 << 16
+CFG = FRConfig(word_bits=16, page_words=256, num_bases=6, width_set=(4, 8),
+               cap_profiles=((64, 192), (192, 64)), outlier_cap=16)
 
 
 @pytest.fixture(scope="session")
@@ -115,7 +120,7 @@ def test_generate_seed_and_name_vary_stream():
 # codec adapters + per-cell verification
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("codec_name", ["gbdi", "bdi", "fr"])
+@pytest.mark.parametrize("codec_name", ["gbdi", "bdi", "fr", "fr_xla"])
 def test_cell_roundtrip_verifies(registry, codecs, codec_name):
     wl = registry.get("605.mcf_s")
     data = wl.generate(SMALL, 0)
@@ -191,3 +196,64 @@ def test_ml_model_families_roundtrip(registry, codecs):
         data = wl.generate(SMALL, 0)
         cell = evaluate_cell(wl, codecs.make("gbdi", wl.word_bits), data)
         assert cell.verified and cell.lossless, (name, cell.error)
+
+
+# ---------------------------------------------------------------------------
+# throughput harness contract (roofline columns, truncation, loud failure)
+# ---------------------------------------------------------------------------
+
+class _BoomCodec:
+    name = "boom"
+    word_bits = 16
+    lossless = True
+
+    def fit(self, data):
+        return None
+
+    def encode(self, data, model):
+        raise ValueError("kaboom")
+
+    def decode(self, blob):
+        return blob
+
+    def size_bits(self, blob):
+        return 0
+
+
+class _BoomRegistry:
+    def make(self, name, word_bits):
+        return _BoomCodec()
+
+
+def test_throughput_fails_loudly_and_marks_cell():
+    from repro.eval.run import throughput
+    from repro.eval.workloads import default_workloads
+
+    rows, seen = [], []
+    with pytest.raises(RuntimeError, match="boom.*ml_grads_bf16"):
+        throughput(default_workloads(), _BoomRegistry(),
+                   suite="ml_grads_bf16", codecs="boom", n_bytes=4096,
+                   kernel_n_bytes=4096, repeats=1, rows=rows,
+                   on_row=lambda r: seen.append(dict(r)))
+    assert rows and rows[-1]["failed"] and "kaboom" in rows[-1]["error"]
+    assert len(seen) == len(rows)  # incremental writer saw the failed cell
+
+
+def test_throughput_row_marks_truncation_and_roofline():
+    from repro.eval.run import measure_throughput, roofline_peak_bytes_s
+    from repro.eval.codecs import FRCodec
+    from repro.eval.workloads import default_workloads
+
+    wl = default_workloads().get("ml_grads_bf16")
+    data = wl.generate(16 << 10, 0)
+    codec = FRCodec(word_bits=16, backend="xla", cfg=CFG, name="fr_xla")
+    row = measure_throughput(wl, codec, data, repeats=1,
+                             n_bytes_requested=2 << 20)
+    assert row["truncated"] and row["n_bytes_requested"] == 2 << 20
+    assert row["devices"] == jax.local_device_count()
+    assert row["bytes_moved"] > row["n_bytes"]
+    # a host rate never goes under a chip's roofline: no published peak
+    # for the CPU, so the roofline columns are null
+    assert row["device_kind"] == jax.devices()[0].device_kind
+    assert row["peak_bytes_s"] is roofline_peak_bytes_s(row["device_kind"]) is None
+    assert row["enc_roofline_frac"] is None and row["dec_roofline_frac"] is None

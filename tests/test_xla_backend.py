@@ -1,5 +1,6 @@
 """Compiled batched XLA backend: three-way blob parity (xla / oracle /
-Pallas-interpret) across width-set configs incl. forced spill, batch-vs-loop
+Pallas-interpret) across width-set configs incl. forced spill, the same
+parity inside jit with a traced table, batch-vs-loop
 equivalence, memoized table upload, 'auto' backend resolution, paged
 attention, and the throughput harness."""
 import numpy as np
@@ -70,6 +71,27 @@ def test_three_way_blob_parity(cfg):
         np.asarray(ops.decode_pages(xb, table, cfg, backend="xla")), ref_dec)
     np.testing.assert_array_equal(
         np.asarray(ops.decode_pages(kb, table, cfg, backend="kernel")), ref_dec)
+
+
+@pytest.mark.parametrize("direction", ["encode", "decode"])
+@pytest.mark.parametrize("cfg", PARITY_CFGS, ids=_cfg_id)
+def test_traced_table_parity(cfg, direction):
+    """Inside jit with the table a jit argument, as the KV flush and the
+    gradient exchange call it, the XLA chain's blobs and words are
+    bit-identical to the oracle's."""
+    x = _pages(cfg, 4, cfg.page_words + cfg.num_bases)
+    table = fit_fr_bases(x, cfg)
+    rb = fr_encode(x, table, cfg)
+    if direction == "encode":
+        xb = jax.jit(lambda xs, t: ops.encode_pages(xs, t, cfg, backend="xla"))(x, table)
+        assert set(xb) == set(rb)
+        for k in rb:
+            np.testing.assert_array_equal(np.asarray(xb[k]), np.asarray(rb[k]),
+                                          err_msg=f"traced xla vs oracle: {k}")
+    else:
+        words = jax.jit(lambda b, t: ops.decode_pages(b, t, cfg, backend="xla"))(rb, table)
+        np.testing.assert_array_equal(np.asarray(words),
+                                      np.asarray(fr_decode(rb, table, cfg)))
 
 
 def test_forced_spill_parity_and_counters():
@@ -158,6 +180,27 @@ def test_table_prep_memoized():
     table3 = BaseTable(jnp.asarray(np.asarray(table.bases)), table.widths)
     assert xla.prepare_table(table3, cfg) is xla.prepare_table(table, cfg)
     assert xla.table_cache_info()["misses"] == 2
+
+
+def test_table_prep_survives_a_donated_table():
+    """The memo holds its own copies of a table's buffers: a caller that
+    donates its table after an eager call (KVSession donates its tables
+    with the cache) leaves later calls with an equal table working."""
+    cfg = FRConfig(word_bits=16, page_words=128, num_bases=4,
+                   width_set=(4, 8), bucket_caps=(32, 96), outlier_cap=8)
+    x = _pages(cfg, 2, 13)
+    table = fit_fr_bases(x, cfg)
+    xla.table_cache_clear()
+    mine = jax.tree.map(jnp.copy, table)
+    blob = xla.encode_pages(x, mine, cfg)
+    jax.jit(lambda t: jax.tree.map(lambda a: a + 1, t), donate_argnums=0)(mine)
+    assert mine.bases.is_deleted()
+    again = xla.encode_pages(x, table, cfg)
+    assert xla.table_cache_info()["hits"] == 1
+    for k in blob:
+        np.testing.assert_array_equal(np.asarray(again[k]), np.asarray(blob[k]), err_msg=k)
+    np.testing.assert_array_equal(np.asarray(xla.decode_pages(again, table, cfg)),
+                                  np.asarray(fr_decode(blob, table, cfg)))
 
 
 def test_table_prep_never_serves_stale_constants_after_gc():
