@@ -3,8 +3,9 @@ context length, incremental resident region vs full re-decode.
 
 The claim under measurement is the serving half of the tentpole: with
 ``KVSpec.resident_decode`` every flushed page is decoded once (at flush)
-into a resident bf16 region, so a decode step's read cost is the tail
-overlay — flat in context length — while the non-resident path re-runs
+into a resident bf16 region, so a decode step decodes no page and reads
+the region and the raw tail as two softmax segments, while the
+non-resident path re-runs
 ``_decompress_all`` over every page slot each step, linear in context
 length.  Both paths are bit-identical (property-tested in
 ``tests/test_kv_compress.py``); this bench records the latency shape.
@@ -14,7 +15,7 @@ Per (context, mode) cell the bench builds a fresh single-sequence
 ``step`` (append + attend over everything so far) with the output blocked
 each repeat.  Modes: ``resident`` uses the auto backend over a
 ``resident_decode=True`` cache; ``full`` uses the oracle backend over a
-plain cache (read_full -> decode-all-pages every step).
+plain cache (decode-all-pages every step).
 
 Artifact schema (``experiments/BENCH_decode_microbench.json``, mirrored
 to the repo root like every BENCH_*.json):

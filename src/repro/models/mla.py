@@ -185,16 +185,25 @@ def absorbed_queries(p: Params, cfg: MLAConfig, x: jax.Array,
 
 
 def latent_attention(q: jax.Array, rows: jax.Array, valid: jax.Array, scale: float,
-                     latent_dim: int) -> jax.Array:
-    """Absorbed attention over latent rows: q (B, 1, H, R), rows (B, S, R),
-    valid (S,) -> (B, 1, H, latent_dim) in the rows' dtype.  Scores over
-    the whole row, values its first ``latent_dim`` words.  As in the
-    inference code, the products come out in the rows' dtype and only the
-    softmax runs in float32."""
-    logits = jnp.einsum("bqhc,btc->bhqt", q, rows).astype(jnp.float32) * jnp.float32(scale)
-    logits = jnp.where(valid[None, None, None, :], logits, jnp.float32(-1e30))
-    probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
-    return jnp.einsum("bhqt,btc->bqhc", probs, rows[..., :latent_dim])
+                     latent_dim: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Absorbed attention over one segment of latent rows: q (B, 1, H, R),
+    rows (B, S, R), valid (S,) -> the segment's unnormalised softmax
+    partials: the output accumulated in float32 (B, 1, H, latent_dim), the
+    row max and the row sum (B, 1, H).  Scores over the whole row, values
+    its first ``latent_dim`` words: the weighted sum runs over whole rows
+    and keeps its first ``latent_dim`` words, as a slice of the rows would
+    be a copy of all of them.  As in the inference code, the products
+    come out in the rows' dtype and only the softmax runs in float32.  A
+    segment with no valid position gives zero output and sum; segments
+    merge with :func:`repro.kernels.gbdi_paged_attn.merge_softmax`, and the
+    attention output is the merged output over the merged sum."""
+    logits = jnp.einsum("bqhc,btc->bqht", q, rows).astype(jnp.float32) * jnp.float32(scale)
+    logits = jnp.where(valid, logits, jnp.float32(-1e30))
+    m = logits.max(-1)
+    p = jnp.where(valid, jnp.exp(logits - m[..., None]), jnp.float32(0))
+    acc = jnp.einsum("bqht,btc->bqhc", p.astype(rows.dtype), rows,
+                     preferred_element_type=jnp.float32)
+    return acc[..., :latent_dim], m, p.sum(-1)
 
 
 def decode_in(p: Params, cfg: MLAConfig, x: jax.Array,

@@ -195,9 +195,10 @@ class KVSession:
     table) this is the surface the decode-steady-state microbench
     (``benchmarks/decode_microbench.py``) and the incremental property
     tests drive: ``step(q, k, v)`` is the per-token serving cost under
-    measurement — with ``spec.resident_decode`` it overlays the raw tail
-    over the flush-maintained decoded region (flat in context length);
-    without it every step re-decodes all pages (linear).
+    measurement — with ``spec.resident_decode`` it attends over the
+    flush-maintained decoded region and the raw tail as two softmax
+    segments, decoding no page; without it every step re-decodes all
+    pages.
 
     Over a stack of MLA layers (``spec`` a :class:`LatentSpec`,
     ``layers=(cfg, params)`` with one parameter tree per layer, ``table``

@@ -32,9 +32,12 @@ Rows are cut into pages across token boundaries: a flush group of
 ``group_tokens`` rows fills ``group_pages`` whole pages (see
 :class:`_Geometry`).  Appends go to the raw tail; when the tail fills, its
 group is compressed into the next page slots (branchless ``lax.cond``).
-Reads decompress pages on the fly; K/V decode attention without a resident
-region takes the compiled batched paged-attention path
-(:mod:`repro.kernels.xla`) with the raw tail softmax-merged in.
+Decode attention reads the flushed positions and the raw tail as two
+segments of one softmax, merged by the streaming-softmax identity; the
+tail is never written over the decoded pages.  The flushed segment is the
+resident decoded region when the cache carries one, else a decode of every
+page, or, for K/V, the compiled batched paged-attention path
+(:mod:`repro.kernels.xla`) over the compressed pages.
 
 Keys/values cache *with RoPE already applied* (like the raw cache), so
 page contents are position-final and compress-once.
@@ -58,6 +61,7 @@ from repro.core.format import (
 from repro.core.gbdi_fr import FRConfig
 from repro.kernels import ops
 from repro.kernels import xla as fr_xla
+from repro.kernels.gbdi_paged_attn import merge_softmax
 
 # The per-token flush and the cache's own decodes run the XLA chain on every
 # platform; moving them to the Pallas kernels is ROADMAP speed item 7.
@@ -170,11 +174,12 @@ class KVSpec(_Geometry):
     region (``k_dec``/``v_dec`` bf16 leaves) to the cache tree: every
     flushed page is decoded ONCE — at flush, from the same blob that
     landed in the page slots, so capacity drops round-trip identically —
-    and reused by every later read.  ``read_full`` then costs O(tail
-    overlay) per step instead of O(all pages), at the HBM price of keeping
-    the decoded copy resident (the compressed pages remain the
-    transport/storage format; ``compressed_bytes`` counts both when the
-    region is enabled).  Invariant (property-tested): at every step
+    and reused by every later read: decode attention reads the region
+    and the raw tail as two softmax segments, decoding no page on the
+    step, at the HBM price of keeping the decoded copy resident (the
+    compressed pages remain the transport/storage format;
+    ``compressed_bytes`` counts both when the region is enabled).
+    Invariant (property-tested): at every step
     ``k_dec``/``v_dec`` are bit-identical to a from-scratch
     ``_decompress_all`` of the page slots."""
 
@@ -309,35 +314,43 @@ def append_rows(spec: Spec, cache: Cache, rows: dict[str, jax.Array], pos: jax.A
         cache[f"{s}_tail"], r.astype(jnp.bfloat16), (0, slot, *zeros))
         for s, r in rows.items()}
     group = pos // G
+    at = (0, group * G, *zeros)
+    # The resident region stays out of the cond: the cond gives the group's
+    # decoded rows (or, without a flush, the rows the region already holds
+    # there) and one in-place update lands them.  A region passed through
+    # the cond is copied whole to reconcile the layouts its branches and
+    # the attention that reads the region ask for.
+    regions = {f"{s}_dec": cache[f"{s}_dec"] for s in rows if spec.resident_decode}
+    held = {k: jax.lax.dynamic_slice_in_dim(r, group * G, G, axis=1) for k, r in regions.items()}
+    rest = {k: v for k, v in cache.items() if k not in regions}
 
-    def flush(c: Cache) -> Cache:
+    def flush(c: Cache) -> tuple[Cache, dict[str, jax.Array]]:
         out = {**c, **tails}
         blobs = {}
         with jax.named_scope(obs.KV_FLUSH_ENCODE):
             for s in rows:
-                blobs[s], dropped = _compress_rows(spec, tails[f"{s}_tail"], cache["table"])
+                blobs[s], dropped = _compress_rows(spec, tails[f"{s}_tail"], c["table"])
                 out[f"{s}_dropped"] = c[f"{s}_dropped"] + dropped
         for s in rows:
             out[f"{s}_pages"] = _put_pages(c[f"{s}_pages"], blobs[s], group * spec.group_pages)
+        # Incremental decode: decode the just-encoded blob (NOT the raw tail
+        # — capacity-dropped outliers must round-trip identically to a
+        # from-scratch decode of the page slots) for this group's rows of
+        # the region.  O(one group) per flush; reads reuse it.
+        decoded = {}
         if spec.resident_decode:
-            # Incremental decode: decode the just-encoded blob (NOT the raw
-            # tail — capacity-dropped outliers must round-trip identically to
-            # a from-scratch decode of the page slots) and land it at this
-            # group's token offset.  O(one group) per flush; reads reuse it.
-            def dec(blob: dict[str, jax.Array]) -> jax.Array:
-                w = ops.decode_pages(blob, cache["table"], spec.fr, backend=_CODEC_BACKEND)
-                return _from_words(w.reshape(w.shape[0], G, *spec.row_shape))
             with jax.named_scope(obs.KV_FLUSH_DECODE):
                 for s in rows:
-                    out[f"{s}_dec"] = jax.lax.dynamic_update_slice(
-                        c[f"{s}_dec"], dec(blobs[s]), (0, group * G, *zeros))
-        return out
+                    w = ops.decode_pages(blobs[s], c["table"], spec.fr, backend=_CODEC_BACKEND)
+                    decoded[f"{s}_dec"] = _from_words(w.reshape(w.shape[0], G, *spec.row_shape))
+        return out, decoded
 
-    def nop(c: Cache) -> Cache:
-        return {**c, **tails}
+    def nop(c: Cache) -> tuple[Cache, dict[str, jax.Array]]:
+        return {**c, **tails}, held
 
-    out: Cache = jax.lax.cond(slot == G - 1, flush, nop, cache)
-    return out
+    out, groups = jax.lax.cond(slot == G - 1, flush, nop, rest)
+    return {**out, **{k: jax.lax.dynamic_update_slice(regions[k], g, at)
+                      for k, g in groups.items()}}
 
 
 def prefill_groups(spec: Spec, cache: Cache, rows: dict[str, jax.Array],
@@ -374,25 +387,66 @@ def prefill_groups(spec: Spec, cache: Cache, rows: dict[str, jax.Array],
     return out
 
 
+def _region(spec: Spec, cache: Cache, stream: str) -> jax.Array:
+    """A stream's decoded page slots, (B, n_groups*group_tokens, *row_shape)
+    bf16: the resident region when the cache carries one, else a decode of
+    every page."""
+    if f"{stream}_dec" in cache:
+        region: jax.Array = cache[f"{stream}_dec"]
+        return region
+    return _decompress_all(spec, cache[f"{stream}_pages"], cache["table"])
+
+
 def read_full(spec: Spec, cache: Cache, pos: jax.Array) -> tuple[jax.Array, ...]:
     """-> (one view per stream, valid) covering [0, pos]: decompressed
-    pages with the raw tail overlaid for the current (unflushed) group;
+    pages with the raw tail written over the current (unflushed) group;
     (K, V, valid) for a :class:`KVSpec`, (C, valid) for a
     :class:`LatentSpec`.
 
-    With ``spec.resident_decode`` the pages were already decoded at flush
-    time, so this is just the tail overlay — per-step cost stops scaling
-    with context length (the decode work moved to one group per flush).
+    The reference view the tests compare against.  Decode attention does
+    not build it: it reads the region and the tail as two segments of one
+    softmax, so no step copies the region.
     """
     G = spec.group_tokens
     at = (0, (pos // G) * G) + (0,) * len(spec.row_shape)
-    views = []
-    for s in spec.streams:
-        full = cache[f"{s}_dec"] if f"{s}_dec" in cache else \
-            _decompress_all(spec, cache[f"{s}_pages"], cache["table"])
-        views.append(jax.lax.dynamic_update_slice(full, cache[f"{s}_tail"], at))
+    views = [jax.lax.dynamic_update_slice(_region(spec, cache, s), cache[f"{s}_tail"], at)
+             for s in spec.streams]
     valid = jnp.arange(views[0].shape[1]) <= pos
     return (*views, valid)
+
+
+def _segments_valid(spec: Spec, n_region: int,
+                    pos: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Masks of the two softmax segments of decode attention at ``pos``:
+    the region's flushed positions ``t < base``, with ``base`` the start of
+    ``pos``'s group (rows at and past it may be stale, from an earlier
+    timeline), and the tail ring's ``base + j <= pos``."""
+    G = spec.group_tokens
+    base = (pos // G) * G
+    return jnp.arange(n_region) < base, base + jnp.arange(G) <= pos
+
+
+def _kv_partials(qg: jax.Array, K: jax.Array, V: jax.Array, valid: jax.Array,
+                 scale: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One softmax segment of grouped-query attention: qg (B, Kv, G, hd)
+    over K, V (B, T, Kv, hd) at the positions ``valid`` (T,) marks ->
+    unnormalised partials, the output accumulated in float32 (B, Kv, G,
+    hd), the row max and the row sum (B, Kv, G).  Products come out in the
+    operands' dtype, the softmax runs in float32; a segment with no valid
+    position gives zero output and sum."""
+    lg = jnp.einsum("bkgh,btkh->bkgt", qg, K).astype(jnp.float32) * scale
+    lg = jnp.where(valid, lg, -1e30)
+    m = lg.max(-1)
+    p = jnp.where(valid, jnp.exp(lg - m[..., None]), 0.0)
+    acc = jnp.einsum("bkgt,btkh->bkgh", p.astype(V.dtype), V,
+                     preferred_element_type=jnp.float32)
+    return acc, m, p.sum(-1)
+
+
+def _normalised(region: tuple[jax.Array, ...], tail: tuple[jax.Array, ...]) -> jax.Array:
+    """Two segments' partials (acc, max, sum) merged and normalised once."""
+    acc, _, l = merge_softmax(*region, *tail)
+    return acc / l[..., None]
 
 
 def attention_decode(
@@ -401,16 +455,17 @@ def attention_decode(
 ) -> jax.Array:
     """q: (B, 1, H, hd) -> (B, 1, H*hd) over the compressed cache.
 
-    ``backend='oracle'`` attends over the full decompressed view (the
-    semantic reference).  ``'resident'`` is the same math but requires the
-    ``spec.resident_decode`` incremental region, so no page is decoded on
-    this step at all.  ``'xla'`` attends over the compressed pages with
-    the compiled paged-attention decode
-    (:func:`repro.kernels.xla.paged_attention_decode`) and merges the raw
-    tail via the streaming-softmax identity — one batched dispatch, no
-    decompressed cache materialised between layers.  ``'auto'`` (default)
-    picks the resident region when the cache carries one, else the paged
-    path.
+    Attention over [0, pos] is two segments of one softmax, merged by the
+    streaming-softmax identity: the decoded page slots before the current
+    group, and the raw tail ring up to ``pos``.  ``backend='oracle'``
+    reads the first from a decode of every page (the semantic reference).
+    ``'resident'`` reads it from the ``spec.resident_decode`` region, so no
+    page is decoded on this step at all; the math is the oracle's.
+    ``'xla'`` attends over the compressed pages with the compiled
+    paged-attention decode (:func:`repro.kernels.xla.paged_attention_decode`),
+    one batched dispatch, no decompressed cache materialised between layers.
+    ``'auto'`` (default) picks the resident region when the cache carries
+    one, else the paged path.
     """
     if backend not in ("oracle", "resident", "xla", "auto"):
         raise ValueError(f"unknown backend {backend!r}; "
@@ -419,41 +474,24 @@ def attention_decode(
         raise ValueError("backend='resident' requires a cache built with "
                          "spec.resident_decode=True")
     with jax.named_scope(obs.KV_ATTEND):
-        if backend in ("oracle", "resident") or (backend == "auto" and "k_dec" in cache):
-            K, V, valid = read_full(spec, cache, pos)
-            B, S, Kv, hd = K.shape
-            H = q.shape[2]
-            scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-            qg = q.reshape(B, 1, Kv, H // Kv, hd)
-            logits = jnp.einsum("bskgh,btkh->bkgst", qg, K).astype(jnp.float32) * scale
-            logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(V.dtype)
-            out = jnp.einsum("bkgst,btkh->bskgh", probs, V)
-            return out.reshape(B, 1, H * hd)
-
-        from repro.kernels.gbdi_paged_attn import merge_softmax
-
         B, _, H, hd = q.shape
         Kv = spec.n_kv
-        G = H // Kv
-        qg = q.reshape(B, Kv, G, hd).astype(jnp.float32)
-        acc, m, l = fr_xla.paged_attention_decode(
-            qg, cache["k_pages"], cache["v_pages"], cache["table"], pos, spec.fr,
-            n_kv=Kv, hd=hd, groups=G,
-        )
-        # raw-tail stream (the current partial page), then softmax-merge
-        pt = spec.group_tokens
+        qg = q.reshape(B, Kv, H // Kv, hd)
         scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-        Kt = cache["k_tail"].astype(jnp.float32)
-        Vt = cache["v_tail"].astype(jnp.float32)
-        tail_valid = (pos // pt) * pt + jnp.arange(pt) <= pos
-        lg = jnp.einsum("bkgh,btkh->bkgt", qg, Kt) * scale
-        lg = jnp.where(tail_valid[None, None, None, :], lg, -1e30)
-        m2 = lg.max(-1)
-        p2 = jnp.where(lg <= -1e29, 0.0, jnp.exp(lg - m2[..., None]))
-        acc2 = jnp.einsum("bkgt,btkh->bkgh", p2, Vt)
-        accm, _, lm = merge_softmax(acc, m, l, acc2, m2, p2.sum(-1))
-        out = accm / lm[..., None]
+        if backend == "xla" or (backend == "auto" and "k_dec" not in cache):
+            qg = qg.astype(jnp.float32)
+            region = fr_xla.paged_attention_decode(
+                qg, cache["k_pages"], cache["v_pages"], cache["table"], pos, spec.fr,
+                n_kv=Kv, hd=hd, groups=H // Kv)
+            tail = _kv_partials(qg, cache["k_tail"].astype(jnp.float32),
+                                cache["v_tail"].astype(jnp.float32),
+                                _segments_valid(spec, 0, pos)[1], scale)
+        else:
+            K, V = _region(spec, cache, "k"), _region(spec, cache, "v")
+            region_valid, tail_valid = _segments_valid(spec, K.shape[1], pos)
+            region = _kv_partials(qg, K, V, region_valid, scale)
+            tail = _kv_partials(qg, cache["k_tail"], cache["v_tail"], tail_valid, scale)
+        out = _normalised(region, tail)
         return out.reshape(B, 1, H * hd).astype(cache["k_tail"].dtype)
 
 
@@ -462,11 +500,15 @@ def attention_decode_latent(spec: LatentSpec, q: jax.Array, cache: Cache, pos: j
     """Absorbed latent attention of one decode step over the cache: q
     (B, 1, H, latent_dim + rope_dim), every head's query already absorbed
     into the latent, -> (B, 1, H, latent_dim).  All heads score the whole
-    row and read its first ``latent_dim`` words as the value, over the
-    resident region when the cache carries one, else over a decode of
-    every page."""
+    row and read its first ``latent_dim`` words as the value.  As in
+    :func:`attention_decode`, two segments of one softmax: the decoded
+    page slots before the current group (the resident region when the
+    cache carries one, else a decode of every page) and the raw tail."""
     from repro.models import mla
 
     with jax.named_scope(obs.KV_ATTEND):
-        rows, valid = read_full(spec, cache, pos)
-        return mla.latent_attention(q, rows, valid, scale, spec.latent_dim)
+        rows = _region(spec, cache, "c")
+        region_valid, tail_valid = _segments_valid(spec, rows.shape[1], pos)
+        region = mla.latent_attention(q, rows, region_valid, scale, spec.latent_dim)
+        tail = mla.latent_attention(q, cache["c_tail"], tail_valid, scale, spec.latent_dim)
+        return _normalised(region, tail).astype(cache["c_tail"].dtype)

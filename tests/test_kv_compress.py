@@ -1,8 +1,12 @@
 """Compressed KV cache: append/read vs raw reference; fused paged-attention
-kernel vs oracle; softmax-merge identity."""
+kernel vs oracle; softmax-merge identity; decode attention's two softmax
+segments against a float32 softmax."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core.gbdi_fr import FRConfig, fit_fr_bases
 from repro.kernels.gbdi_paged_attn import merge_softmax, paged_attention_decode
@@ -256,3 +260,69 @@ def test_kvsession_step_matches_manual_path():
                                     backend="oracle")
         _bit_equal(got, want, f"session step @ {t}")
     assert sess.pos == n
+
+
+# ---------------------------------------------------------------------------
+# decode attention as two softmax segments: masking at group boundaries
+# ---------------------------------------------------------------------------
+
+# (spec, positions checked): 128-word rows make a flush group of one token,
+# 32-word rows one of 4 tokens; positions on a group's first, a middle and
+# its last slot, in the first group (no flushed position yet) and later
+GROUP_CASES = {
+    "g1": (kvc.KVSpec(n_kv=2, head_dim=64, max_len=12, fr=SPEC.fr, resident_decode=True),
+           (0, 1, 6)),
+    "g4-first": (kvc.KVSpec(n_kv=2, head_dim=16, max_len=16, fr=SPEC.fr,
+                            resident_decode=True), (0, 8)),
+    "g4-middle": (kvc.KVSpec(n_kv=2, head_dim=16, max_len=16, fr=SPEC.fr,
+                             resident_decode=True), (2, 9)),
+    "g4-last": (kvc.KVSpec(n_kv=2, head_dim=16, max_len=16, fr=SPEC.fr,
+                           resident_decode=True), (3, 11)),
+}
+
+
+def _np_attention(q, K, V, pos):
+    """Plain float32 numpy softmax attention of q (B, 1, H, hd) over the
+    positions [0, pos] of K, V (B, S, Kv, hd) -> (B, 1, H*hd)."""
+    B, _, H, hd = q.shape
+    Kv = K.shape[2]
+    qg = q.reshape(B, Kv, H // Kv, hd)
+    lg = np.einsum("bkgh,btkh->bkgt", qg, K[:, :pos + 1]) / np.sqrt(np.float32(hd))
+    p = np.exp(lg - lg.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bkgt,btkh->bkgh", p, V[:, :pos + 1]).reshape(B, 1, H * hd)
+
+
+@pytest.mark.parametrize("case", list(GROUP_CASES))
+def test_two_segment_attention_masks_at_group_boundaries(case):
+    """Decode attention reads the flushed positions of the region and the
+    raw tail as two softmax segments.  At each checked position, every
+    backend gives a float32 softmax over the full [0, pos] view, and rows of
+    the region at and past the current group's start (stale rows, as after
+    a restart from an earlier cache) change no bit of the output."""
+    spec, positions = GROUP_CASES[case]
+    rng = np.random.default_rng(sum(positions))
+    n = max(positions) + 1
+    ks, vs = (jnp.asarray(rng.normal(0, 1, (B, n, spec.n_kv, spec.head_dim)), jnp.bfloat16)
+              for _ in range(2))
+    table = _bases(np.concatenate([np.asarray(ks, np.float32), np.asarray(vs, np.float32)], 1))
+    H = 2 * spec.n_kv
+    qs = rng.normal(0, 1, (n, B, 1, H, spec.head_dim)).astype(np.float32)
+    append = jax.jit(functools.partial(kvc.append, spec))
+    cache = kvc.init_compressed(spec, B, table)
+    for t in range(n):
+        cache = append(cache, ks[:, t:t + 1], vs[:, t:t + 1], jnp.int32(t))
+        if t not in positions:
+            continue
+        pos, q = jnp.int32(t), jnp.asarray(qs[t])
+        K, V, _ = kvc.read_full(spec, cache, pos)
+        want = _np_attention(qs[t], np.asarray(K, np.float32), np.asarray(V, np.float32), t)
+        out = kvc.attention_decode(spec, q, cache, pos)
+        for backend in ("resident", "oracle", "xla"):
+            got = kvc.attention_decode(spec, q, cache, pos, backend=backend)
+            np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=2e-2,
+                                       err_msg=f"{backend} @ pos {t}")
+        base = t // spec.group_tokens * spec.group_tokens
+        stale = {**cache, **{f"{s}_dec": cache[f"{s}_dec"].at[:, base:].set(1e6)
+                             for s in ("k", "v")}}
+        _bit_equal(kvc.attention_decode(spec, q, stale, pos), out, f"stale rows @ pos {t}")

@@ -70,7 +70,9 @@ def test_absorbed_decode_matches_reference(T):
         row, q = mla.decode_in(p, SMALL, x[:, t:t + 1], jnp.int32(t))
         assert row.shape == (B, 1, 80) and q.shape == (B, 1, 8, 80)
         rows = rows.at[:, t].set(row[:, 0])
-        o = mla.latent_attention(q, rows, jnp.arange(T) <= t, scale, SMALL.kv_lora_rank)
+        acc, _, l = mla.latent_attention(q, rows, jnp.arange(T) <= t, scale,
+                                         SMALL.kv_lora_rank)
+        o = acc / l[..., None]
         y = np.asarray(mla.decode_out(p, SMALL, o))[:, 0]
         np.testing.assert_allclose(y, ref[:, t], atol=1e-4 * np.abs(ref).max(), rtol=0)
 
@@ -166,6 +168,41 @@ def test_latent_append_across_group_boundary(n):
         _bit_equal(C[:, :flushed], _roundtrip(rows[:, :flushed], table), "flushed groups")
     _bit_equal(C[:, flushed:n], rows[:, flushed:n], "tail")
     assert cache["c_dropped"].shape == (B,)
+
+
+@pytest.mark.parametrize("positions", [(0, 16), (4, 20), (7, 23)],
+                         ids=["first", "middle", "last"])
+def test_latent_attention_masks_at_group_boundaries(positions):
+    """The latent cache's decode attention reads the region's flushed
+    positions and the raw tail as two softmax segments: on a group's first,
+    a middle and its last slot it gives a float32 softmax over the full
+    [0, pos] view, and rows of the region at and past the current group's
+    start (stale rows, as after a restart) change no bit of the output."""
+    spec = _latent_spec(24)
+    n = max(positions) + 1
+    rows = _rows(n, (B,), n, 80)
+    table = _table(rows)
+    qs = jax.random.normal(jax.random.PRNGKey(n), (n, B, 1, 4, 80))
+    scale = 80 ** -0.5
+    append = jax.jit(lambda c, r, t: kvc.append_rows(spec, c, {"c": r}, t))
+    cache = kvc.init_compressed(spec, B, table)
+    for t in range(n):
+        cache = append(cache, rows[:, t:t + 1], jnp.int32(t))
+        if t not in positions:
+            continue
+        pos = jnp.int32(t)
+        C = np.asarray(kvc.read_full(spec, cache, pos)[0], np.float32)[:, :t + 1]
+        lg = np.einsum("bqhc,btc->bqht", np.asarray(qs[t]), C) * np.float32(scale)
+        p = np.exp(lg - lg.max(-1, keepdims=True))
+        want = np.einsum("bqht,btc->bqhc", p / p.sum(-1, keepdims=True), C[..., :64])
+        out = kvc.attention_decode_latent(spec, qs[t], cache, pos, scale)
+        assert out.shape == (B, 1, 4, 64) and out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=2e-2,
+                                   err_msg=f"pos {t}")
+        base = t // spec.group_tokens * spec.group_tokens
+        stale = {**cache, "c_dec": cache["c_dec"].at[:, base:].set(1e6)}
+        _bit_equal(kvc.attention_decode_latent(spec, qs[t], stale, pos, scale), out,
+                   f"stale rows @ pos {t}")
 
 
 @pytest.mark.parametrize("geometry", ["latent", "kv"])

@@ -1,4 +1,6 @@
-"""Compile rehearsal for the TPU v5e: the codec kernels at real widths.
+"""Compile rehearsal for the TPU v5e: the codec kernels at real widths,
+and the decode attention of the compressed caches at the benchmark cells'
+shapes.
 
 Interpret mode runs the Pallas kernels as plain jnp and lets through what
 the chip's compiler (Mosaic) refuses — block shapes off the (8, 128) int32
@@ -7,6 +9,9 @@ for a described, not attached, ``v5e:2x2`` topology, so they need only the
 installed TPU compiler and run with ``JAX_PLATFORMS=cpu``.  Nothing runs:
 a pass says the programs compile, not that they are right or fast.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +24,9 @@ from repro.kernels import xla
 from repro.kernels.gbdi_decode import gbdi_decode_pallas
 from repro.kernels.gbdi_encode import gbdi_encode_pallas
 from repro.launch.mesh import CHIP_PEAKS
+from repro.models import mla
+from repro.serving import kv_cache as kvc
+from repro.serving.engine import _mla_step
 from repro.serving.kv_cache import KV_FR
 
 N_PAGES = 1024
@@ -122,3 +130,71 @@ def test_kernels_compile_keeping_their_regions(one_chip, kernel):
     compiled = jax.jit(lambda a, t: fn(a, t, cfg, interpret=False)).lower(arg, table).compile(
         compiler_options={"xla_enable_custom_call_region_trace": True})
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the decode cells' caches: the 7B's 32x128 K/V heads over 4096 positions,
+# one DeepSeek-V3 MLA layer's 576-word latent rows over 33024, batch 8
+ATTENTION = {
+    "kv-7b": (kvc.KVSpec(n_kv=32, head_dim=128, max_len=4096, resident_decode=True),
+              (8, 1, 32, 128)),
+    "mla-v3": (kvc.LatentSpec(latent_dim=512, rope_dim=64, max_len=33024,
+                              resident_decode=True), (8, 1, 128, 576)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ATTENTION))
+def test_resident_attention_copies_no_region(one_chip, name):
+    """Decode attention over a resident cache reads the region and the raw
+    tail as two softmax segments: its temporaries stay below one decoded
+    region's bytes, so the region is never copied to write the tail over
+    it (a tail overlay compiles to a copy of each region: 536,967,680
+    temporary bytes for the K/V pair, 304,445,952 for the latent rows)."""
+    spec, q_shape = ATTENTION[name]
+    B = q_shape[0]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table = BaseTable(s((KV_FR.num_bases,), jnp.int32), s((KV_FR.num_bases,), jnp.int32))
+    cache = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                         jax.eval_shape(lambda t: kvc.init_compressed(spec, B, t), table))
+    q, pos = s(q_shape, jnp.bfloat16), s((), jnp.int32)
+    if isinstance(spec, kvc.KVSpec):
+        def attend(q, c, p):
+            return kvc.attention_decode(spec, q, c, p)
+    else:
+        scale = mla.softmax_scale(mla.MLAConfig())
+
+        def attend(q, c, p):
+            return kvc.attention_decode_latent(spec, q, c, p, scale)
+    mem = _report(f"resident attention {name}",
+                  jax.jit(attend).lower(q, cache, pos).compile())
+    region = B * spec.n_groups * spec.group_tokens * spec.row_words * spec.word_bytes
+    assert mem.temp_size_in_bytes < region, (mem.temp_size_in_bytes, region)
+
+
+def test_mla_step_copies_no_region(one_chip):
+    """One MLA layer's whole decode step as the session compiles it (the
+    cache donated; append, whose flush may land a group in the region,
+    then attention) at the cell's shapes: no copy of the region, and
+    temporaries below its bytes.  A region threaded through the flush's
+    ``lax.cond``, or a slice of its rows for the values, compiles to
+    region-sized copies (372830720 temporary bytes with the tail overlay)."""
+    spec, _ = ATTENTION["mla-v3"]
+    cfg = mla.MLAConfig()
+
+    def s(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    base = jax.ShapeDtypeStruct((KV_FR.num_bases,), jnp.int32, sharding=one_chip)
+    caches = [jax.tree.map(s, jax.eval_shape(
+        lambda t: kvc.init_compressed(spec, 8, t), BaseTable(base, base)))]
+    params = [jax.tree.map(s, jax.eval_shape(lambda: mla.init(jax.random.PRNGKey(0), cfg)))]
+    xs = jax.ShapeDtypeStruct((1, 8, 1, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(functools.partial(_mla_step, spec, cfg), donate_argnums=(1,)) \
+        .lower(params, caches, xs, pos).compile()
+    mem = _report("MLA step, one layer", compiled)
+    region = 8 * spec.n_groups * spec.group_tokens * spec.row_words * spec.word_bytes
+    assert mem.temp_size_in_bytes < region, (mem.temp_size_in_bytes, region)
+    assert not re.search(r"bf16\[8,33024,576\]\{[^}]*\} copy\(", compiled.as_text())
